@@ -18,6 +18,7 @@ import json
 import time
 
 from benchmarks import _shared
+from repro.compile_cache import use_compile_cache
 
 
 def main() -> None:
@@ -29,6 +30,7 @@ def main() -> None:
     # run writes a report only when --out is passed explicitly.
     ap.add_argument("--out", default=None)
     args = ap.parse_args()
+    use_compile_cache()
     if args.out is None and args.smoke:
         args.out = "BENCH_engine.json"
     if args.smoke:

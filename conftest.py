@@ -5,6 +5,8 @@ import os
 
 import pytest
 
+from repro.compile_cache import use_compile_cache
+
 assert "xla_force_host_platform_device_count" not in os.environ.get(
     "XLA_FLAGS", ""), (
     "run pytest without the dry-run's XLA_FLAGS; smoke tests expect 1 device")
@@ -12,11 +14,7 @@ assert "xla_force_host_platform_device_count" not in os.environ.get(
 # Persistent XLA compile cache: the suite is dominated by compiles of the
 # same engine programs run after run, so cache them across processes.
 # First run pays the compiles; warm runs skip the XLA backend work.
-# Override (or disable with an empty value) via JAX_COMPILATION_CACHE_DIR.
-if "JAX_COMPILATION_CACHE_DIR" not in os.environ:
-    os.environ["JAX_COMPILATION_CACHE_DIR"] = os.path.join(
-        os.path.dirname(os.path.abspath(__file__)), ".jax_cache")
-os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "1")
+use_compile_cache()
 
 
 def pytest_configure(config):

@@ -21,6 +21,7 @@ import tempfile
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.core import PCSConfig, Scheme, fuzz_crash_ns, fuzz_trace
 from repro.core.engine import simulate
 from repro.core.semantics import EventKind, PersistentBuffer
@@ -103,4 +104,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

@@ -10,6 +10,7 @@ scalar, not a compile-time static.
 """
 import argparse
 
+from repro.compile_cache import use_compile_cache
 from repro.core import PCSConfig, Scheme, make_trace, simulate_grid
 
 if __name__ == "__main__":
@@ -18,6 +19,7 @@ if __name__ == "__main__":
     ap.add_argument("--workloads", nargs="+",
                     default=["radiosity", "cholesky", "fft"])
     args = ap.parse_args()
+    use_compile_cache()
     budget = 8_000 if args.quick else 100_000
 
     schemes = (Scheme.NOPB, Scheme.PB, Scheme.PB_RF)

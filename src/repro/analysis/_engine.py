@@ -90,7 +90,6 @@ def trace_engine(return_state: bool = False):
     """
     import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
 
     from repro.core.engine.step import scan_cell
 
@@ -100,7 +99,7 @@ def trace_engine(return_state: bool = False):
         return scan_cell(ops, addrs, gaps, lengths, scheme, sc,
                          mlen=mlen, return_state=return_state, **statics)
 
-    with enable_x64():
+    with jax.enable_x64(True):
         sc_j = {k: jnp.asarray(v, jnp.float64) for k, v in sc.items()}
         closed = jax.make_jaxpr(cell)(jnp.asarray(2, jnp.int32), sc_j)
     names = ["scheme"] + sorted(sc_j)
@@ -124,7 +123,6 @@ def final_state_shapes() -> Dict[str, Tuple[str, Tuple[int, ...]]]:
     regression — the carry must round-trip every step."""
     import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
 
     from repro.core.engine.step import scan_cell
 
@@ -135,7 +133,7 @@ def final_state_shapes() -> Dict[str, Tuple[str, Tuple[int, ...]]]:
                         mlen=mlen, return_state=True, **statics)
         return out[-1]
 
-    with enable_x64():
+    with jax.enable_x64(True):
         sc_j = {k: jnp.asarray(v, jnp.float64) for k, v in sc.items()}
         st = jax.eval_shape(final_state, jnp.asarray(2, jnp.int32), sc_j)
     return {k: (str(v.dtype), tuple(v.shape))

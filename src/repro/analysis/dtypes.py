@@ -96,22 +96,22 @@ def check_packing(shapes: Optional[Dict[str, Tuple[str, tuple]]] = None,
 def _walk_eqns(jaxpr):
     """Yield every eqn of a jaxpr, recursing into sub-jaxprs (scan,
     while, cond, pjit, ...)."""
-    from jax import core as jcore
     for eqn in jaxpr.eqns:
         yield eqn
         for v in eqn.params.values():
-            for sub in _sub_jaxprs(v, jcore):
+            for sub in _sub_jaxprs(v):
                 yield from _walk_eqns(sub)
 
 
-def _sub_jaxprs(v, jcore):
-    if isinstance(v, jcore.ClosedJaxpr):
+def _sub_jaxprs(v):
+    from jax.extend.core import ClosedJaxpr, Jaxpr
+    if isinstance(v, ClosedJaxpr):
         yield v.jaxpr
-    elif isinstance(v, jcore.Jaxpr):
+    elif isinstance(v, Jaxpr):
         yield v
     elif isinstance(v, (tuple, list)):
         for x in v:
-            yield from _sub_jaxprs(x, jcore)
+            yield from _sub_jaxprs(x)
 
 
 def check_f32_leaks(closed=None, fn=None, args: tuple = ()
@@ -121,8 +121,7 @@ def check_f32_leaks(closed=None, fn=None, args: tuple = ()
 
     if closed is None and fn is not None:
         import jax
-        from jax.experimental import enable_x64
-        with enable_x64():
+        with jax.enable_x64(True):
             closed = jax.make_jaxpr(fn)(*args)
     if closed is None:
         from repro.analysis._engine import trace_engine
@@ -146,13 +145,10 @@ def check_f32_leaks(closed=None, fn=None, args: tuple = ()
 
 
 def _eqn_location(eqn) -> Tuple[str, int]:
-    try:
-        from jax._src import source_info_util
-        frame = source_info_util.user_frame(eqn.source_info)
-        if frame is not None:
-            return rel(frame.file_name), frame.start_line
-    except Exception:
-        pass
+    from jax._src import source_info_util
+    frame = source_info_util.user_frame(eqn.source_info.traceback)
+    if frame is not None:
+        return rel(frame.file_name), frame.start_line
     return "<traced>", 0
 
 

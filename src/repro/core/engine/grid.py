@@ -32,7 +32,6 @@ from typing import List, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 
 from repro.core.engine.macro import MACRO_ABORT_REASONS
 from repro.core.engine.state import (SimResult, result_from_stats,
@@ -255,7 +254,7 @@ def simulate_grid(traces: Sequence[Trace], configs: Sequence[PCSConfig], *,
     sc_np, schemes, max_pbe, pm_banks, n_deep, n_leaves = _stack_configs(
         configs, max_pbe, n_tenants_max)
     single = len(traces) == 1 and len(configs) == 1
-    with enable_x64(), warnings.catch_warnings():
+    with jax.enable_x64(True), warnings.catch_warnings():
         # donated buffers the program cannot alias (dtype/layout) emit a
         # UserWarning; donation is best-effort here
         warnings.filterwarnings("ignore", message=".*[Dd]onat")
@@ -317,7 +316,7 @@ def simulate_cells(traces: Sequence[Trace], configs: Sequence[PCSConfig], *,
     n_tenants_max = max(c.n_tenants for c in configs)
     sc_np, schemes, max_pbe, pm_banks, n_deep, n_leaves = _stack_configs(
         configs, max_pbe, n_tenants_max)
-    with enable_x64(), warnings.catch_warnings():
+    with jax.enable_x64(True), warnings.catch_warnings():
         warnings.filterwarnings("ignore", message=".*[Dd]onat")
         sc = {k: jnp.asarray(v, jnp.float64) for k, v in sc_np.items()}
         out = _run_cells(

@@ -8,8 +8,8 @@
 Each kernel ships as ``<name>.py`` (pl.pallas_call + BlockSpec),
 ``ops.py`` (jit wrapper with platform dispatch) and ``ref.py``
 (pure-jnp oracle); tests sweep shapes/dtypes against the oracle with the
-kernels in interpret mode (this container is CPU-only; TPU is the
-compilation target).
+kernels in interpret mode on the CPU backend, and
+``tests/test_tpu_compile.py`` compiles each one for a described TPU v5e.
 """
 from repro.kernels.ops import flash_attention, ssd_scan, tat_lookup
 

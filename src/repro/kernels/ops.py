@@ -1,9 +1,10 @@
 """jit'd public wrappers with platform dispatch.
 
-On TPU the Pallas kernels compile natively (``interpret=False``); on CPU
-(this container) they run in interpret mode, where the kernel body
-executes in Python — bit-identical semantics, used by the allclose tests
-against the ``ref`` oracles.
+On TPU the Pallas kernels compile natively (``interpret=False``); on the
+CPU backend they run in interpret mode, where the kernel body executes
+in Python — the same semantics, used by the allclose tests against the
+``ref`` oracles.  A shape that does not divide the kernel's block raises:
+these wrappers never substitute the reference for the kernel.
 """
 from __future__ import annotations
 
@@ -12,14 +13,19 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import ref
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.ssd_scan import ssd_scan_pallas
 from repro.kernels.tat_lookup import tat_lookup_pallas
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def _interpret() -> bool:
+    return jax.default_backend() == "cpu"
+
+
+def _check_divides(what: str, size: int, block: int) -> None:
+    if size % block:
+        raise ValueError(f"{what} {size} is not a multiple of the kernel "
+                         f"block {block}")
 
 
 def tat_lookup(req_tags: jnp.ndarray, tat: jnp.ndarray,
@@ -27,10 +33,9 @@ def tat_lookup(req_tags: jnp.ndarray, tat: jnp.ndarray,
                ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     r = req_tags.shape[0]
     block_r = min(block_r, r)
-    if r % block_r:
-        return ref.tat_lookup_ref(req_tags, tat, states)
+    _check_divides("request count", r, block_r)
     return tat_lookup_pallas(req_tags, tat, states, block_r=block_r,
-                             interpret=not _on_tpu())
+                             interpret=_interpret())
 
 
 def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
@@ -39,11 +44,11 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     s = q.shape[2]
     block_q = min(block_q, s)
     block_k = min(block_k, s)
-    if s % block_q or s % block_k:
-        return ref.flash_attention_ref(q, k, v, causal=causal, window=window)
+    _check_divides("sequence length", s, block_q)
+    _check_divides("sequence length", s, block_k)
     return flash_attention_pallas(q, k, v, causal=causal, window=window,
                                   block_q=block_q, block_k=block_k,
-                                  interpret=not _on_tpu())
+                                  interpret=_interpret())
 
 
 def ssd_scan(x: jnp.ndarray, dt: jnp.ndarray, A: jnp.ndarray,
@@ -51,7 +56,6 @@ def ssd_scan(x: jnp.ndarray, dt: jnp.ndarray, A: jnp.ndarray,
              ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     s = x.shape[1]
     chunk = min(chunk, s)
-    if s % chunk:
-        return ref.ssd_scan_ref(x, dt, A, B, C, chunk=chunk)
+    _check_divides("sequence length", s, chunk)
     return ssd_scan_pallas(x, dt, A, B, C, chunk=chunk,
-                           interpret=not _on_tpu())
+                           interpret=_interpret())

@@ -32,29 +32,38 @@ def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, fin_ref, state_scr, *,
         state_scr[...] = jnp.zeros_like(state_scr)
 
     x = x_ref[0, 0, 0].astype(jnp.float32)     # (Q, P)
-    dt = dt_ref[0, 0, 0, :, 0].astype(jnp.float32)  # (Q,)
-    a = a_ref[0]                               # () scalar decay rate (f32)
+    dt = dt_ref[0, 0, 0].astype(jnp.float32)   # (Q, 1)
+    a = a_ref[pl.program_id(1)]                # () this head's decay rate
     bm = b_ref[0, 0].astype(jnp.float32)       # (Q, N)
     cm = c_ref[0, 0].astype(jnp.float32)       # (Q, N)
-
-    da = dt * a                                # (Q,) log-decay per step
-    da_cum = jnp.cumsum(da)                    # (Q,)
     q = x.shape[0]
 
+    # the TPU lowering has no cumsum and no vector transpose: prefix
+    # sums and the column -> row moves are masked (Q, Q) reductions
+    ii = jax.lax.broadcasted_iota(jnp.int32, (q, q), 0)
+    jj = jax.lax.broadcasted_iota(jnp.int32, (q, q), 1)
+    tri = ii >= jj
+    da = dt * a                                # (Q, 1) log-decay per step
+    da_row = jnp.sum(jnp.where(ii == jj, da, 0.0), axis=0, keepdims=True)
+    dt_row = jnp.sum(jnp.where(ii == jj, dt, 0.0), axis=0, keepdims=True)
+    da_cum = jnp.sum(jnp.where(tri, da_row, 0.0), axis=1,
+                     keepdims=True)            # (Q, 1)
+    da_cum_row = jnp.sum(jnp.where(tri, 0.0, da), axis=0,
+                         keepdims=True) + da_row   # (1, Q)
+    total = jnp.sum(da_row, axis=1, keepdims=True)  # (1, 1)
+
     # intra-chunk dual form: L[i,j] = exp(sum_{j<k<=i} da_k), lower-tri
-    seg = da_cum[:, None] - da_cum[None, :]
-    tri = (jax.lax.broadcasted_iota(jnp.int32, (q, q), 0)
-           >= jax.lax.broadcasted_iota(jnp.int32, (q, q), 1))
-    L = jnp.where(tri, jnp.exp(seg), 0.0)
-    scores = cm @ bm.T                          # (Q, Q)
-    y = ((scores * L) * dt[None, :]) @ x        # (Q, P)
+    L = jnp.where(tri, jnp.exp(da_cum - da_cum_row), 0.0)
+    scores = jax.lax.dot_general(cm, bm, (((1,), (1,)), ((), ())))  # (Q, Q)
+    y = ((scores * L) * dt_row) @ x             # (Q, P)
 
     # carried-state contribution + state update
     state = state_scr[...]                      # (P, N)
-    y += jnp.exp(da_cum)[:, None] * (cm @ state.T)
-    decay_to_end = jnp.exp(da_cum[-1] - da_cum)            # (Q,)
-    state_new = (state * jnp.exp(da_cum[-1])
-                 + (x * (dt * decay_to_end)[:, None]).T @ bm)  # (P, N)
+    y += jnp.exp(da_cum) * jax.lax.dot_general(
+        cm, state, (((1,), (1,)), ((), ())))    # (Q, P)
+    decay_to_end = jnp.exp(total - da_cum)      # (Q, 1)
+    state_new = state * jnp.exp(total) + jax.lax.dot_general(
+        x * (dt * decay_to_end), bm, (((0,), (0,)), ((), ())))  # (P, N)
     state_scr[...] = state_new
 
     y_ref[0, 0, 0] = y.astype(y_ref.dtype)
@@ -94,7 +103,8 @@ def ssd_scan_pallas(x: jnp.ndarray, dt: jnp.ndarray, A: jnp.ndarray,
                          lambda ib, ih, ic: (ib, ih, ic, 0, 0)),
             pl.BlockSpec((1, 1, 1, chunk, 1),
                          lambda ib, ih, ic: (ib, ih, ic, 0, 0)),
-            pl.BlockSpec((1,), lambda ib, ih, ic: (ih,)),
+            # the (H,) decay rates sit whole in scalar memory
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, 1, chunk, n), lambda ib, ih, ic: (ib, ic, 0, 0)),
             pl.BlockSpec((1, 1, chunk, n), lambda ib, ih, ic: (ib, ic, 0, 0)),
         ],

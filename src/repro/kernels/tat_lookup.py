@@ -2,12 +2,13 @@
 
 The PB's hot loop (PBCS tag check, Section V-C) as a TPU kernel: a block
 of request tags is compared against the whole Tag Address Table resident
-in VMEM; the match reduction maps onto the VPU's 8x128 lanes.  Used by
-the vectorized PCS simulator when scoring large request batches.
+in VMEM.  The engine does not call it; the tests check it against
+``ref.tat_lookup_ref``.
 
-Tiling: requests are tiled in blocks of ``block_r``; the TAT (tags +
-states) is small (16-1024 entries) and fully VMEM-resident, broadcast to
-every program.
+Tiling: requests are tiled in ``(1, block_r)`` rows, one request per
+lane; the TAT (tags + states) is small (16-1024 entries), held as an
+``(n, 1)`` column fully VMEM-resident and broadcast to every program, so
+the match reduction runs down the sublanes.
 """
 from __future__ import annotations
 
@@ -20,15 +21,19 @@ from jax.experimental import pallas as pl
 
 
 def _kernel(req_ref, tat_ref, st_ref, idx_ref, out_st_ref):
-    req = req_ref[...]                       # (block_r,)
-    tat = tat_ref[...]                       # (n,)
-    st = st_ref[...]                         # (n,)
-    match = (req[:, None] == tat[None, :]) & (st[None, :] != 0)
-    has = jnp.any(match, axis=1)
-    # argmax over the entry axis (first match wins, like priority encode)
-    idx = jnp.argmax(match, axis=1).astype(jnp.int32)
-    idx_ref[...] = jnp.where(has, idx, -1)
-    out_st_ref[...] = jnp.where(has, jnp.take(st, idx), 0).astype(jnp.int32)
+    req = req_ref[...]                       # (1, block_r)
+    tat = tat_ref[...]                       # (n, 1)
+    st = st_ref[...]                         # (n, 1)
+    # requests on the lanes, table entries on the sublanes
+    match = (tat == req) & (st != 0)         # (n, block_r)
+    # first match wins, like a priority encoder: the least matching
+    # row, or n when nothing matches
+    row = jax.lax.broadcasted_iota(jnp.int32, match.shape, 0)
+    n = tat.shape[0]
+    idx = jnp.min(jnp.where(match, row, n), axis=0, keepdims=True)
+    idx_ref[...] = jnp.where(idx < n, idx, -1)
+    out_st_ref[...] = jnp.sum(jnp.where(row == idx, st, 0), axis=0,
+                              keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("block_r", "interpret"))
@@ -40,21 +45,22 @@ def tat_lookup_pallas(req_tags: jnp.ndarray, tat: jnp.ndarray,
     n = tat.shape[0]
     assert r % block_r == 0, (r, block_r)
     grid = (r // block_r,)
-    return pl.pallas_call(
+    idx, st = pl.pallas_call(
         _kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((block_r,), lambda i: (i,)),
-            pl.BlockSpec((n,), lambda i: (0,)),
-            pl.BlockSpec((n,), lambda i: (0,)),
+            pl.BlockSpec((1, block_r), lambda i: (0, i)),
+            pl.BlockSpec((n, 1), lambda i: (0, 0)),
+            pl.BlockSpec((n, 1), lambda i: (0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((block_r,), lambda i: (i,)),
-            pl.BlockSpec((block_r,), lambda i: (i,)),
+            pl.BlockSpec((1, block_r), lambda i: (0, i)),
+            pl.BlockSpec((1, block_r), lambda i: (0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((r,), jnp.int32),
-            jax.ShapeDtypeStruct((r,), jnp.int32),
+            jax.ShapeDtypeStruct((1, r), jnp.int32),
+            jax.ShapeDtypeStruct((1, r), jnp.int32),
         ],
         interpret=interpret,
-    )(req_tags, tat, states)
+    )(req_tags.reshape(1, r), tat.reshape(n, 1), states.reshape(n, 1))
+    return idx.reshape(r), st.reshape(r)

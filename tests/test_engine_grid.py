@@ -17,7 +17,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental import enable_x64
 
 from conftest import TINY_BUCKET
 from repro.core import Op, PCSConfig, Scheme, Trace, make_trace
@@ -154,7 +153,7 @@ def _scan_state(tr, cfg, n_steps, extra_cores=0):
     lengths = np.zeros((C + extra_cores,), np.int32)
     ops[:C], addrs[:C], gaps[:C], lengths[:C] = (tr.ops, tr.addrs, tr.gaps,
                                                  tr.lengths)
-    with enable_x64():
+    with jax.enable_x64(True):
         sc = {k: jnp.asarray(v, jnp.float64)
               for k, v in scalars_from_config(cfg).items()}
         out = _jitted_cell(cfg.n_pbe, n_steps, cfg.pm_banks)(

@@ -21,6 +21,24 @@ def test_tat_lookup_sweep(r, n, dtype):
     np.testing.assert_array_equal(s1, s2)
 
 
+@pytest.mark.parametrize("kernel", ["tat_lookup", "flash_attention",
+                                    "ssd_scan"])
+def test_ops_reject_shape_not_dividing_block(kernel):
+    """The wrappers raise; they never fall back to the reference."""
+    f32 = jnp.float32
+    with pytest.raises(ValueError, match="not a multiple of the kernel"):
+        if kernel == "tat_lookup":
+            tat_lookup(jnp.zeros(300, jnp.int32), jnp.zeros(16, jnp.int32),
+                       jnp.ones(16, jnp.int32))
+        elif kernel == "flash_attention":
+            q = jnp.zeros((1, 1, 192, 64), f32)
+            flash_attention(q, q, q)
+        else:
+            ssd_scan(jnp.zeros((1, 192, 1, 16), f32), jnp.zeros((1, 192, 1), f32),
+                     -jnp.ones(1, f32), jnp.zeros((1, 192, 16), f32),
+                     jnp.zeros((1, 192, 16), f32))
+
+
 def test_tat_lookup_empty_never_matches():
     req = jnp.asarray([7, 7], jnp.int32)
     tat = jnp.asarray([7, 7, 7, 7], jnp.int32)

@@ -99,7 +99,7 @@ def test_data_pipeline_deterministic_resume():
 def test_sharding_rules():
     from jax.sharding import PartitionSpec as P
     from repro.launch.sharding import param_spec
-    mesh = jax.sharding.AbstractMesh((("data", 16), ("model", 16)))
+    mesh = jax.sharding.AbstractMesh((16, 16), ("data", "model"))
 
     class Leaf:
         def __init__(self, shape):

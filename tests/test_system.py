@@ -2,6 +2,7 @@
 PCS persistence tier in each scheme."""
 import subprocess
 import sys
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -93,6 +94,6 @@ def test_cli_train_runs(tmp_path):
            "--seq", "16", "--ckpt-every", "2",
            "--ckpt-dir", str(tmp_path / "ck"), "--store-delay-ms", "1"]
     out = subprocess.run(cmd, capture_output=True, text=True, timeout=300,
-                         env=env, cwd="/root/repo")
+                         env=env, cwd=Path(__file__).resolve().parents[1])
     assert out.returncode == 0, out.stderr[-2000:]
     assert "train done" in out.stdout
