@@ -40,13 +40,13 @@ def main() -> None:
     from benchmarks import (ckpt_tier_bench, fig1_switch_depth, fig5_speedup,
                             fig6_latency, fig7_rf_rates, fig8_pbe_sweep,
                             fig_dynamic, fig_fabric, fig_qos, fig_recovery,
-                            fig_slo, fig_tenants, kernel_bench)
+                            fig_slo, fig_tenants)
     from repro.core.engine import compile_count
 
     figures = (fig1_switch_depth, fig5_speedup, fig6_latency, fig7_rf_rates,
                fig8_pbe_sweep, fig_recovery, fig_tenants, fig_qos, fig_slo,
                fig_fabric, fig_dynamic)
-    extras = () if args.smoke else (ckpt_tier_bench, kernel_bench)
+    extras = () if args.smoke else (ckpt_tier_bench,)
 
     rows, timings = [], {}
     # Figures sharing the cached {workload x scheme} grid cost ~0 wall

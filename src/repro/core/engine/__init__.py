@@ -9,7 +9,9 @@ pieces:
                    home of the scheme/threshold constants shared with
                    the untimed oracle and the checkpoint tier
   * ``handlers`` — per-op handlers with traced-scheme ``lax.switch``
-  * ``step``     — clock-merge step driver + the scan (compile counter)
+  * ``step``     — clock-merge step driver + the scan
+  * ``spans``    — the engine's spans and counters (compile counter,
+                   macro hit rate, scan segments, jax.monitoring seconds)
   * ``macro``    — guarded macro-step mini-interpreter (homogeneous-run
                    speculation; bit-exact commit-or-abort)
   * ``fabric``   — fan-out fabric helpers (leaf partition of the hop-1
@@ -18,12 +20,12 @@ pieces:
                    front-ends and the ``simulate`` / ``simulate_sweep``
                    compat wrappers
 """
-from repro.core.engine.grid import (last_macro_abort_reasons,  # noqa: F401
-                                    last_macro_hit_rate,
-                                    simulate, simulate_cells,
+from repro.core.engine.grid import (simulate, simulate_cells,  # noqa: F401
                                     simulate_grid, simulate_sweep)
+from repro.core.engine.spans import (compile_count,  # noqa: F401
+                                     last_macro_abort_reasons,
+                                     last_macro_hit_rate)
 from repro.core.engine.state import SimResult  # noqa: F401
-from repro.core.engine.step import compile_count  # noqa: F401
 
 __all__ = ["SimResult", "simulate", "simulate_cells", "simulate_grid",
            "simulate_sweep", "compile_count", "last_macro_hit_rate",

@@ -12,12 +12,18 @@ compile-time static.
 *pair* under a single vmap axis, for sweeps that never needed the full
 cross product (half the cells of an anchored two-trace sweep).
 
-The stacker also runs the macro-run pre-pass (``core.traces.plan_runs``)
-and pads the op axis by ``MACRO_KMAX`` slots so the engine's macro-step
-window slice never clamps; ``macro=False`` opts a call out (the
-differential tests' control column).  Input buffers are donated to the
-jitted programs — they are freshly staged per call, so XLA may reuse
-them for the scan carry instead of allocating.
+The stacker pads the op axis by ``MACRO_KMAX`` slots so the engine's
+macro-step window slice never clamps, and the macro-run pre-pass
+(``core.traces.plan_runs``) plans each stacked trace; ``macro=False``
+opts a call out (the differential tests' control column).  Input
+buffers are donated to the jitted programs — they are freshly staged
+per call, so XLA may reuse them for the scan carry instead of
+allocating.
+
+Each call is one ``engine.call`` span with a span per stage
+(``engine.stack``, ``engine.plan_runs``, ``engine.lower_configs``,
+``engine.put``, ``engine.scan``, ``engine.fetch``, ``engine.unpack``)
+and one counter record (``engine.spans``).
 
 ``simulate`` and ``simulate_sweep`` are thin compatibility wrappers over
 the same cell program, returning identical ``SimResult`` objects to the
@@ -33,37 +39,15 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.engine import spans
 from repro.core.engine.macro import MACRO_ABORT_REASONS
 from repro.core.engine.state import (SimResult, result_from_stats,
                                      scalars_from_config)
-from repro.core.engine.step import scan_cell
+from repro.core.engine.step import CHUNK, scan_cell
 from repro.core.params import MACRO_KMAX, PCSConfig
 from repro.core.traces import Trace, plan_runs
 
 _BUCKET = 16384
-
-# telemetry of the most recent grid/cells call: macro-executed trace
-# slots vs total trace slots (the benchmarks' macro_hit_rate source),
-# plus the per-reason counts of live macro windows that failed to
-# commit (MACRO_ABORT_REASONS order, summed over all cells)
-_LAST_MACRO = {"macro_ops": 0, "total_ops": 0,
-               "abort_reasons": [0] * len(MACRO_ABORT_REASONS)}
-
-
-def last_macro_hit_rate() -> float:
-    """Fraction of trace slots the latest simulate_* call ran via
-    macro-steps (0.0 when macro was disabled or nothing ran)."""
-    total = _LAST_MACRO["total_ops"]
-    return (_LAST_MACRO["macro_ops"] / total) if total else 0.0
-
-
-def last_macro_abort_reasons() -> dict:
-    """Per-reason counts of live macro candidates the latest simulate_*
-    call failed to commit, keyed by ``MACRO_ABORT_REASONS`` name (all
-    zero when macro was disabled or nothing ran).  Emitted next to
-    ``*_macro_hit`` in BENCH_engine.json so a hit-rate regression can be
-    attributed to a guard instead of bisected blind."""
-    return dict(zip(MACRO_ABORT_REASONS, _LAST_MACRO["abort_reasons"]))
 
 
 def _pad_up(n: int, b: int = _BUCKET) -> int:
@@ -78,7 +62,7 @@ def _stack_traces(traces: Sequence[Trace], bucket: int):
     one bucket across workloads of different sizes changes no result.
     The op axis carries ``MACRO_KMAX`` slots of slack past the longest
     stream (inside the bucket rounding) so the macro-step window slice
-    never clamps, and the macro-run plan is stacked alongside.
+    never clamps.
     """
     C = max(t.ops.shape[0] for t in traces)
     L = _pad_up(max(t.ops.shape[1] for t in traces) + MACRO_KMAX, bucket)
@@ -93,10 +77,14 @@ def _stack_traces(traces: Sequence[Trace], bucket: int):
         addrs[k, :c, :l] = t.addrs
         gaps[k, :c, :l] = t.gaps
         lengths[k, :c] = t.lengths
-    mlen = np.stack([plan_runs(ops[k], addrs[k], gaps[k], MACRO_KMAX)
-                     for k in range(T)])
     n_steps = _pad_up(max(t.total_ops for t in traces), bucket)
-    return ops, addrs, gaps, lengths, mlen, n_steps
+    return ops, addrs, gaps, lengths, n_steps
+
+
+def _plan_runs(ops, addrs, gaps):
+    """The macro-run plan of each stacked trace (``plan_runs``)."""
+    return np.stack([plan_runs(ops[k], addrs[k], gaps[k], MACRO_KMAX)
+                     for k in range(len(ops))])
 
 
 def _stack_configs(configs: Sequence[PCSConfig], max_pbe: int | None,
@@ -190,16 +178,61 @@ def _run_cells(ops, addrs, gaps, lengths, mlen, schemes, sc, *,
     return jax.vmap(cell)(ops, addrs, gaps, lengths, mlen, schemes, sc)
 
 
-def _results_from(out, traces, configs, track_addrs, pairs: bool):
-    (runtimes, stats, durable_ver, n_recov, recov_ns, recov_t,
-     hop_stats, recov_h, recov_l, mops, maborts) = out
-    _LAST_MACRO["macro_ops"] = int(np.sum(mops))
-    _LAST_MACRO["total_ops"] = int(sum(t.total_ops for t in traces)
-                                   * (1 if pairs else len(configs)))
-    _LAST_MACRO["abort_reasons"] = [
+def _execute(program, buffers, configs: Sequence[PCSConfig], max_pbe,
+             n_steps: int, track_addrs: int, macro: bool, single: bool):
+    """Lower the configs, stage the inputs, run one program and fetch
+    its outputs to the host (with a leading trace and config axis of 1
+    each for the ``single`` cell)."""
+    with spans.span("engine.lower_configs"):
+        # static per-tenant stats row count; every config's rows beyond
+        # its own n_tenants stay zero, so mixed tenant counts share one
+        # program
+        n_tenants_max = max(c.n_tenants for c in configs)
+        sc_np, schemes, max_pbe, pm_banks, n_deep, n_leaves = (
+            _stack_configs(configs, max_pbe, n_tenants_max))
+    first = (lambda a: a[0]) if single else (lambda a: a)
+    with jax.enable_x64(True), warnings.catch_warnings():
+        # donated buffers the program cannot alias (dtype/layout) emit a
+        # UserWarning; donation is best-effort here
+        warnings.filterwarnings("ignore", message=".*[Dd]onat")
+        with spans.span("engine.put"):
+            args = [jnp.asarray(first(b)) for b in buffers]
+            args.append(jnp.asarray(first(schemes)))
+            sc = {k: jnp.asarray(first(v), jnp.float64)
+                  for k, v in sc_np.items()}
+        with spans.span("engine.scan"):
+            out = jax.block_until_ready(program(
+                *args, sc, max_pbe=max_pbe, n_steps=n_steps,
+                pm_banks=pm_banks, n_track=track_addrs,
+                n_tenants_max=n_tenants_max, n_deep_max=n_deep,
+                n_leaves_max=n_leaves, macro=macro))
+        with spans.span("engine.fetch"):
+            out = tuple(np.asarray(o) for o in out)
+    return tuple(o[None, None] for o in out) if single else out
+
+
+def _count(rec: spans.Call, mops, maborts, segments, traces,
+           configs, n_steps: int, pairs: bool) -> None:
+    """The call's counters from the program's telemetry outputs."""
+    rec.cells = int(np.size(segments))
+    rec.trace_ops = int(sum(t.total_ops for t in traces)
+                        * (1 if pairs else len(configs)))
+    rec.segments = np.asarray(segments, np.int64).ravel()
+    # the early-exit loop runs until the grid's slowest cell is drained,
+    # then the tail segment runs for every cell
+    rec.steps = int(rec.segments.max()) * CHUNK + n_steps % CHUNK
+    rec.macro_ops = int(np.sum(mops))
+    rec.abort_reasons = dict(zip(MACRO_ABORT_REASONS, (
         int(x) for x in np.sum(
             np.asarray(maborts).reshape(-1, len(MACRO_ABORT_REASONS)),
-            axis=0)]
+            axis=0))))
+
+
+def _results_from(out, traces, configs, track_addrs, rec, n_steps,
+                  pairs: bool):
+    (runtimes, stats, durable_ver, n_recov, recov_ns, recov_t,
+     hop_stats, recov_h, recov_l, mops, maborts, segments) = out
+    _count(rec, mops, maborts, segments, traces, configs, n_steps, pairs)
 
     def cell(i, j, k):
         fab = configs[j].fabric
@@ -247,41 +280,21 @@ def simulate_grid(traces: Sequence[Trace], configs: Sequence[PCSConfig], *,
     """
     if not traces or not configs:
         return [[] for _ in traces]
-    ops, addrs, gaps, lengths, mlen, n_steps = _stack_traces(traces, bucket)
-    # static per-tenant stats row count; every config's rows beyond its
-    # own n_tenants stay zero, so mixed tenant counts share one program
-    n_tenants_max = max(c.n_tenants for c in configs)
-    sc_np, schemes, max_pbe, pm_banks, n_deep, n_leaves = _stack_configs(
-        configs, max_pbe, n_tenants_max)
-    single = len(traces) == 1 and len(configs) == 1
-    with jax.enable_x64(True), warnings.catch_warnings():
-        # donated buffers the program cannot alias (dtype/layout) emit a
-        # UserWarning; donation is best-effort here
-        warnings.filterwarnings("ignore", message=".*[Dd]onat")
-        if single:
-            # 1x1 grid: skip the vmap so the op/scheme switches keep
-            # their branch semantics (~4x less work per scan step)
-            sc = {k: jnp.asarray(v[0], jnp.float64)
-                  for k, v in sc_np.items()}
-            out = _run_cell(
-                jnp.asarray(ops[0]), jnp.asarray(addrs[0]),
-                jnp.asarray(gaps[0]), jnp.asarray(lengths[0]),
-                jnp.asarray(mlen[0]), jnp.asarray(schemes[0]), sc,
-                max_pbe=max_pbe, n_steps=n_steps, pm_banks=pm_banks,
-                n_track=track_addrs, n_tenants_max=n_tenants_max,
-                n_deep_max=n_deep, n_leaves_max=n_leaves, macro=macro)
-            out = tuple(np.asarray(o)[None, None] for o in out)
-        else:
-            sc = {k: jnp.asarray(v, jnp.float64) for k, v in sc_np.items()}
-            out = _run_grid(
-                jnp.asarray(ops), jnp.asarray(addrs), jnp.asarray(gaps),
-                jnp.asarray(lengths), jnp.asarray(mlen),
-                jnp.asarray(schemes), sc,
-                max_pbe=max_pbe, n_steps=n_steps, pm_banks=pm_banks,
-                n_track=track_addrs, n_tenants_max=n_tenants_max,
-                n_deep_max=n_deep, n_leaves_max=n_leaves, macro=macro)
-            out = tuple(np.asarray(o) for o in out)
-    return _results_from(out, traces, configs, track_addrs, pairs=False)
+    with spans.call() as rec:
+        with spans.span("engine.stack"):
+            ops, addrs, gaps, lengths, n_steps = _stack_traces(traces,
+                                                               bucket)
+        with spans.span("engine.plan_runs"):
+            mlen = _plan_runs(ops, addrs, gaps)
+        # 1x1 grid: skip the vmap so the op/scheme switches keep their
+        # branch semantics (~4x less work per scan step)
+        single = len(traces) == 1 and len(configs) == 1
+        out = _execute(_run_cell if single else _run_grid,
+                       (ops, addrs, gaps, lengths, mlen), configs, max_pbe,
+                       n_steps, track_addrs, macro, single)
+        with spans.span("engine.unpack"):
+            return _results_from(out, traces, configs, track_addrs, rec,
+                                 n_steps, pairs=False)
 
 
 def simulate_cells(traces: Sequence[Trace], configs: Sequence[PCSConfig], *,
@@ -302,32 +315,26 @@ def simulate_cells(traces: Sequence[Trace], configs: Sequence[PCSConfig], *,
         return []
     if len(traces) != len(configs):
         raise ValueError("simulate_cells wants len(traces) == len(configs)")
-    # stack unique traces once, then index the stacked arrays per pair
-    uniq: List[Trace] = []
-    index = {}
-    for t in traces:
-        if id(t) not in index:
-            index[id(t)] = len(uniq)
-            uniq.append(t)
-    ops, addrs, gaps, lengths, mlen, n_steps = _stack_traces(uniq, bucket)
-    sel = np.asarray([index[id(t)] for t in traces], np.int32)
-    ops, addrs, gaps = ops[sel], addrs[sel], gaps[sel]
-    lengths, mlen = lengths[sel], mlen[sel]
-    n_tenants_max = max(c.n_tenants for c in configs)
-    sc_np, schemes, max_pbe, pm_banks, n_deep, n_leaves = _stack_configs(
-        configs, max_pbe, n_tenants_max)
-    with jax.enable_x64(True), warnings.catch_warnings():
-        warnings.filterwarnings("ignore", message=".*[Dd]onat")
-        sc = {k: jnp.asarray(v, jnp.float64) for k, v in sc_np.items()}
-        out = _run_cells(
-            jnp.asarray(ops), jnp.asarray(addrs), jnp.asarray(gaps),
-            jnp.asarray(lengths), jnp.asarray(mlen),
-            jnp.asarray(schemes), sc,
-            max_pbe=max_pbe, n_steps=n_steps, pm_banks=pm_banks,
-            n_track=track_addrs, n_tenants_max=n_tenants_max,
-            n_deep_max=n_deep, n_leaves_max=n_leaves, macro=macro)
-        out = tuple(np.asarray(o) for o in out)
-    return _results_from(out, traces, configs, track_addrs, pairs=True)
+    with spans.call() as rec:
+        with spans.span("engine.stack"):
+            # stack unique traces once, then index the stacked arrays
+            # per pair
+            uniq: List[Trace] = []
+            index = {}
+            for t in traces:
+                if id(t) not in index:
+                    index[id(t)] = len(uniq)
+                    uniq.append(t)
+            ops, addrs, gaps, lengths, n_steps = _stack_traces(uniq, bucket)
+            sel = np.asarray([index[id(t)] for t in traces], np.int32)
+            paired = (ops[sel], addrs[sel], gaps[sel], lengths[sel])
+        with spans.span("engine.plan_runs"):
+            mlen = _plan_runs(ops, addrs, gaps)[sel]
+        out = _execute(_run_cells, paired + (mlen,), configs, max_pbe,
+                       n_steps, track_addrs, macro, single=False)
+        with spans.span("engine.unpack"):
+            return _results_from(out, traces, configs, track_addrs, rec,
+                                 n_steps, pairs=True)
 
 
 def simulate(trace: Trace, config: PCSConfig,

@@ -33,40 +33,32 @@ surviving Dirty/Drain PBEs.
 
 ``scan_cell`` is the unjitted single-cell program; the front-ends in
 ``engine.grid`` wrap it in ``jax.jit`` (single cell) or
-``jit(vmap(vmap(...)))`` (full trace x config grid).  A module-level
-compile counter increments once per trace of ``scan_cell`` — i.e. once
-per XLA program built — backing the one-compilation acceptance test and
-the BENCH_engine.json perf tracking.  ``return_state=True`` traces
-(the padding-invariant tests' state-introspection path) are excluded
-from the counter: they are test-only retraces of an already-counted
-program shape, and counting them double-billed suites that mix both.
+``jit(vmap(vmap(...)))`` (full trace x config grid).  Each trace of
+``scan_cell`` — i.e. each XLA program built — ticks
+``spans.compile_count()``, which backs the one-compilation acceptance
+tests and the benchmark's ``window_compiles`` check.
+``return_state=True`` traces (the padding-invariant tests'
+state-introspection path) are excluded from the counter: they are
+test-only retraces of an already-counted program shape, and counting
+them double-billed suites that mix both.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
+from repro.core.engine import spans
 from repro.core.engine.handlers import HANDLERS, StepCtx, recovery_snapshot
 from repro.core.engine.macro import MACRO_ABORT_REASONS, macro_step
 from repro.core.engine.state import (EPOCH_KEYS, INF, MachineState,
                                      init_state)
 from repro.core.params import MACRO_KMAX, Op
 
-# Incremented inside `scan_cell` at trace time: one tick per XLA program
-# built from the engine (jit cache hits do not retrace; test-only
-# return_state traces are excluded, see module docstring).
-_COMPILES = [0]
-
 # Steps per inner scan segment of the chunked driver.  Segment
 # boundaries only ever skip provable no-op steps (every core past its
 # stream end), so results are invariant to this constant; it trades
 # while_loop trip overhead against wasted post-exhaustion steps.
 CHUNK = 128
-
-
-def compile_count() -> int:
-    """Number of engine XLA programs traced/compiled so far this process."""
-    return _COMPILES[0]
 
 
 def resolve_epoch_sc(sc, t_issue):
@@ -109,7 +101,7 @@ def scan_cell(ops, addrs, gaps, lengths, scheme, sc, *,
 
     Returns ``(runtime, stats, durable_ver, n_recovered, recovery_ns,
     recovered_per_tenant, hop_stats, recovered_per_hop,
-    recovered_per_leaf, macro_ops, macro_aborts)``, plus the final
+    recovered_per_leaf, macro_ops, macro_aborts, segments)``, plus the final
     :class:`MachineState` when ``return_state`` is set (used by the
     padding-invariant tests).  ``scheme`` and every entry of ``sc`` are
     traced scalars; only array shapes (core count C, ``max_pbe``,
@@ -124,7 +116,10 @@ def scan_cell(ops, addrs, gaps, lengths, scheme, sc, *,
     aggregate cell.  ``macro_aborts`` is the per-reason count of live
     macro candidates that failed to commit
     (:data:`~repro.core.engine.macro.MACRO_ABORT_REASONS` order, all
-    zero when ``macro`` is off).
+    zero when ``macro`` is off).  ``segments`` is the number of
+    ``CHUNK``-step segments the early-exit loop ran for this cell: under
+    ``vmap`` each cell's count stops when its own streams are drained,
+    while the loop runs on for the grid's slowest cell.
 
     ``macro=True`` (static) enables the macro-stepping fast path;
     ``mlen`` is the (C, L) int8 run plan from
@@ -141,7 +136,7 @@ def scan_cell(ops, addrs, gaps, lengths, scheme, sc, *,
     (``core.traces.tenant_ids`` is the numpy twin of this mapping).
     """
     if not return_state:
-        _COMPILES[0] += 1
+        spans.count_compile()
     use_macro = bool(macro) and mlen is not None
     C = ops.shape[0]
     slot_ids = jnp.arange(max_pbe)
@@ -237,6 +232,7 @@ def scan_cell(ops, addrs, gaps, lengths, scheme, sc, *,
              jnp.zeros((), jnp.int32),
              jnp.zeros((len(MACRO_ABORT_REASONS),), jnp.int32))
     n_full, n_tail = divmod(n_steps, CHUNK)
+    segments = jnp.zeros((), jnp.int32)
     if n_full > 0:
         def more_work(loop):
             k, (st, _mops, _mab) = loop
@@ -246,7 +242,7 @@ def scan_cell(ops, addrs, gaps, lengths, scheme, sc, *,
             k, seg_carry = loop
             return k + 1, segment(seg_carry, CHUNK)
 
-        _, carry = jax.lax.while_loop(
+        segments, carry = jax.lax.while_loop(
             more_work, run_segment, (jnp.asarray(0, jnp.int32), carry))
     if n_tail > 0:
         carry = segment(carry, n_tail)
@@ -260,5 +256,5 @@ def scan_cell(ops, addrs, gaps, lengths, scheme, sc, *,
      recov_l) = recovery_snapshot(
         final, scheme, sc, slot_active, pm_banks, n_track)
     out = (runtime, final.stats, durable_ver, n_recov, recov_ns, recov_t,
-           final.hop_stats, recov_h, recov_l, mops, maborts)
+           final.hop_stats, recov_h, recov_l, mops, maborts, segments)
     return out + (final,) if return_state else out

@@ -53,12 +53,13 @@ def paper_inputs():
     """Stacked paper-budget inputs of the default {7 workloads x NoPB/PB/
     PB_RF} grid, as ``simulate_grid`` stages them."""
     from repro.core import PCSConfig, Scheme, WORKLOADS, make_trace
-    from repro.core.engine.grid import _stack_configs, _stack_traces
+    from repro.core.engine.grid import (_plan_runs, _stack_configs,
+                                        _stack_traces)
     traces = [make_trace(n, persist_budget=PAPER_BUDGET) for n in WORKLOADS]
     configs = [PCSConfig(scheme=s)
                for s in (Scheme.NOPB, Scheme.PB, Scheme.PB_RF)]
-    ops, addrs, gaps, lengths, mlen, n_steps = _stack_traces(traces,
-                                                             PAPER_BUCKET)
+    ops, addrs, gaps, lengths, n_steps = _stack_traces(traces, PAPER_BUCKET)
+    mlen = _plan_runs(ops, addrs, gaps)
     n_tenants_max = max(c.n_tenants for c in configs)
     sc, schemes, max_pbe, pm_banks, n_deep, n_leaves = _stack_configs(
         configs, None, n_tenants_max)
@@ -105,7 +106,6 @@ def _kernel_cases():
     from repro.kernels.ssd_scan import ssd_scan_pallas
     from repro.kernels.tat_lookup import tat_lookup_pallas
     f32, i32 = jnp.float32, jnp.int32
-    # the widths of benchmarks/kernel_bench.py
     return {
         "tat_lookup": (
             functools.partial(tat_lookup_pallas, interpret=False),
