@@ -134,8 +134,9 @@ _DONATED = ("ops", "addrs", "gaps", "mlen")
 def _run_cell(ops, addrs, gaps, lengths, mlen, scheme, sc, *,
               max_pbe, n_steps, pm_banks, n_track, n_tenants_max,
               n_deep_max, n_leaves_max, macro):
-    # single-cell program: no batch axes, so `lax.switch` lowers to real
-    # branches instead of vmap's execute-all-and-select
+    # single-cell program: no batch axes, so `lax.switch` and the macro
+    # gate's `lax.cond` lower to real branches instead of vmap's
+    # execute-all-and-select
     return scan_cell(ops, addrs, gaps, lengths, scheme, sc,
                      max_pbe=max_pbe, n_steps=n_steps, pm_banks=pm_banks,
                      n_track=n_track, n_tenants_max=n_tenants_max,
@@ -144,14 +145,16 @@ def _run_cell(ops, addrs, gaps, lengths, mlen, scheme, sc, *,
 
 
 def _cell_fn(max_pbe, n_steps, pm_banks, n_track, n_tenants_max,
-             n_deep_max, n_leaves_max, macro):
+             n_deep_max, n_leaves_max, macro, axis_names):
+    # ``axis_names``: the vmap axes the cell runs under, over which the
+    # macro gate reduces (engine.step)
     def cell(ops, addrs, gaps, lengths, mlen, scheme, sc):
         return scan_cell(ops, addrs, gaps, lengths, scheme, sc,
                          max_pbe=max_pbe, n_steps=n_steps,
                          pm_banks=pm_banks, n_track=n_track,
                          n_tenants_max=n_tenants_max,
                          n_deep_max=n_deep_max, n_leaves_max=n_leaves_max,
-                         mlen=mlen, macro=macro)
+                         mlen=mlen, macro=macro, axis_names=axis_names)
     return cell
 
 
@@ -161,9 +164,11 @@ def _run_grid(ops, addrs, gaps, lengths, mlen, schemes, sc, *,
               max_pbe, n_steps, pm_banks, n_track, n_tenants_max,
               n_deep_max, n_leaves_max, macro):
     cell = _cell_fn(max_pbe, n_steps, pm_banks, n_track, n_tenants_max,
-                    n_deep_max, n_leaves_max, macro)
-    over_cfg = jax.vmap(cell, in_axes=(None, None, None, None, None, 0, 0))
-    over_tr = jax.vmap(over_cfg, in_axes=(0, 0, 0, 0, 0, None, None))
+                    n_deep_max, n_leaves_max, macro, ("tr", "cfg"))
+    over_cfg = jax.vmap(cell, in_axes=(None, None, None, None, None, 0, 0),
+                        axis_name="cfg")
+    over_tr = jax.vmap(over_cfg, in_axes=(0, 0, 0, 0, 0, None, None),
+                       axis_name="tr")
     return over_tr(ops, addrs, gaps, lengths, mlen, schemes, sc)
 
 
@@ -174,8 +179,9 @@ def _run_cells(ops, addrs, gaps, lengths, mlen, schemes, sc, *,
                n_deep_max, n_leaves_max, macro):
     # flat pairing: one shared batch axis over traces AND configs
     cell = _cell_fn(max_pbe, n_steps, pm_banks, n_track, n_tenants_max,
-                    n_deep_max, n_leaves_max, macro)
-    return jax.vmap(cell)(ops, addrs, gaps, lengths, mlen, schemes, sc)
+                    n_deep_max, n_leaves_max, macro, ("cell",))
+    return jax.vmap(cell, axis_name="cell")(ops, addrs, gaps, lengths, mlen,
+                                            schemes, sc)
 
 
 def _execute(program, buffers, configs: Sequence[PCSConfig], max_pbe,
@@ -211,7 +217,7 @@ def _execute(program, buffers, configs: Sequence[PCSConfig], max_pbe,
     return tuple(o[None, None] for o in out) if single else out
 
 
-def _count(rec: spans.Call, mops, maborts, segments, traces,
+def _count(rec: spans.Call, mops, maborts, segments, gate_steps, traces,
            configs, n_steps: int, pairs: bool) -> None:
     """The call's counters from the program's telemetry outputs."""
     rec.cells = int(np.size(segments))
@@ -221,6 +227,8 @@ def _count(rec: spans.Call, mops, maborts, segments, traces,
     # the early-exit loop runs until the grid's slowest cell is drained,
     # then the tail segment runs for every cell
     rec.steps = int(rec.segments.max()) * CHUNK + n_steps % CHUNK
+    # the gate is grid-wide; the slowest cell saw every step
+    rec.macro_gate_steps = int(np.max(gate_steps))
     rec.macro_ops = int(np.sum(mops))
     rec.abort_reasons = dict(zip(MACRO_ABORT_REASONS, (
         int(x) for x in np.sum(
@@ -231,8 +239,9 @@ def _count(rec: spans.Call, mops, maborts, segments, traces,
 def _results_from(out, traces, configs, track_addrs, rec, n_steps,
                   pairs: bool):
     (runtimes, stats, durable_ver, n_recov, recov_ns, recov_t,
-     hop_stats, recov_h, recov_l, mops, maborts, segments) = out
-    _count(rec, mops, maborts, segments, traces, configs, n_steps, pairs)
+     hop_stats, recov_h, recov_l, mops, maborts, segments, gate_steps) = out
+    _count(rec, mops, maborts, segments, gate_steps, traces, configs,
+           n_steps, pairs)
 
     def cell(i, j, k):
         fab = configs[j].fabric

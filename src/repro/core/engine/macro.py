@@ -33,8 +33,38 @@ provable no-ops that only advance its cursor and clock — those are
 collapsed ``MACRO_KMAX`` at a time with no guard beyond gap
 non-negativity (dead ops touch no shared state, so they commute with
 every other core's ops bit-exactly).
+
+The replay is an 8-slot serial loop, and under the grid's ``vmap`` it
+cannot be branched around per cell.  :func:`macro_gate` is a cheap,
+exact pre-filter the step driver evaluates first, from the window's
+head alone (:func:`macro_window`): it says when a window *cannot*
+commit, so the driver skips the replay on grid steps where no cell of
+the grid can (``engine.step``).  It rests on a lower bound on the
+window's last issue time ``t_last`` that needs no replay:
+
+    t_last >= t_issue + sum_{1 <= j < k_live} (gap_j + lat_lo)
+
+where ``lat_lo`` is the least latency any replayed op can take.  Proof:
+op ``j`` issues at ``t_j = clk_j + gap_j``, and the mini-interpreter
+sets ``clk_{j+1}`` to the op's completion — a PM read's response
+``max(bank_busy, t_j + ow_cpu_pm) + nvm_read + ow_cpu_pm``, a NoPB
+persist's ack ``max(bank_busy, t_j + ow_cpu_pm) + nvm_write +
+ow_cpu_pm``, a buffered persist's ack ``max(pbc_busy, t_j + ow_cpu_sw1)
++ pbc_proc_ns + tag_ns + data_ns + ow_cpu_sw1`` — each at least ``t_j``
+plus the smallest of ``2 ow_cpu_pm + min(nvm_read, nvm_write)`` and
+``2 ow_cpu_sw1 + pbc_proc_ns + tag_ns + data_ns`` (= ``lat_lo``; at
+least two one-way link crossings), since ``max(b, x) >= x``.  Induction
+from ``t_0 = t_issue`` gives the bound in exact arithmetic.  The gate
+adds only *half* of ``lat_lo`` per op: the other half is slack for
+rounding, which in float64 (IEEE, or the chip's emulated float pairs)
+stays many orders of magnitude below ``lat_lo / 2`` (73 ns or more with
+Table I's latencies) for any simulated time below ~10^12 ns.  A window is then sure to
+fail the interleave guard where ``others_min <= lb``, and where
+``lat_lo <= 0`` the gate proves nothing and lets every window through.
 """
 from __future__ import annotations
+
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -65,9 +95,96 @@ MACRO_ABORT_REASONS = ("window", "fabric", "deep", "epoch_boundary",
                        "interleave", "guard")
 
 
-def macro_step(ctx, st, ops, addrs, gaps64, lengths, mlen, tsel,
-               valid, live, t_issue, i, *, kmax: int,
-               next_epoch_bound=None):
+class Window(NamedTuple):
+    """The selected core's window as the step sees it before a replay."""
+    w_gap: jax.Array        # (kmax,) f64 gaps from the cursor on
+    k_cap: jax.Array        # slots left in the stream, at most kmax
+    k_live: jax.Array       # planned run length at the cursor, <= k_cap
+    cand: jax.Array         # a live op heads the window
+    elig: jax.Array         # ... and the plan gives it >= 2 ops
+    fab_ok: jax.Array       # not a multi-leaf fabric cell (or NoPB)
+    deep_ok: jax.Array      # not a >= 2-switch chain cell (or NoPB)
+    others_min: jax.Array   # earliest next issue time of the other cores
+
+
+def macro_window(ctx, gaps64, lengths, mlen, tsel, valid, live, i, *,
+                 kmax: int) -> Window:
+    """The window at core ``ctx.c``'s cursor ``i``, read from the run
+    plan ``mlen`` and the issue times ``tsel`` without replaying it."""
+    sc = ctx.sc
+    c = ctx.c
+    # the grid pads L by kmax slots so the slice never clamps (see
+    # grid._stack_traces)
+    w_gap = jax.lax.dynamic_slice(
+        gaps64, (c.astype(jnp.int32), i.astype(jnp.int32)), (1, kmax))[0]
+    k_cap = jnp.clip(lengths[c] - i, 0, kmax)
+    k_live = jnp.minimum(mlen[c, i].astype(jnp.int32), k_cap)
+    is_nopb = ctx.scheme == 0                       # Scheme.NOPB
+    cand = valid & live
+    return Window(
+        w_gap=w_gap, k_cap=k_cap, k_live=k_live, cand=cand,
+        elig=cand & (k_live >= 2),
+        # multi-leaf fabric cells scope hop-1 state to the issuing
+        # tenant's leaf and may defer drains on spine backpressure —
+        # neither is modelled by the mini-interpreter (a fabric forces
+        # n_switches = 2, so deep_ok already aborts these; fab_ok
+        # attributes the abort)
+        fab_ok=is_nopb | (sc["n_leaves"] < 2.0),
+        # chain cells (>= 2 switches) take the deep persist/read legs
+        # the mini-interpreter does not model; their dead tails still
+        # collapse
+        deep_ok=is_nopb | (sc["n_switches"] < 2.0),
+        # no other core may issue inside the window (strict: argmin
+        # ties break by index, so equality must abort too)
+        others_min=jnp.min(tsel.at[c].set(INF)))
+
+
+def _abort_vec(win: Window, ep_ok, no_ilv, guard):
+    """The one-hot ``MACRO_ABORT_REASONS`` vector of a window: each
+    live candidate that fails to commit counts its first failing gate."""
+    gated = win.elig & win.fab_ok & win.deep_ok
+    return jnp.stack([
+        win.cand & (win.k_live < 2),
+        win.elig & ~win.fab_ok,
+        win.elig & win.fab_ok & ~win.deep_ok,
+        gated & ~ep_ok,
+        gated & ep_ok & ~no_ilv,
+        gated & ep_ok & no_ilv & ~guard,
+    ]).astype(jnp.int32)
+
+
+def macro_gate(win: Window, sc, t_issue, valid, live):
+    """Whether :func:`macro_step` can commit anything at this window.
+
+    Returns ``(want, abort_vec)``.  Where ``want`` is False,
+    ``macro_step`` provably returns ``use_macro`` False and exactly
+    ``abort_vec`` (module docstring: the lower bound on ``t_last``), so
+    the replay may be skipped.  ``want`` holds for an eligible window
+    that passes the fabric and deep gates and whose every other core
+    issues after the bound ``lb``, and for a dead run of >= 2 slots.
+    Not for epoch-scheduled grids: ``epoch_boundary`` comes before
+    ``interleave`` in the attribution, and telling them apart needs
+    ``t_last`` itself.
+    """
+    lat_lo = jnp.minimum(
+        2.0 * sc["ow_cpu_pm"] + jnp.minimum(sc["nvm_read"], sc["nvm_write"]),
+        2.0 * sc["ow_cpu_sw1"] + sc["pbc_proc_ns"] + sc["tag_ns"]
+        + sc["data_ns"])
+    floor = 0.5 * lat_lo
+    j = jnp.arange(win.w_gap.shape[0])
+    lb = t_issue + jnp.sum(jnp.where((j >= 1) & (j < win.k_live),
+                                     win.w_gap + floor, 0.0))
+    may_fit = (floor <= 0.0) | (win.others_min > lb)
+    want = ((win.elig & win.fab_ok & win.deep_ok & may_fit)
+            | (valid & ~live & (win.k_cap >= 2)))
+    # skipped: no epoch gate, and every window the fabric and deep
+    # gates pass failed the interleave guard
+    true, false = jnp.asarray(True), jnp.asarray(False)
+    return want, _abort_vec(win, ep_ok=true, no_ilv=false, guard=true)
+
+
+def macro_step(ctx, st, ops, addrs, win: Window, valid, live, t_issue, i,
+               *, kmax: int, next_epoch_bound=None):
     """Candidate macro execution of up to ``kmax`` ops of core ``ctx.c``.
 
     Returns ``(st_macro, use_macro, k_adv, abort_vec)``: the candidate
@@ -77,6 +194,7 @@ def macro_step(ctx, st, ops, addrs, gaps64, lengths, mlen, tsel,
     (all-zero when the window committed or no live candidate existed).
     The caller selects ``st_macro`` over the slot-step result and
     advances the cursor by ``k_adv`` when ``use_macro`` is set.
+    ``win`` is :func:`macro_window` at the head op.
 
     ``next_epoch_bound`` is the first epoch boundary strictly after the
     head op's issue time in an epoch-scheduled grid (``INF`` inside the
@@ -100,9 +218,7 @@ def macro_step(ctx, st, ops, addrs, gaps64, lengths, mlen, tsel,
     i32 = i.astype(jnp.int32)
     w_ops = jax.lax.dynamic_slice(ops, (c32, i32), (1, kmax))[0]
     w_addr = jax.lax.dynamic_slice(addrs, (c32, i32), (1, kmax))[0]
-    w_gap = jax.lax.dynamic_slice(gaps64, (c32, i32), (1, kmax))[0]
-    rem = lengths[c] - i
-    k_cap = jnp.clip(rem, 0, kmax)
+    w_gap, k_cap, k_live = win.w_gap, win.k_cap, win.k_live
 
     # ---------------- dead-run collapse (post-crash stream drain) ------
     # Each dead step sets clock[c] to its issue time and bumps the
@@ -117,18 +233,9 @@ def macro_step(ctx, st, ops, addrs, gaps64, lengths, mlen, tsel,
     st_dead = st._replace(clock=st.clock.at[c].set(clk_dead))
 
     # ---------------- live window (exact mini-interpreter) -------------
-    k_live = jnp.minimum(mlen[c, i].astype(jnp.int32), k_cap)
     is_nopb = ctx.scheme == 0                       # Scheme.NOPB
     is_rf = ctx.scheme == 2                         # Scheme.PB_RF
     pb_like = ~is_nopb
-    # chain cells (>= 2 switches) take the deep persist/read legs the
-    # mini-interpreter does not model; their dead tails still collapse
-    deep_ok = is_nopb | (sc["n_switches"] < 2.0)
-    # multi-leaf fabric cells additionally scope hop-1 state to the
-    # issuing tenant's leaf and may defer drains on spine backpressure —
-    # neither is modelled here (a fabric forces n_switches = 2, so
-    # deep_ok already aborts these; fab_ok attributes the abort)
-    fab_ok = is_nopb | (sc["n_leaves"] < 2.0)
     # per-leaf PBC clocks: in a grid carrying the fabric axis the
     # handlers serve hop-1 PBC time from lpbc[leaf(tenant)], so the
     # mini-interpreter must read/write the same cell (the window's
@@ -323,10 +430,7 @@ def macro_step(ctx, st, ops, addrs, gaps64, lengths, mlen, tsel,
      guard, t_last), _ = jax.lax.scan(
         win_op, carry0, (jnp.arange(kmax), w_ops, w_addr, w_gap))
 
-    # no other core may issue inside the window (strict: argmin ties
-    # break by index, so equality must abort too)
-    others_min = jnp.min(tsel.at[c].set(INF))
-    no_ilv = others_min > t_last
+    no_ilv = win.others_min > t_last
     # epoch-scheduled grids: the whole window must live in the head
     # op's epoch (boundary instants belong to the *next* epoch, so the
     # last issue time must be strictly below the next boundary)
@@ -334,21 +438,11 @@ def macro_step(ctx, st, ops, addrs, gaps64, lengths, mlen, tsel,
         ep_ok = jnp.asarray(True)
     else:
         ep_ok = t_last < next_epoch_bound
-    live_ok = (valid & live & (k_live >= 2) & fab_ok & deep_ok & ep_ok
-               & guard & no_ilv)
-
+    live_ok = (win.elig & win.fab_ok & win.deep_ok & ep_ok & guard
+               & no_ilv)
     # prioritized abort attribution (MACRO_ABORT_REASONS order): each
     # live candidate that failed to commit counts exactly one reason
-    cand = valid & live
-    elig = cand & (k_live >= 2)
-    abort_vec = jnp.stack([
-        cand & (k_live < 2),
-        elig & ~fab_ok,
-        elig & fab_ok & ~deep_ok,
-        elig & fab_ok & deep_ok & ~ep_ok,
-        elig & fab_ok & deep_ok & ep_ok & ~no_ilv,
-        elig & fab_ok & deep_ok & ep_ok & no_ilv & ~guard,
-    ]).astype(jnp.int32)
+    abort_vec = _abort_vec(win, ep_ok, no_ilv, guard)
 
     if NL > 0:
         pbc_kw = dict(lpbc=st.lpbc.at[my_leaf].set(pbc_cur))

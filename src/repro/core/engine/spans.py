@@ -16,6 +16,8 @@ What reads each (the benchmark's per-layer metrics, ``bench/metrics/``):
   ``engine.put``, ``engine.fetch``, ``engine.unpack`` — ``host_span_ms``;
 * :attr:`Call.macro_ops` over :attr:`Call.trace_ops`
   (:func:`last_macro_hit_rate`) — ``macro_hit``;
+* :attr:`Call.macro_gate_steps` over :attr:`Call.steps` —
+  ``macro_gate_open``;
 * :func:`jax_seconds` as :attr:`Call.jax_s` of the first timed call —
   ``setup_trace_s``, ``setup_lower_s``, ``setup_compile_s``;
 * :func:`compile_count` — the benchmark's ``window_compiles`` check and
@@ -64,6 +66,7 @@ class Call:
     segments: Optional[np.ndarray] = None   # CHUNK-step segments, per cell
     steps: int = 0                  # grid steps executed
     macro_ops: int = 0              # trace slots committed by macro-steps
+    macro_gate_steps: int = 0       # grid steps the macro replay ran on
     abort_reasons: Dict[str, int] = dataclasses.field(
         default_factory=lambda: dict.fromkeys(MACRO_ABORT_REASONS, 0))
     compiles: int = 0               # engine programs built by the call

@@ -16,7 +16,16 @@ Two step-count optimizations ride on that no-op property:
     (``mlen``) marks an eligible homogeneous window at the selected
     core's cursor, the step executes up to ``MACRO_KMAX`` ops at once
     behind a traced guard conjunction, falling back to the
-    slot-at-a-time handlers on guard failure — bit-exact either way;
+    slot-at-a-time handlers on guard failure — bit-exact either way.
+    The replay sits behind one **grid-wide gate**: ``macro.macro_gate``
+    proves from the window's head alone (a lower bound on its last
+    issue time) that a cell cannot commit, the step ORs that over every
+    cell of the grid (``lax.psum`` over the front-end's named ``vmap``
+    axes, which makes the predicate unbatched) and a ``lax.cond`` skips
+    the replay when no cell can commit, returning the abort vector the
+    replay would have given.  Epoch-scheduled grids keep the full
+    replay (their ``epoch_boundary`` attribution needs the replay's
+    last issue time); the choice is static, from the ``sc`` keys;
   * **chunked early exit**: the scan runs in ``CHUNK``-step segments
     under a ``while_loop`` that stops at the first segment boundary
     where every core has drained its stream, so bucket-padded
@@ -49,7 +58,8 @@ import jax.numpy as jnp
 
 from repro.core.engine import spans
 from repro.core.engine.handlers import HANDLERS, StepCtx, recovery_snapshot
-from repro.core.engine.macro import MACRO_ABORT_REASONS, macro_step
+from repro.core.engine.macro import (MACRO_ABORT_REASONS, macro_gate,
+                                    macro_step, macro_window)
 from repro.core.engine.state import (EPOCH_KEYS, INF, MachineState,
                                      init_state)
 from repro.core.params import MACRO_KMAX, Op
@@ -96,12 +106,14 @@ def scan_cell(ops, addrs, gaps, lengths, scheme, sc, *,
               n_tenants_max: int = 1, n_deep_max: int = 0,
               n_leaves_max: int = 1,
               mlen=None, macro: bool = False,
+              axis_names: tuple = (),
               return_state: bool = False):
     """Simulate one (trace, config) cell.
 
     Returns ``(runtime, stats, durable_ver, n_recovered, recovery_ns,
     recovered_per_tenant, hop_stats, recovered_per_hop,
-    recovered_per_leaf, macro_ops, macro_aborts, segments)``, plus the final
+    recovered_per_leaf, macro_ops, macro_aborts, segments,
+    gate_steps)``, plus the final
     :class:`MachineState` when ``return_state`` is set (used by the
     padding-invariant tests).  ``scheme`` and every entry of ``sc`` are
     traced scalars; only array shapes (core count C, ``max_pbe``,
@@ -119,7 +131,14 @@ def scan_cell(ops, addrs, gaps, lengths, scheme, sc, *,
     zero when ``macro`` is off).  ``segments`` is the number of
     ``CHUNK``-step segments the early-exit loop ran for this cell: under
     ``vmap`` each cell's count stops when its own streams are drained,
-    while the loop runs on for the grid's slowest cell.
+    while the loop runs on for the grid's slowest cell.  ``gate_steps``
+    counts the steps on which the macro gate opened (the replay ran):
+    every cell of a grid sees the same gate, so the slowest cell's
+    count is the grid's (0 when ``macro`` is off).
+
+    ``axis_names`` (static) names the ``vmap`` axes the caller maps this
+    cell over, so the macro gate can reduce over the whole grid; ``()``
+    (one cell, no batch axes) makes it a per-cell branch.
 
     ``macro=True`` (static) enables the macro-stepping fast path;
     ``mlen`` is the (C, L) int8 run plan from
@@ -158,7 +177,7 @@ def scan_cell(ops, addrs, gaps, lengths, scheme, sc, *,
     gaps64 = gaps.astype(jnp.float64)
 
     def step(carry, _):
-        st, mops, maborts = carry
+        st, mops, maborts, gsteps = carry
         active = st.ptr < lengths
         idx = jnp.minimum(st.ptr, jnp.maximum(lengths - 1, 0))
         next_gap = gaps64[core_ids, idx]
@@ -188,15 +207,32 @@ def scan_cell(ops, addrs, gaps, lengths, scheme, sc, *,
         st2 = jax.lax.switch(jnp.clip(op, 0, 5), branches, st)
 
         if use_macro:
-            st_m, took, k_m, ab_vec = macro_step(
-                ctx, st, ops, addrs, gaps64, lengths, mlen, tsel,
-                valid, live, t_issue, i, kmax=MACRO_KMAX,
-                next_epoch_bound=next_bound)
-            st2 = jax.tree_util.tree_map(
-                lambda a, b: jnp.where(took, a, b), st_m, st2)
-            adv = jnp.where(took, k_m, 1)
-            mops = mops + jnp.where(took, k_m, 0)
+            win = macro_window(ctx, gaps64, lengths, mlen, tsel, valid,
+                               live, i, kmax=MACRO_KMAX)
+
+            def replay(s):
+                st_m, took, k_m, ab_vec = macro_step(
+                    ctx, st, ops, addrs, win, valid, live, t_issue, i,
+                    kmax=MACRO_KMAX, next_epoch_bound=next_bound)
+                s = jax.tree_util.tree_map(
+                    lambda a, b: jnp.where(took, a, b), st_m, s)
+                return s, took, jnp.where(took, k_m, 1), ab_vec
+
+            if next_bound is None:
+                want, ab_skip = macro_gate(win, sc_op, t_issue, valid, live)
+                gate = (jax.lax.psum(want.astype(jnp.int32), axis_names) > 0
+                        if axis_names else want)
+                st2, took, adv, ab_vec = jax.lax.cond(
+                    gate, replay,
+                    lambda s: (s, jnp.asarray(False),
+                               jnp.asarray(1, jnp.int32), ab_skip),
+                    st2)
+            else:
+                gate = jnp.asarray(True)
+                st2, took, adv, ab_vec = replay(st2)
+            mops = mops + jnp.where(took, adv, 0)
             maborts = maborts + ab_vec
+            gsteps = gsteps + gate.astype(jnp.int32)
         else:
             took = jnp.asarray(False)
             adv = 1
@@ -222,7 +258,7 @@ def scan_cell(ops, addrs, gaps, lengths, scheme, sc, *,
         clock = st2.clock.at[c].set(
             jnp.where(valid & ~live & ~took, t_issue, st2.clock[c]))
         return (st2._replace(clock=clock, ptr=ptr, blocked=blocked,
-                             bcount=bcount), mops, maborts), None
+                             bcount=bcount), mops, maborts, gsteps), None
 
     def segment(carry, length):
         return jax.lax.scan(step, carry, None, length=length)[0]
@@ -230,12 +266,13 @@ def scan_cell(ops, addrs, gaps, lengths, scheme, sc, *,
     carry = (init_state(C, max_pbe, pm_banks, n_track, n_tenants_max,
                         n_deep_max, n_leaves_max),
              jnp.zeros((), jnp.int32),
-             jnp.zeros((len(MACRO_ABORT_REASONS),), jnp.int32))
+             jnp.zeros((len(MACRO_ABORT_REASONS),), jnp.int32),
+             jnp.zeros((), jnp.int32))
     n_full, n_tail = divmod(n_steps, CHUNK)
     segments = jnp.zeros((), jnp.int32)
     if n_full > 0:
         def more_work(loop):
-            k, (st, _mops, _mab) = loop
+            k, (st, *_) = loop
             return (k < n_full) & jnp.any(st.ptr < lengths)
 
         def run_segment(loop):
@@ -246,7 +283,7 @@ def scan_cell(ops, addrs, gaps, lengths, scheme, sc, *,
             more_work, run_segment, (jnp.asarray(0, jnp.int32), carry))
     if n_tail > 0:
         carry = segment(carry, n_tail)
-    final, mops, maborts = carry
+    final, mops, maborts, gsteps = carry
     # a crashed run ends at the power loss: dead cores advanced their
     # clocks through never-executed ops, so cap at the crash instant
     runtime = jnp.max(jnp.where(final.clock < INF * 0.5,
@@ -256,5 +293,6 @@ def scan_cell(ops, addrs, gaps, lengths, scheme, sc, *,
      recov_l) = recovery_snapshot(
         final, scheme, sc, slot_active, pm_banks, n_track)
     out = (runtime, final.stats, durable_ver, n_recov, recov_ns, recov_t,
-           final.hop_stats, recov_h, recov_l, mops, maborts, segments)
+           final.hop_stats, recov_h, recov_l, mops, maborts, segments,
+           gsteps)
     return out + (final,) if return_state else out
