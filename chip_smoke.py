@@ -16,8 +16,8 @@ exits non-zero and no result line is printed:
    differential (engine on the chip, oracle on the host), macro-stepping
    on against off on the smoke grid (bit-exact), and the smoke grid on
    the chip against the host CPU backend (integer counts exact, float
-   fields within ``CPU_REL_TOL``, except the cells of ``CPU_DIVERGENT``,
-   which must differ and are printed).
+   fields within ``CPU_REL_TOL``; a cell of ``CPU_DIVERGENT`` must
+   differ instead, and every differing cell is printed).
 
 The last line of stdout is one JSON object naming the device.
 """
@@ -57,18 +57,19 @@ PAPER_BUCKET = 16384
 # stay the paper's.
 PAPER_WORKLOADS = ["fft", "lu_cont", "lu_non", "radiosity", "raytrace"]
 PAPER_MEAN_SPEEDUP = {"PB": 12.0, "PB_RF": 15.0}   # % over NoPB, paper
-# The engine keeps time in float64, which the TPU emulates with pairs of
-# float32 (about 48 significand bits, relative rounding ~3.6e-15 per
-# operation).  A time field is a sum over at most ~1e4 scan steps on the
-# smoke grid, so emulation alone stays below ~1e-10 relative; a float32
-# demotion anywhere on a time path shows up near 1e-7.
+# The engine keeps time in time words (``engine.timebase``: IEEE binary64
+# bit patterns in int64), which round the same on the chip as on the CPU,
+# so every decision and every time field agrees exactly.  Only the
+# statistics stay float64, which the TPU emulates with pairs of float32
+# (about 48 significand bits, relative rounding ~3.6e-15 per operation):
+# a latency sum over at most ~1e4 scan steps on the smoke grid stays
+# below ~1e-10 relative; a float32 demotion shows up near 1e-7.
 CPU_REL_TOL = 1e-9
-# The smoke cells whose chip result is a different trajectory from the
-# CPU's: PB latencies such as 0.388 ns are not exact in float32 pairs, so
-# chip times differ from the CPU's by ~1e-13 relative, and in this cell
-# a near-tie decision flips (ROADMAP S3).  Pinned exactly: any other
-# cell diverging, or this one converging, fails the run.
-CPU_DIVERGENT = {"lu_non/PB_RF"}
+# Smoke cells whose chip result may be another trajectory than the
+# CPU's.  None since time rounds like IEEE on the chip (before, PB_RF
+# under lu_non flipped a near-tie: ROADMAP S3).  Pinned exactly: any cell
+# diverging fails the run.
+CPU_DIVERGENT: set = set()
 
 
 def _compare(a: SimResult, b: SimResult, rel_tol: float):
@@ -241,7 +242,7 @@ def phase_correctness():
         print(f"chip vs CPU: {cell} differs (chip vs CPU): " + "; ".join(
             _show(f, getattr(chip[i][j], f), getattr(host[i][j], f))
             for f in bad))
-    print(f"chip vs CPU: emulated float64 diverges in {sorted(diverged)} "
+    print(f"chip vs CPU: cells that diverge: {sorted(diverged)} "
           f"(pinned: {sorted(CPU_DIVERGENT)})")
     if diverged != CPU_DIVERGENT:
         raise RuntimeError(f"chip vs CPU: cells {sorted(diverged)} differ, "

@@ -91,6 +91,7 @@ def trace_engine(return_state: bool = False):
     import jax
     import jax.numpy as jnp
 
+    from repro.core.engine.state import lower_scalars
     from repro.core.engine.step import scan_cell
 
     (ops, addrs, gaps, lengths, mlen), statics, sc = _example_inputs()
@@ -100,7 +101,7 @@ def trace_engine(return_state: bool = False):
                          mlen=mlen, return_state=return_state, **statics)
 
     with jax.enable_x64(True):
-        sc_j = {k: jnp.asarray(v, jnp.float64) for k, v in sc.items()}
+        sc_j = {k: jnp.asarray(v) for k, v in lower_scalars(sc).items()}
         closed = jax.make_jaxpr(cell)(jnp.asarray(2, jnp.int32), sc_j)
     names = ["scheme"] + sorted(sc_j)
     if len(names) != len(closed.jaxpr.invars):
@@ -124,6 +125,7 @@ def final_state_shapes() -> Dict[str, Tuple[str, Tuple[int, ...]]]:
     import jax
     import jax.numpy as jnp
 
+    from repro.core.engine.state import lower_scalars
     from repro.core.engine.step import scan_cell
 
     (ops, addrs, gaps, lengths, mlen), statics, sc = _example_inputs()
@@ -134,7 +136,7 @@ def final_state_shapes() -> Dict[str, Tuple[str, Tuple[int, ...]]]:
         return out[-1]
 
     with jax.enable_x64(True):
-        sc_j = {k: jnp.asarray(v, jnp.float64) for k, v in sc.items()}
+        sc_j = {k: jnp.asarray(v) for k, v in lower_scalars(sc).items()}
         st = jax.eval_shape(final_state, jnp.asarray(2, jnp.int32), sc_j)
     return {k: (str(v.dtype), tuple(v.shape))
             for k, v in st._asdict().items()}

@@ -2,20 +2,20 @@
 
 The scan carry is deliberately packed (DESIGN.md "Macro-stepping &
 state packing"): categorical columns in int8, barrier counts in int16,
-time columns pinned to float64.  Three silent regressions this pass
-catches statically:
+time columns pinned to the time words of ``engine.timebase`` (the one
+definition of the time type; this pass reads it from there).  Three
+silent regressions this pass catches statically:
 
   * **packed-column widening** — an init or handler change that
     promotes ``state``/``owner``/... to int32 quietly triples the scan
     carry (the packing registry below is the contract; the check runs
     ``jax.eval_shape`` over a full cell so a widened carry column is
     caught wherever it happens);
-  * **float64 -> float32 demotion on a time path** — the engine
-    subtracts ns-scale quantities from ~1e9-scale clocks; any f64->f32
-    ``convert_element_type`` in the traced program quantizes at ~100 ns
-    and breaks the bit-exact differentials (the single legitimate
-    narrow direction, the f32 *input* gaps widening to f64, is f32->f64
-    and does not match);
+  * **float64 -> float32 demotion** — the statistics accumulate
+    ns-scale latencies into float64 sums; any f64->f32
+    ``convert_element_type`` in the traced program quantizes them
+    (the legitimate narrow directions, powers of two built from
+    float32 bits widening to f64, are f32->f64 and do not match);
   * **un-donated grid buffers** — the jitted grid wrappers must donate
     the freshly-staged trace buffers (``ops``/``addrs``/``gaps``/
     ``mlen``) so XLA reuses them for the carry instead of allocating.
@@ -28,6 +28,7 @@ from typing import Dict, List, Optional, Tuple
 
 from repro.analysis.common import (Finding, find_line, read_source, rel,
                                    REPO_ROOT)
+from repro.core.engine.timebase import DTYPE_NAME as TIME
 
 _STATE = REPO_ROOT / "src" / "repro" / "core" / "engine" / "state.py"
 _GRID = REPO_ROOT / "src" / "repro" / "core" / "engine" / "grid.py"
@@ -37,17 +38,17 @@ _GRID = REPO_ROOT / "src" / "repro" / "core" / "engine" / "grid.py"
 # engine.state.MachineState — this registry is the machine-checked
 # form.
 EXPECTED_DTYPES: Dict[str, str] = {
-    "clock": "float64", "ptr": "int32",
-    "tag": "int32", "state": "int8", "lru": "float64", "dd": "float64",
+    "clock": TIME, "ptr": "int32",
+    "tag": "int32", "state": "int8", "lru": TIME, "dd": TIME,
     "ver": "int32", "owner": "int8",
     "aver": "int32", "pm_ver": "int32",
-    "pm_busy": "float64", "pbc_busy": "float64",
+    "pm_busy": TIME, "pbc_busy": TIME,
     "blocked": "bool", "bcount": "int16",
     "stats": "float64",
-    "dtag": "int32", "dstate": "int8", "dlru": "float64",
-    "ddd": "float64", "dver": "int32", "downer": "int8",
-    "dwt": "float64", "hpbc": "float64", "hop_stats": "float64",
-    "lpbc": "float64",
+    "dtag": "int32", "dstate": "int8", "dlru": TIME,
+    "ddd": TIME, "dver": "int32", "downer": "int8",
+    "dwt": TIME, "hpbc": TIME, "hop_stats": "float64",
+    "lpbc": TIME,
 }
 
 REQUIRED_DONATED = ("ops", "addrs", "gaps", "mlen")
@@ -137,10 +138,10 @@ def check_f32_leaks(closed=None, fn=None, args: tuple = ()
             findings.append(Finding(
                 file=file, line=line, rule="dtype-f32-leak",
                 message="float64 value demoted to float32 in the traced "
-                        "step: time columns quantize at ~100 ns at "
+                        "step: latency sums quantize at ~100 ns at "
                         "clock scale",
-                suggestion="keep time arithmetic in f64 (widen the f32 "
-                           "operand instead)"))
+                suggestion="keep time in time words and statistics in "
+                           "f64 (widen the f32 operand instead)"))
     return findings
 
 

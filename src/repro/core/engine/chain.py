@@ -43,7 +43,8 @@ from typing import NamedTuple
 import jax.numpy as jnp
 
 from repro.core.engine import channels
-from repro.core.engine.state import (DIRTY, DRAIN, EMPTY, INF, H_BYPASS,
+from repro.core.engine import timebase as tb
+from repro.core.engine.state import (DIRTY, DRAIN, EMPTY, H_BYPASS,
                                      H_COALESCES, H_FWD_CNT, H_FWD_SUM,
                                      MachineState)
 
@@ -59,7 +60,7 @@ class Batch(NamedTuple):
     owner: jnp.ndarray   # (Q,) i8 (the packed MachineState owner dtype;
                          #          `_place`'s injective pick() sums carry
                          #          it through without widening)
-    emit: jnp.ndarray    # (Q,) f64  emission time at the previous switch
+    emit: jnp.ndarray    # (Q,) time emission time at the previous switch
     ohop: jnp.ndarray    # (Q,) i32  origin hop (0 = hop-1 flat columns,
                          #           m > 0 = deep row m-1) for dd writeback
     oslot: jnp.ndarray   # (Q,) i32  origin PBE slot
@@ -102,25 +103,29 @@ def _pm_land(sc, pos, batch: Batch, pm_busy, pm_ver, n_banks, n_track):
     act = batch.active
     # remaining wire: switch pos -> PM through the switches below it
     rem = jnp.maximum(sc["n_switches"] - float(pos), 0.0)
-    path_down = sc["link_ns"] + rem * sc["hop_ns"]
-    arr = batch.emit + path_down
+    # ack back at the origin switch o: PM -> switch n -> ... -> switch o;
+    # both wires as one vector operation each (elementwise roundings)
+    o = batch.ohop + 1
+    paths = tb.add(sc["link_ns"], tb.mul(sc["hop_ns"], jnp.concatenate([
+        rem[None], jnp.maximum(sc["n_switches"] - o.astype(F), 0.0)])))
+    path_down, path_up = paths[0], paths[1:]
+    arr = tb.add(batch.emit, path_down)
     bank = batch.addr % n_banks
     same_bank = bank[None, :] == bank[:, None]
     q = jnp.arange(act.shape[0])
     earlier = q[None, :] < q[:, None]
     rank_b = jnp.sum((same_bank & earlier & act[None, :]).astype(F), axis=1)
-    start = jnp.maximum(pm_busy[bank], arr) + rank_b * sc["nvm_w_occ"]
-    # ack back at the origin switch o: PM -> switch n -> ... -> switch o
-    o = batch.ohop + 1
-    path_up = sc["link_ns"] + jnp.maximum(
-        sc["n_switches"] - o.astype(F), 0.0) * sc["hop_ns"]
-    dd_vals = start + sc["nvm_write"] + path_up
-    busy_after = jnp.where(same_bank & act[None, :],
-                           (start + sc["nvm_w_occ"])[None, :], 0.0).max(axis=1)
-    pm_busy2 = jnp.maximum(
+    start = tb.add(tb.maximum(pm_busy[bank], arr),
+                   tb.mul(sc["nvm_w_occ"], rank_b))
+    write_end, bank_free = tb.add(
+        start, jnp.stack([sc["nvm_write"], sc["nvm_w_occ"]])[:, None])
+    dd_vals = tb.add(write_end, path_up)
+    busy_after = tb.max(jnp.where(
+        same_bank & act[None, :], bank_free[None, :], tb.ZERO), axis=1)
+    pm_busy2 = tb.maximum(
         pm_busy, jnp.zeros_like(pm_busy).at[bank].max(
-            jnp.where(act, busy_after, 0.0)))
-    ok = act & (dd_vals <= crash) & (batch.addr >= 0) \
+            jnp.where(act, busy_after, tb.ZERO)))
+    ok = act & tb.le(dd_vals, crash) & (batch.addr >= 0) \
         & (batch.addr < n_track)
     pm_ver2 = pm_ver.at[jnp.clip(batch.addr, 0, A - 1)].max(
         jnp.where(ok, batch.ver, 0))
@@ -146,15 +151,16 @@ def _place(sc, j, scheme, rows, hpbc_j, batch: Batch, hop_stats):
     act = batch.active
     any_act = jnp.any(act)
 
-    arr = batch.emit + sc["hop_ns"]
+    arr = tb.add(batch.emit, sc["hop_ns"])
     starts, hpbc_j = channels.fifo_service(hpbc_j, arr, act,
                                            sc["pbc_occ_ns"])
-    classify = starts + sc["pbc_proc_ns"] + sc["deep_tag"][j]
-    commit = classify + sc["deep_data"][j]
+    classify = tb.add(tb.add(starts, sc["pbc_proc_ns"]), sc["deep_tag"][j])
+    commit = tb.add(classify, sc["deep_data"][j])
 
     # lazy-free observed once at the batch head (single settle point)
-    t0 = jnp.where(any_act, jnp.min(jnp.where(act, classify, INF)), -INF)
-    freed = (rows["dstate"][j] == DRAIN) & (rows["ddd"][j] <= t0)
+    t0 = jnp.where(any_act, tb.min(jnp.where(act, classify, tb.INF)),
+                   tb.NEG)
+    freed = (rows["dstate"][j] == DRAIN) & tb.le(rows["ddd"][j], t0)
     state0 = jnp.where(freed, EMPTY, rows["dstate"][j])
 
     co = act[:, None] & slot_act[None, :] \
@@ -170,7 +176,7 @@ def _place(sc, j, scheme, rows, hpbc_j, batch: Batch, hop_stats):
     amat = placed[:, None] & empty[None, :] \
         & (arank[:, None] == erank[None, :])
 
-    gate = commit <= crash
+    gate = tb.le(commit, crash)
     mat = (co | amat) & gate[:, None]
     upd = jnp.any(mat, axis=0)
 
@@ -198,7 +204,7 @@ def _place(sc, j, scheme, rows, hpbc_j, batch: Batch, hop_stats):
     keep_owner = co_upd & (rows["dver"][j] > ver_in)
     owner1 = jnp.where(upd & ~keep_owner, pick(batch.owner, 0),
                        rows["downer"][j])
-    t_new = pick(commit, 0.0)
+    t_new = pick(commit, tb.ZERO)
     lru1 = jnp.where(upd, t_new, rows["dlru"][j])
     wt1 = jnp.where(upd, t_new, rows["dwt"][j])
 
@@ -206,7 +212,8 @@ def _place(sc, j, scheme, rows, hpbc_j, batch: Batch, hop_stats):
     hop_stats = hop_stats.at[j + 1, H_FWD_CNT].add(
         jnp.sum((ended & gate).astype(F)))
     hop_stats = hop_stats.at[j + 1, H_FWD_SUM].add(
-        jnp.sum(jnp.where(ended & gate, commit - batch.emit, 0.0)))
+        jnp.sum(jnp.where(ended & gate,
+                          tb.to_f64(tb.sub(commit, batch.emit)), 0.0)))
     hop_stats = hop_stats.at[j + 1, H_COALESCES].add(
         jnp.sum((has_co & gate).astype(F)))
     hop_stats = hop_stats.at[j + 1, H_BYPASS].add(
@@ -215,8 +222,8 @@ def _place(sc, j, scheme, rows, hpbc_j, batch: Batch, hop_stats):
     # dd writeback: every committed packet acks its origin entry, gated
     # or not (a post-crash commit still yields a post-crash ack time —
     # exactly what keeps the origin entry alive through the crash)
-    dd_vals = commit + (float(j + 2) - (batch.ohop.astype(F) + 1.0)) \
-        * sc["hop_ns"]
+    dd_vals = tb.add(commit, tb.mul(
+        sc["hop_ns"], float(j + 2) - (batch.ohop.astype(F) + 1.0)))
 
     # this hop's own drain-down (evaluated once, after the batch settles)
     dirty = slot_act & (state1 == DIRTY)
@@ -224,24 +231,24 @@ def _place(sc, j, scheme, rows, hpbc_j, batch: Batch, hop_stats):
     k_rf = jnp.where(dirty_cnt >= sc["deep_thr"][j],
                      dirty_cnt - sc["deep_pre"][j], 0.0)
     k = jnp.where(scheme == 1, dirty_cnt, k_rf)     # PB forwards everything
-    key = jnp.where(dirty, lru1, INF)
-    rank = jnp.argsort(jnp.argsort(key)).astype(F)
+    key = jnp.where(dirty, lru1, tb.INF)
+    rank = jnp.argsort(tb.argsort(key)).astype(F)
     to_drain = (rank < k) & dirty
-    t_row = jnp.maximum(
-        jnp.max(jnp.where(ended & gate, commit, -INF)), 0.0)
+    t_row = tb.maximum(
+        tb.max(jnp.where(ended & gate, commit, tb.NEG)), tb.ZERO)
     state2 = jnp.where(to_drain, DRAIN, state1)
 
     # the drain-down set leaves in LRU order (the wire order the oracle
     # replays; downstream LRU stamps — and who bypasses a full hop —
     # depend on it)
-    order = jnp.argsort(key).astype(jnp.int32)
+    order = tb.argsort(key).astype(jnp.int32)
     nxt = Batch(
         active=jnp.concatenate([bypass, to_drain[order]]),
         addr=jnp.concatenate([batch.addr, tag1[order]]),
         ver=jnp.concatenate([batch.ver, ver1[order]]),
         owner=jnp.concatenate([batch.owner, owner1[order]]),
-        emit=jnp.concatenate([jnp.where(bypass, classify, 0.0),
-                              jnp.zeros((P,), F) + t_row]),
+        emit=jnp.concatenate([jnp.where(bypass, classify, tb.ZERO),
+                              jnp.broadcast_to(t_row, (P,))]),
         ohop=jnp.concatenate([batch.ohop,
                               jnp.full((P,), j + 1, jnp.int32)]),
         oslot=jnp.concatenate([batch.oslot, order]),
@@ -315,17 +322,23 @@ def deep_read(sc, st: MachineState, addr, t):
     P = st.dtag.shape[1]
     slot_ids = jnp.arange(P, dtype=jnp.int32)
     hit = jnp.zeros((D,), bool)
-    resp = jnp.zeros((D,), F)
     idxs = jnp.zeros((D,), jnp.int32)
+    # every row's arrival and response as one vector operation each
+    # (row j is switch j+2, j+1 hops below hop 1)
+    path = tb.mul(sc["hop_ns"], jnp.arange(1, D + 1))
+    arr = tb.add(tb.add(t, sc["ow_cpu_sw1"]), path)
+    resp = tb.add(tb.add(tb.add(tb.add(tb.add(
+        arr, sc["pbc_read_ns"]), sc["deep_tag"][:D]),
+        sc["deep_data"][:D]), sc["ow_cpu_sw1"]), path)
+    late = tb.add(arr, sc["fwd_margin"])
     for j in range(D):
         row_live = (float(j) + 2.0) <= sc["n_switches"]
         slot_act = slot_ids < sc["deep_pbe"][j].astype(jnp.int32)
-        arr = t + sc["ow_cpu_sw1"] + (float(j) + 1.0) * sc["hop_ns"]
         live = slot_act & (st.dtag[j] == addr) \
-            & (st.dstate[j] != EMPTY) & (st.dwt[j] <= t)
+            & (st.dstate[j] != EMPTY) & tb.le(st.dwt[j], t)
         served = live & ((st.dstate[j] == DIRTY)
                          | ((st.dstate[j] == DRAIN)
-                            & (st.ddd[j] > arr + sc["fwd_margin"])))
+                            & tb.gt(st.ddd[j], late[j])))
         has = jnp.any(served) & row_live
         # a Dirty entry supersedes a late-Drain one (same rule as the
         # hop-1 pb_lookup: the Dirty copy is the newer version)
@@ -334,10 +347,6 @@ def deep_read(sc, st: MachineState, addr, t):
                         jnp.argmax(served)).astype(jnp.int32)
         hit = hit.at[j].set(has)
         idxs = idxs.at[j].set(idx)
-        resp = resp.at[j].set(
-            arr + sc["pbc_read_ns"] + sc["deep_tag"][j]
-            + sc["deep_data"][j]
-            + sc["ow_cpu_sw1"] + (float(j) + 1.0) * sc["hop_ns"])
     first = jnp.argmax(hit)                       # shallowest serving hop
     any_hit = jnp.any(hit)
     dlru = st.dlru

@@ -12,10 +12,9 @@ writes (the paper's Fig. 6b mechanism) falls out of this scalar.
 """
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
-_INF = 1e30  # engine.state.INF (kept local: state imports no channels)
+from repro.core.engine import timebase as tb
 
 
 def bank_of(addr, n_banks: int):
@@ -25,22 +24,22 @@ def bank_of(addr, n_banks: int):
 
 def service_start(busy, bank, ready):
     """When bank ``bank`` can begin serving a request arriving at ``ready``."""
-    return jnp.maximum(busy[bank], ready)
+    return tb.maximum(busy[bank], ready)
 
 
 def reserve(busy, bank, start, occ):
     """Hold the bank from ``start`` for ``occ`` ns; returns updated vector."""
-    return busy.at[bank].set(start + occ)
+    return busy.at[bank].set(tb.add(start, occ))
 
 
 def pbc_start(pbc_busy, arrival, proc_ns):
     """PBC FIFO service start + processing for one packet."""
-    return jnp.maximum(pbc_busy, arrival) + proc_ns
+    return tb.add(tb.maximum(pbc_busy, arrival), proc_ns)
 
 
 def pbc_hold(pbc_busy, arrival, occ_ns):
     """Advance the PBC next-free time past one packet's issue interval."""
-    return jnp.maximum(pbc_busy, arrival) + occ_ns
+    return tb.add(tb.maximum(pbc_busy, arrival), occ_ns)
 
 
 def fifo_service(busy, arrivals, active, occ_ns):
@@ -54,13 +53,15 @@ def fifo_service(busy, arrivals, active, occ_ns):
 
         start_q = occ*rank_q + max(busy, max_{i<=q}(arr_i - occ*rank_i))
 
-    Returns ``(starts (Q,), busy_after ())``; inactive packets get INF
-    starts and do not advance the channel.
+    A negative ``arr_i - occ*rank_i`` never wins against ``busy >= 0``,
+    so it is taken as 0 (``tb.monus``).  Returns ``(starts (Q,),
+    busy_after ())``; inactive packets get INF starts and do not advance
+    the channel.
     """
-    rank = jnp.cumsum(active.astype(jnp.float64)) - 1.0
-    adj = jnp.where(active, arrivals - occ_ns * rank, -_INF)
-    run = jax.lax.cummax(adj)
-    starts = jnp.where(active,
-                       occ_ns * rank + jnp.maximum(run, busy), _INF)
-    busy_after = jnp.max(jnp.where(active, starts + occ_ns, busy))
-    return starts, jnp.maximum(busy_after, busy)
+    rank = jnp.cumsum(active.astype(jnp.int32)) - 1
+    wait = tb.mul(occ_ns, jnp.maximum(rank, 0))
+    adj = jnp.where(active, tb.monus(arrivals, wait), tb.NEG)
+    run = tb.cummax(adj)
+    starts = jnp.where(active, tb.add(wait, tb.maximum(run, busy)), tb.INF)
+    busy_after = tb.max(jnp.where(active, tb.add(starts, occ_ns), busy))
+    return starts, tb.maximum(busy_after, busy)

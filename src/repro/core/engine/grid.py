@@ -40,9 +40,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.engine import spans
+from repro.core.engine import timebase as tb
+from repro.core.engine.handlers import ALL_SCHEMES
 from repro.core.engine.macro import MACRO_ABORT_REASONS
-from repro.core.engine.state import (SimResult, result_from_stats,
-                                     scalars_from_config)
+from repro.core.engine.state import (SimResult, lower_scalars,
+                                     result_from_stats, scalars_from_config)
 from repro.core.engine.step import CHUNK, scan_cell
 from repro.core.params import MACRO_KMAX, PCSConfig
 from repro.core.traces import Trace, plan_runs
@@ -119,13 +121,14 @@ def _stack_configs(configs: Sequence[PCSConfig], max_pbe: int | None,
     rows = [scalars_from_config(c, n_tenants_max, n_deep, n_leaves,
                                 n_epochs_max=n_epochs)
             for c in configs]
-    sc = {k: np.asarray([r[k] for r in rows], np.float64) for k in rows[0]}
+    sc = lower_scalars({k: np.asarray([r[k] for r in rows], np.float64)
+                        for k in rows[0]})
     schemes = np.asarray([int(c.scheme) for c in configs], np.int32)
     return sc, schemes, max_pbe, banks.pop(), n_deep, n_leaves
 
 
 _STATICS = ("max_pbe", "n_steps", "pm_banks", "n_track", "n_tenants_max",
-            "n_deep_max", "n_leaves_max", "macro")
+            "n_deep_max", "n_leaves_max", "macro", "schemes")
 _DONATED = ("ops", "addrs", "gaps", "mlen")
 
 
@@ -133,7 +136,7 @@ _DONATED = ("ops", "addrs", "gaps", "mlen")
                    donate_argnames=_DONATED)
 def _run_cell(ops, addrs, gaps, lengths, mlen, scheme, sc, *,
               max_pbe, n_steps, pm_banks, n_track, n_tenants_max,
-              n_deep_max, n_leaves_max, macro):
+              n_deep_max, n_leaves_max, macro, schemes=ALL_SCHEMES):
     # single-cell program: no batch axes, so `lax.switch` and the macro
     # gate's `lax.cond` lower to real branches instead of vmap's
     # execute-all-and-select
@@ -141,11 +144,11 @@ def _run_cell(ops, addrs, gaps, lengths, mlen, scheme, sc, *,
                      max_pbe=max_pbe, n_steps=n_steps, pm_banks=pm_banks,
                      n_track=n_track, n_tenants_max=n_tenants_max,
                      n_deep_max=n_deep_max, n_leaves_max=n_leaves_max,
-                     mlen=mlen, macro=macro)
+                     mlen=mlen, macro=macro, schemes=schemes)
 
 
 def _cell_fn(max_pbe, n_steps, pm_banks, n_track, n_tenants_max,
-             n_deep_max, n_leaves_max, macro, axis_names):
+             n_deep_max, n_leaves_max, macro, axis_names, schemes):
     # ``axis_names``: the vmap axes the cell runs under, over which the
     # macro gate reduces (engine.step)
     def cell(ops, addrs, gaps, lengths, mlen, scheme, sc):
@@ -154,34 +157,35 @@ def _cell_fn(max_pbe, n_steps, pm_banks, n_track, n_tenants_max,
                          pm_banks=pm_banks, n_track=n_track,
                          n_tenants_max=n_tenants_max,
                          n_deep_max=n_deep_max, n_leaves_max=n_leaves_max,
-                         mlen=mlen, macro=macro, axis_names=axis_names)
+                         mlen=mlen, macro=macro, axis_names=axis_names,
+                         schemes=schemes)
     return cell
 
 
 @functools.partial(jax.jit, static_argnames=_STATICS,
                    donate_argnames=_DONATED)
-def _run_grid(ops, addrs, gaps, lengths, mlen, schemes, sc, *,
+def _run_grid(ops, addrs, gaps, lengths, mlen, scheme, sc, *,
               max_pbe, n_steps, pm_banks, n_track, n_tenants_max,
-              n_deep_max, n_leaves_max, macro):
+              n_deep_max, n_leaves_max, macro, schemes=ALL_SCHEMES):
     cell = _cell_fn(max_pbe, n_steps, pm_banks, n_track, n_tenants_max,
-                    n_deep_max, n_leaves_max, macro, ("tr", "cfg"))
+                    n_deep_max, n_leaves_max, macro, ("tr", "cfg"), schemes)
     over_cfg = jax.vmap(cell, in_axes=(None, None, None, None, None, 0, 0),
                         axis_name="cfg")
     over_tr = jax.vmap(over_cfg, in_axes=(0, 0, 0, 0, 0, None, None),
                        axis_name="tr")
-    return over_tr(ops, addrs, gaps, lengths, mlen, schemes, sc)
+    return over_tr(ops, addrs, gaps, lengths, mlen, scheme, sc)
 
 
 @functools.partial(jax.jit, static_argnames=_STATICS,
                    donate_argnames=_DONATED)
-def _run_cells(ops, addrs, gaps, lengths, mlen, schemes, sc, *,
+def _run_cells(ops, addrs, gaps, lengths, mlen, scheme, sc, *,
                max_pbe, n_steps, pm_banks, n_track, n_tenants_max,
-               n_deep_max, n_leaves_max, macro):
+               n_deep_max, n_leaves_max, macro, schemes=ALL_SCHEMES):
     # flat pairing: one shared batch axis over traces AND configs
     cell = _cell_fn(max_pbe, n_steps, pm_banks, n_track, n_tenants_max,
-                    n_deep_max, n_leaves_max, macro, ("cell",))
+                    n_deep_max, n_leaves_max, macro, ("cell",), schemes)
     return jax.vmap(cell, axis_name="cell")(ops, addrs, gaps, lengths, mlen,
-                                            schemes, sc)
+                                            scheme, sc)
 
 
 def _execute(program, buffers, configs: Sequence[PCSConfig], max_pbe,
@@ -204,21 +208,21 @@ def _execute(program, buffers, configs: Sequence[PCSConfig], max_pbe,
         with spans.span("engine.put"):
             args = [jnp.asarray(first(b)) for b in buffers]
             args.append(jnp.asarray(first(schemes)))
-            sc = {k: jnp.asarray(first(v), jnp.float64)
-                  for k, v in sc_np.items()}
+            sc = {k: jnp.asarray(first(v)) for k, v in sc_np.items()}
         with spans.span("engine.scan"):
             out = jax.block_until_ready(program(
                 *args, sc, max_pbe=max_pbe, n_steps=n_steps,
                 pm_banks=pm_banks, n_track=track_addrs,
                 n_tenants_max=n_tenants_max, n_deep_max=n_deep,
-                n_leaves_max=n_leaves, macro=macro))
+                n_leaves_max=n_leaves, macro=macro,
+                schemes=tuple(sorted(set(schemes.tolist())))))
         with spans.span("engine.fetch"):
             out = tuple(np.asarray(o) for o in out)
     return tuple(o[None, None] for o in out) if single else out
 
 
-def _count(rec: spans.Call, mops, maborts, segments, gate_steps, traces,
-           configs, n_steps: int, pairs: bool) -> None:
+def _count(rec: spans.Call, mops, maborts, segments, gate_steps, time_ops,
+           traces, configs, n_steps: int, pairs: bool) -> None:
     """The call's counters from the program's telemetry outputs."""
     rec.cells = int(np.size(segments))
     rec.trace_ops = int(sum(t.total_ops for t in traces)
@@ -230,6 +234,7 @@ def _count(rec: spans.Call, mops, maborts, segments, gate_steps, traces,
     # the gate is grid-wide; the slowest cell saw every step
     rec.macro_gate_steps = int(np.max(gate_steps))
     rec.macro_ops = int(np.sum(mops))
+    rec.time_ops = int(np.max(time_ops))
     rec.abort_reasons = dict(zip(MACRO_ABORT_REASONS, (
         int(x) for x in np.sum(
             np.asarray(maborts).reshape(-1, len(MACRO_ABORT_REASONS)),
@@ -239,9 +244,11 @@ def _count(rec: spans.Call, mops, maborts, segments, gate_steps, traces,
 def _results_from(out, traces, configs, track_addrs, rec, n_steps,
                   pairs: bool):
     (runtimes, stats, durable_ver, n_recov, recov_ns, recov_t,
-     hop_stats, recov_h, recov_l, mops, maborts, segments, gate_steps) = out
-    _count(rec, mops, maborts, segments, gate_steps, traces, configs,
-           n_steps, pairs)
+     hop_stats, recov_h, recov_l, mops, maborts, segments, gate_steps,
+     time_ops) = out
+    _count(rec, mops, maborts, segments, gate_steps, time_ops, traces,
+           configs, n_steps, pairs)
+    runtimes, recov_ns = tb.to_host(runtimes), tb.to_host(recov_ns)
 
     def cell(i, j, k):
         fab = configs[j].fabric

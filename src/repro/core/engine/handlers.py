@@ -21,8 +21,9 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.engine import chain, channels, fabric, policy
-from repro.core.params import spine_defer
-from repro.core.engine.state import (DIRTY, DRAIN, EMPTY, INF, H_COALESCES,
+from repro.core.engine import timebase as tb
+from repro.core.params import Scheme, spine_defer
+from repro.core.engine.state import (DIRTY, DRAIN, EMPTY, H_COALESCES,
                                      H_FWD_CNT, H_FWD_SUM, H_READ_HITS,
                                      MachineState, S_ACKED, S_COALESCES,
                                      S_DRAM_READS, S_DURABLE, S_LAT_HIST0,
@@ -31,6 +32,14 @@ from repro.core.engine.state import (DIRTY, DRAIN, EMPTY, INF, H_COALESCES,
                                      S_PM_WRITES, S_READ_CNT, S_READ_HITS,
                                      S_READ_SUM, S_SLO_OVER, S_STALL_TIME,
                                      S_VICTIM_CNT, lat_bin)
+
+
+NOPB = int(Scheme.NOPB)
+ALL_SCHEMES = tuple(int(s) for s in Scheme)
+
+# clock of a core waiting at a barrier: above every op's issue time,
+# below the "no op left" sentinel (the step's INF / 2 test)
+WAITING = tb.bits(0.9e30)
 
 
 class StepCtx(NamedTuple):
@@ -48,6 +57,19 @@ class StepCtx(NamedTuple):
     n_live_t: jnp.ndarray   # ()  live cores in this op's tenant (barriers)
     n_banks: int            # static PM bank count
     n_track: int = 0        # static durability-tracked address count
+    schemes: tuple = ALL_SCHEMES  # static scheme ids the grid holds
+
+
+def by_scheme(schemes, scheme, nopb, buffered, operand):
+    """``lax.switch`` over the volatile (NoPB) and the buffered (PB,
+    PB_RF) leg of a handler.  A leg that no cell of the grid takes
+    (``schemes``: the static ids of the grid's configs) is left out at
+    trace time; under ``vmap`` a switch would run it for every cell."""
+    if NOPB not in schemes:
+        return buffered(operand)
+    if set(schemes) == {NOPB}:
+        return nopb(operand)
+    return jax.lax.switch(jnp.minimum(scheme, 1), [nopb, buffered], operand)
 
 
 def _tracked(ctx: StepCtx, addr):
@@ -62,8 +84,9 @@ def handle_compute(ctx: StepCtx, st: MachineState) -> MachineState:
 
 def handle_dram_read(ctx: StepCtx, st: MachineState) -> MachineState:
     stats = st.stats.at[ctx.tenant, S_DRAM_READS].add(1.0)
-    return st._replace(clock=st.clock.at[ctx.c].set(ctx.t + ctx.sc["dram_ns"]),
-                       stats=stats)
+    return st._replace(
+        clock=st.clock.at[ctx.c].set(tb.add(ctx.t, ctx.sc["dram_ns"])),
+        stats=stats)
 
 
 def handle_dram_write(ctx: StepCtx, st: MachineState) -> MachineState:
@@ -79,11 +102,12 @@ def handle_pm_read(ctx: StepCtx, st: MachineState) -> MachineState:
 
     def direct(st: MachineState) -> MachineState:
         # NoPB: the volatile switch forwards every read to PM.
-        pm_start = channels.service_start(st.pm_busy, bank, t + ow)
-        resp = pm_start + sc["nvm_read"] + ow
+        pm_start = channels.service_start(st.pm_busy, bank, tb.add(t, ow))
+        resp = tb.add(tb.add(pm_start, sc["nvm_read"]), ow)
         stats = st.stats.at[
             ctx.tenant, jnp.asarray([S_READ_SUM, S_READ_CNT], jnp.int32)
-        ].add(jnp.stack([resp - t, jnp.ones((), jnp.float64)]))
+        ].add(jnp.stack([tb.to_f64(tb.sub(resp, t)),
+                         jnp.ones((), jnp.float64)]))
         return st._replace(
             clock=st.clock.at[ctx.c].set(resp),
             pm_busy=channels.reserve(st.pm_busy, bank, pm_start,
@@ -93,8 +117,8 @@ def handle_pm_read(ctx: StepCtx, st: MachineState) -> MachineState:
     def via_pb(st: MachineState) -> MachineState:
         # PB/PB_RF: the PBCS classifies the read; a live entry routes it
         # through the PI buffer to the PBC (read forwarding).
-        pm_start_dir = channels.service_start(st.pm_busy, bank, t + ow)
-        resp_dir = pm_start_dir + sc["nvm_read"] + ow
+        pm_start_dir = channels.service_start(st.pm_busy, bank,
+                                              tb.add(t, ow))
 
         state0 = policy.lazy_free(st.state, st.dd, t)
         # Fabric: a read routes through the issuing tenant's own leaf
@@ -112,20 +136,28 @@ def handle_pm_read(ctx: StepCtx, st: MachineState) -> MachineState:
             pbc_prev = st.pbc_busy
         has, idx = policy.pb_lookup(st.tag, state0, leaf_act, addr)
         # PI-buffer path: wait for the PBC (head-of-line blocking)
-        arr = t + sc["ow_cpu_sw1"]
-        pbc_start = channels.pbc_start(pbc_prev, arr,
-                                       sc["pbc_read_ns"] + sc["tag_ns"])
+        arr = tb.add(t, sc["ow_cpu_sw1"])
+        pbc_start = channels.pbc_start(
+            pbc_prev, arr, tb.add(sc["pbc_read_ns"], sc["tag_ns"]))
         st_i = state0[idx]
         dd_i = st.dd[idx]
         served = (st_i == DIRTY) | (
-            (st_i == DRAIN) & (dd_i > pbc_start + sc["fwd_margin"]))
-        resp_pb = pbc_start + sc["data_ns"] + sc["ow_cpu_sw1"]
+            (st_i == DRAIN)
+            & tb.gt(dd_i, tb.add(pbc_start, sc["fwd_margin"])))
         # forwarded to PM through the PO buffer after the detour; the
         # packet re-enters the routing pipeline (one extra pipe pass)
-        pm_start_fwd = jnp.maximum(
+        pm_start_fwd = tb.maximum(
             st.pm_busy[bank],
-            pbc_start + sc["switch_pipe"] + sc["ow_sw1_pm"])
-        resp_fwd = pm_start_fwd + sc["nvm_read"] + ow
+            tb.add(tb.add(pbc_start, sc["switch_pipe"]), sc["ow_sw1_pm"]))
+        # the three possible responses and the two bank holds, as one
+        # vector operation each (elementwise: the same roundings)
+        resp_dir, resp_pb, resp_fwd = tb.add(
+            tb.add(jnp.stack([pm_start_dir, pbc_start, pm_start_fwd]),
+                   jnp.stack([sc["nvm_read"], sc["data_ns"],
+                              sc["nvm_read"]])),
+            jnp.stack([ow, sc["ow_cpu_sw1"], ow]))
+        free_fwd, free_dir = tb.add(
+            jnp.stack([pm_start_fwd, pm_start_dir]), sc["nvm_r_occ"])
 
         # Read-forwarding checks below hop 1 (switch chain): when hop 1
         # has no live entry, the packet travels toward PM passing every
@@ -144,10 +176,8 @@ def handle_pm_read(ctx: StepCtx, st: MachineState) -> MachineState:
                          jnp.where(deep_hit, resp_deep, resp_dir))
         pm_busy2 = st.pm_busy.at[bank].set(jnp.where(
             has,
-            jnp.where(served, st.pm_busy[bank],
-                      pm_start_fwd + sc["nvm_r_occ"]),
-            jnp.where(deep_hit, st.pm_busy[bank],
-                      pm_start_dir + sc["nvm_r_occ"])))
+            jnp.where(served, st.pm_busy[bank], free_fwd),
+            jnp.where(deep_hit, st.pm_busy[bank], free_dir)))
         pbc_busy2 = jnp.where(
             has, channels.pbc_hold(pbc_prev, arr, sc["pbc_read_occ"]),
             pbc_prev)
@@ -165,14 +195,15 @@ def handle_pm_read(ctx: StepCtx, st: MachineState) -> MachineState:
         stats = st.stats.at[
             ctx.tenant, jnp.asarray([S_READ_SUM, S_READ_CNT, S_READ_HITS,
                                      S_PI_DETOURS], jnp.int32)
-        ].add(jnp.stack([resp - t, jnp.ones((), jnp.float64),
+        ].add(jnp.stack([tb.to_f64(tb.sub(resp, t)),
+                         jnp.ones((), jnp.float64),
                          ((has & served) | deep_hit).astype(jnp.float64),
                          has.astype(jnp.float64)]))
         return st._replace(clock=st.clock.at[ctx.c].set(resp), state=state0,
                            lru=lru2, dlru=dlru3, pm_busy=pm_busy2,
                            stats=stats, hop_stats=hop_stats, **pbc_kw)
 
-    return jax.lax.switch(jnp.minimum(ctx.scheme, 1), [direct, via_pb], st)
+    return by_scheme(ctx.schemes, ctx.scheme, direct, via_pb, st)
 
 
 # ----------------------------------------------------------------- persist
@@ -191,7 +222,7 @@ def _persist_with_buffer(ctx: StepCtx, st: MachineState) -> MachineState:
     is_rf = ctx.scheme == 2          # Scheme.PB_RF, traced
     crash = sc["crash_at"]
     bank = channels.bank_of(addr, ctx.n_banks)
-    arr = t + sc["ow_cpu_sw1"]
+    arr = tb.add(t, sc["ow_cpu_sw1"])
     # Fabric: the persist enters the issuing tenant's own leaf switch —
     # lookup/alloc/victim/drain are scoped to that leaf's slot window,
     # and that leaf's own PBC front serves the packet.  NL == 0 (no
@@ -209,7 +240,7 @@ def _persist_with_buffer(ctx: StepCtx, st: MachineState) -> MachineState:
         leaf_act = ctx.slot_active
         pbc_prev = st.pbc_busy
     pbc_start = channels.pbc_start(pbc_prev, arr,
-                                   sc["pbc_proc_ns"] + sc["tag_ns"])
+                                   tb.add(sc["pbc_proc_ns"], sc["tag_ns"]))
     state1 = policy.lazy_free(st.state, st.dd, pbc_start)
     match_dirty = leaf_act & (st.tag == addr) & (state1 == DIRTY)
     has_dirty = jnp.any(match_dirty)
@@ -238,15 +269,17 @@ def _persist_with_buffer(ctx: StepCtx, st: MachineState) -> MachineState:
 
     # victim drain (only used when no Empty entry exists)
     victim_bank = channels.bank_of(st.tag[victim_idx], ctx.n_banks)
-    victim_pm_start = jnp.maximum(st.pm_busy[victim_bank],
-                                  pbc_start + sc["ow_sw1_pm"])
-    victim_dd = victim_pm_start + sc["nvm_write"] + sc["ow_sw1_pm"]
+    victim_pm_start = tb.maximum(st.pm_busy[victim_bank],
+                                 tb.add(pbc_start, sc["ow_sw1_pm"]))
+    victim_end, victim_free = tb.add(
+        victim_pm_start, jnp.stack([sc["nvm_write"], sc["nvm_w_occ"]]))
+    victim_dd = tb.add(victim_end, sc["ow_sw1_pm"])
     needs_victim = (~is_coalesce) & (~any_empty) & any_dirty
 
     # the victim's in-flight write is durable at PM iff its ack beats the
     # crash (a later ack means the write is lost with the power)
     vic_tag = st.tag[victim_idx]
-    vic_ok = (needs_victim & (victim_dd <= crash) & (vic_tag >= 0)
+    vic_ok = (needs_victim & tb.le(victim_dd, crash) & (vic_tag >= 0)
               & (vic_tag < ctx.n_track))
     pm_ver1 = st.pm_ver.at[jnp.clip(vic_tag, 0, A - 1)].max(
         jnp.where(vic_ok, st.ver[victim_idx], 0))
@@ -258,7 +291,7 @@ def _persist_with_buffer(ctx: StepCtx, st: MachineState) -> MachineState:
     # so the slot frees at its true downstream ack.  D == 0 (no deep row
     # allocated anywhere in the grid) skips the chain at trace time.
     D = st.dtag.shape[0]
-    vic_emit = needs_victim & (pbc_start <= crash)
+    vic_emit = needs_victim & tb.le(pbc_start, crash)
     if D > 0:
         is_chain = sc["n_switches"] >= 2.0
         one_i = lambda v: jnp.asarray([v], jnp.int32)        # noqa: E731
@@ -281,9 +314,9 @@ def _persist_with_buffer(ctx: StepCtx, st: MachineState) -> MachineState:
                      jnp.where(any_dirty, victim_idx, earliest_idx))
     ta = jnp.where(any_empty, pbc_start,
                    jnp.where(any_dirty, vic_wait,
-                             jnp.maximum(pbc_start, st.dd[earliest_idx])))
+                             tb.maximum(pbc_start, st.dd[earliest_idx])))
     pm_busy1 = st.pm_busy.at[victim_bank].set(jnp.where(
-        needs_victim, victim_pm_start + sc["nvm_w_occ"],
+        needs_victim, victim_free,
         st.pm_busy[victim_bank]))
     state2 = jnp.where(
         needs_victim & (ctx.slot_ids == victim_idx), DRAIN, state1)
@@ -292,15 +325,18 @@ def _persist_with_buffer(ctx: StepCtx, st: MachineState) -> MachineState:
 
     # write the entry (new allocation or coalesce-in-place)
     wslot = jnp.where(is_coalesce, idx, slot)
-    t_written = jnp.where(is_coalesce, pbc_start, ta) + sc["data_ns"]
-    ack = t_written + sc["ow_cpu_sw1"]
+    t_written = tb.add(jnp.where(is_coalesce, pbc_start, ta), sc["data_ns"])
+    ack = tb.add(t_written, sc["ow_cpu_sw1"])
     # Serving-SLO drain tightening (DrainPolicy.latency_target_ns): the
     # running over-target fraction *including this persist* decides
     # whether this op's drain-down runs tight.  With no target the
     # lowered scalar is INF, over_now is always 0 and tight is always
     # false — bit-exact with the pre-SLO engine.
-    lat = ack - t
-    over_now = (lat > sc["lat_target"]).astype(jnp.float64)  # lint: mirror(slo-over)
+    # the persist's four time differences, as one vector operation
+    diffs = tb.sub(jnp.stack([pbc_prev, ack, ta, t_written]),
+                   jnp.stack([arr, t, pbc_start, arr]))
+    lat = diffs[1]
+    over_now = tb.gt(lat, sc["lat_target"]).astype(jnp.float64)  # lint: mirror(slo-over)
     cnt1 = st.stats[ctx.tenant, S_PERSIST_CNT] + 1.0  # lint: mirror(slo-cnt)
     over1 = st.stats[ctx.tenant, S_SLO_OVER] + over_now  # lint: mirror(slo-run)
     tight = over1 > sc["lat_tol"] * cnt1  # lint: mirror(slo-tight)
@@ -343,7 +379,7 @@ def _persist_with_buffer(ctx: StepCtx, st: MachineState) -> MachineState:
     # drains the policy just scheduled (Dirty -> Drain) whose PM ack
     # beats the crash make their versions durable at the device
     drained_now = (state4 == DRAIN) & (state3 == DIRTY)
-    drain_ok = (drained_now & (dd4 <= crash) & (tag3 >= 0)
+    drain_ok = (drained_now & tb.le(dd4, crash) & (tag3 >= 0)
                 & (tag3 < ctx.n_track))
     pm_ver2 = pm_ver1.at[jnp.clip(tag3, 0, A - 1)].max(
         jnp.where(drain_ok, ver3, 0))
@@ -359,7 +395,7 @@ def _persist_with_buffer(ctx: StepCtx, st: MachineState) -> MachineState:
     # and a non-committed persist consumes no version number.  Resource
     # clocks (PBC/PM/core) stay as computed: the packet occupied them
     # until the power died, and the core is dead afterwards anyway.
-    commit = t_written <= crash
+    commit = tb.le(t_written, crash)
     vslot = ctx.slot_ids == victim_idx
     state5 = jnp.where(commit, state4,
                        jnp.where(vic_emit & vslot, DRAIN, st.state))
@@ -385,13 +421,13 @@ def _persist_with_buffer(ctx: StepCtx, st: MachineState) -> MachineState:
         # the batch leaves the PBC in LRU order of the drained entries
         # (the wire order the oracle's drain-down replays)
         pol_active = drained_now & commit
-        pol_order = jnp.argsort(
-            jnp.where(pol_active, lru3, INF)).astype(jnp.int32)
+        pol_order = tb.argsort(
+            jnp.where(pol_active, lru3, tb.INF)).astype(jnp.int32)
         pol_batch = chain.Batch(
             active=pol_active[pol_order],
             addr=tag3[pol_order], ver=ver3[pol_order],
             owner=owner3[pol_order],
-            emit=jnp.zeros((P,), jnp.float64) + t_written,
+            emit=jnp.broadcast_to(t_written, (P,)),
             ohop=jnp.zeros((P,), jnp.int32),
             oslot=pol_order)
         (dd_c, rows_c, hpbc_c, hstats_c, pmb_c, pmv_c,
@@ -412,17 +448,18 @@ def _persist_with_buffer(ctx: StepCtx, st: MachineState) -> MachineState:
         hop_stats = st.hop_stats
     # hop-1 telemetry row (chain row 0; maintained at every depth >= 1)
     hop_stats = hop_stats.at[0, H_FWD_CNT].add(commit.astype(jnp.float64))
+    diffs64 = tb.to_f64(diffs)
     hop_stats = hop_stats.at[0, H_FWD_SUM].add(
-        jnp.where(commit, t_written - arr, 0.0))
+        jnp.where(commit, diffs64[3], 0.0))
     hop_stats = hop_stats.at[0, H_COALESCES].add(
         (is_coalesce & commit).astype(jnp.float64))
 
-    stall = jnp.where(is_coalesce, 0.0, ta - pbc_start)
+    stall = jnp.where(is_coalesce, 0.0, diffs64[2])
     # Only a genuine Empty-shortage stall (ta > pbc_start) holds the PI
     # front beyond the pipelined issue interval.
-    pbc_free = jnp.maximum(
+    pbc_free = tb.maximum(
         channels.pbc_hold(pbc_prev, arr, sc["pbc_occ_ns"]),
-        jnp.where(is_coalesce | (ta <= pbc_start), 0.0, ta))
+        jnp.where(is_coalesce | tb.le(ta, pbc_start), tb.ZERO, ta))
     if NL > 0:
         pbc_kw = dict(lpbc=st.lpbc.at[my_leaf].set(pbc_free))
     else:
@@ -434,7 +471,8 @@ def _persist_with_buffer(ctx: StepCtx, st: MachineState) -> MachineState:
     # (the paper's core claim); the core only *observes* the ack if it
     # lands before the crash, and ack beats the crash only if the write
     # committed first, so acked => durable.
-    hist_col = (S_LAT_HIST0 + lat_bin(lat))[None]  # lint: mirror(lat-bin)
+    lat64 = diffs64[1]
+    hist_col = (S_LAT_HIST0 + lat_bin(lat64))[None]  # lint: mirror(lat-bin)
     cols = jnp.concatenate([
         jnp.asarray([S_VICTIM_CNT, S_PBCQ_SUM, S_PERSIST_SUM,
                      S_PERSIST_CNT, S_SLO_OVER, S_COALESCES, S_PM_WRITES,
@@ -442,14 +480,14 @@ def _persist_with_buffer(ctx: StepCtx, st: MachineState) -> MachineState:
         hist_col])
     vals = jnp.stack([
         ((~is_coalesce) & (~any_empty)).astype(jnp.float64),
-        jnp.maximum(pbc_prev - arr, 0.0),
-        ack - t,
+        jnp.where(tb.gt(pbc_prev, arr), diffs64[0], 0.0),
+        lat64,
         jnp.ones((), jnp.float64),
         over_now,
         is_coalesce.astype(jnp.float64),
         pm_writes_inc,
         stall,
-        (ack <= crash).astype(jnp.float64),
+        tb.le(ack, crash).astype(jnp.float64),
         commit.astype(jnp.float64),
         jnp.ones((), jnp.float64)])
     stats = st.stats.at[ctx.tenant, cols].add(vals)  # lint: mirror(stats-scatter)
@@ -470,24 +508,25 @@ def handle_persist(ctx: StepCtx, st: MachineState) -> MachineState:
         ow = sc["ow_cpu_pm"]
         crash = sc["crash_at"]
         bank = channels.bank_of(addr, ctx.n_banks)
-        pm_start = channels.service_start(st.pm_busy, bank, t + ow)
-        ack = pm_start + sc["nvm_write"] + ow
-        ok = ack <= crash
+        pm_start = channels.service_start(st.pm_busy, bank, tb.add(t, ow))
+        ack = tb.add(tb.add(pm_start, sc["nvm_write"]), ow)
+        ok = tb.le(ack, crash)
         A = st.aver.shape[0]
         tracked = _tracked(ctx, addr)
         a_idx = jnp.clip(addr, 0, A - 1)
         v_new = st.aver[a_idx] + 1
         # lint: exempt(stats-columns, S_COALESCES S_READ_HITS S_PI_DETOURS): no PB table on the volatile switch
         # lint: exempt(stats-columns, S_PBCQ_SUM S_STALL_TIME S_VICTIM_CNT): no PBC queue or eviction on the direct PM path
-        lat = ack - t
-        over_now = (lat > sc["lat_target"]).astype(jnp.float64)  # lint: mirror(slo-over)
+        lat = tb.sub(ack, t)
+        over_now = tb.gt(lat, sc["lat_target"]).astype(jnp.float64)  # lint: mirror(slo-over)
         one = jnp.ones((), jnp.float64)
-        hist_col = (S_LAT_HIST0 + lat_bin(lat))[None]  # lint: mirror(lat-bin)
+        lat64 = tb.to_f64(lat)
+        hist_col = (S_LAT_HIST0 + lat_bin(lat64))[None]  # lint: mirror(lat-bin)
         cols = jnp.concatenate([
             jnp.asarray([S_PERSIST_SUM, S_PERSIST_CNT, S_SLO_OVER,
                          S_PM_WRITES, S_ACKED, S_DURABLE], jnp.int32),
             hist_col])
-        vals = jnp.stack([ack - t, one, over_now, one,
+        vals = jnp.stack([lat64, one, over_now, one,
                           ok.astype(jnp.float64), ok.astype(jnp.float64),
                           one])
         stats = st.stats.at[ctx.tenant, cols].add(vals)  # lint: mirror(stats-scatter)
@@ -506,7 +545,7 @@ def handle_persist(ctx: StepCtx, st: MachineState) -> MachineState:
         # expensive chain legs once per step instead of twice.
         return _persist_with_buffer(ctx, st)
 
-    return jax.lax.switch(jnp.minimum(ctx.scheme, 1), [nopb, buffered], st)
+    return by_scheme(ctx.schemes, ctx.scheme, nopb, buffered, st)
 
 
 # ----------------------------------------------------------------- barrier
@@ -519,7 +558,7 @@ def handle_barrier(ctx: StepCtx, st: MachineState) -> MachineState:
     last = (st.bcount[ctx.tenant] + 1) >= ctx.n_live_t
     released = jnp.where(st.blocked & same, ctx.t,
                          st.clock).at[ctx.c].set(ctx.t)
-    waiting = st.clock.at[ctx.c].set(INF * 0.9)
+    waiting = st.clock.at[ctx.c].set(WAITING)
     return st._replace(clock=jnp.where(last, released, waiting))
 
 
@@ -529,7 +568,8 @@ HANDLERS = [handle_compute, handle_dram_read, handle_dram_write,
 
 # ---------------------------------------------------------------- recovery
 def recovery_snapshot(st: MachineState, scheme, sc, slot_active,
-                      n_banks: int, n_track: int):
+                      n_banks: int, n_track: int,
+                      schemes: tuple = ALL_SCHEMES):
     """Section V-D4 recovery pass over the crash-time machine state.
 
     Dispatches over the traced scheme like the op handlers: NoPB has no
@@ -553,12 +593,13 @@ def recovery_snapshot(st: MachineState, scheme, sc, slot_active,
     D = st.dtag.shape[0]
     NL = max(st.lpbc.shape[0], 1)
     zero = jnp.asarray(0.0, jnp.float64)
+    zero_ns = jnp.asarray(tb.ZERO, tb.DTYPE)
     zero_t = jnp.zeros((T,), jnp.float64)
     zero_h = jnp.zeros((D + 1,), jnp.float64)
     zero_l = jnp.zeros((NL,), jnp.float64)
 
     def nopb(_):
-        return st.pm_ver, zero, zero, zero_t, zero_h, zero_l
+        return st.pm_ver, zero, zero_ns, zero_t, zero_h, zero_l
 
     def pb(_):
         surviving = policy.surviving_entries(st.state, st.dd, slot_active,
@@ -588,9 +629,10 @@ def recovery_snapshot(st: MachineState, scheme, sc, slot_active,
             # entry survives iff its downstream ack is lost with the
             # power (placements are commit-gated, so wt <= crash always
             # holds — kept as written defence)
-            surv_j = (row_live & sa & (st.dwt[j] <= crash)
+            surv_j = (row_live & sa & tb.le(st.dwt[j], crash)
                       & ((st.dstate[j] == DIRTY)
-                         | ((st.dstate[j] == DRAIN) & (st.ddd[j] > crash))))
+                         | ((st.dstate[j] == DRAIN)
+                            & tb.gt(st.ddd[j], crash))))
             in_r = surv_j & (st.dtag[j] >= 0) & (st.dtag[j] < n_track)
             dv = dv.at[jnp.clip(st.dtag[j], 0, A - 1)].max(
                 jnp.where(in_r, st.dver[j], 0))
@@ -604,4 +646,4 @@ def recovery_snapshot(st: MachineState, scheme, sc, slot_active,
         cost = policy.recovery_burst_cost(sc, per_bank, n_total)
         return dv, n_total, cost, per_t, per_hop, per_leaf
 
-    return jax.lax.switch(jnp.minimum(scheme, 1), [nopb, pb], None)
+    return by_scheme(schemes, scheme, nopb, pb, None)

@@ -56,8 +56,8 @@ plus the smallest of ``2 ow_cpu_pm + min(nvm_read, nvm_write)`` and
 least two one-way link crossings), since ``max(b, x) >= x``.  Induction
 from ``t_0 = t_issue`` gives the bound in exact arithmetic.  The gate
 adds only *half* of ``lat_lo`` per op: the other half is slack for
-rounding, which in float64 (IEEE, or the chip's emulated float pairs)
-stays many orders of magnitude below ``lat_lo / 2`` (73 ns or more with
+rounding, which in the gate's plain float64 sum (IEEE, or the chip's
+emulated float pairs) stays many orders of magnitude below ``lat_lo / 2`` (73 ns or more with
 Table I's latencies) for any simulated time below ~10^12 ns.  A window is then sure to
 fail the interleave guard where ``others_min <= lb``, and where
 ``lat_lo <= 0`` the gate proves nothing and lets every window through.
@@ -70,7 +70,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.engine import channels, fabric, policy
-from repro.core.engine.state import (DIRTY, EMPTY, INF, H_FWD_CNT, H_FWD_SUM,
+from repro.core.engine import timebase as tb
+from repro.core.engine.state import (DIRTY, EMPTY, H_FWD_CNT, H_FWD_SUM,
                                      S_ACKED, S_DURABLE, S_LAT_HIST0,
                                      S_PBCQ_SUM, S_PERSIST_CNT,
                                      S_PERSIST_SUM, S_PM_WRITES, S_READ_CNT,
@@ -97,7 +98,7 @@ MACRO_ABORT_REASONS = ("window", "fabric", "deep", "epoch_boundary",
 
 class Window(NamedTuple):
     """The selected core's window as the step sees it before a replay."""
-    w_gap: jax.Array        # (kmax,) f64 gaps from the cursor on
+    w_gap: jax.Array        # (kmax,) gaps from the cursor on (time)
     k_cap: jax.Array        # slots left in the stream, at most kmax
     k_live: jax.Array       # planned run length at the cursor, <= k_cap
     cand: jax.Array         # a live op heads the window
@@ -107,7 +108,7 @@ class Window(NamedTuple):
     others_min: jax.Array   # earliest next issue time of the other cores
 
 
-def macro_window(ctx, gaps64, lengths, mlen, tsel, valid, live, i, *,
+def macro_window(ctx, gaps_t, lengths, mlen, tsel, valid, live, i, *,
                  kmax: int) -> Window:
     """The window at core ``ctx.c``'s cursor ``i``, read from the run
     plan ``mlen`` and the issue times ``tsel`` without replaying it."""
@@ -116,7 +117,7 @@ def macro_window(ctx, gaps64, lengths, mlen, tsel, valid, live, i, *,
     # the grid pads L by kmax slots so the slice never clamps (see
     # grid._stack_traces)
     w_gap = jax.lax.dynamic_slice(
-        gaps64, (c.astype(jnp.int32), i.astype(jnp.int32)), (1, kmax))[0]
+        gaps_t, (c.astype(jnp.int32), i.astype(jnp.int32)), (1, kmax))[0]
     k_cap = jnp.clip(lengths[c] - i, 0, kmax)
     k_live = jnp.minimum(mlen[c, i].astype(jnp.int32), k_cap)
     is_nopb = ctx.scheme == 0                       # Scheme.NOPB
@@ -136,7 +137,7 @@ def macro_window(ctx, gaps64, lengths, mlen, tsel, valid, live, i, *,
         deep_ok=is_nopb | (sc["n_switches"] < 2.0),
         # no other core may issue inside the window (strict: argmin
         # ties break by index, so equality must abort too)
-        others_min=jnp.min(tsel.at[c].set(INF)))
+        others_min=tb.min(tsel.at[c].set(tb.INF)))
 
 
 def _abort_vec(win: Window, ep_ok, no_ilv, guard):
@@ -166,15 +167,21 @@ def macro_gate(win: Window, sc, t_issue, valid, live):
     ``interleave`` in the attribution, and telling them apart needs
     ``t_last`` itself.
     """
+    # a bound with slack, not a decision: plain float64 is enough
+    ns = {k: tb.to_f64(sc[k]) for k in ("ow_cpu_pm", "nvm_read",
+                                         "nvm_write", "ow_cpu_sw1",
+                                         "pbc_proc_ns", "tag_ns",
+                                         "data_ns")}
     lat_lo = jnp.minimum(
-        2.0 * sc["ow_cpu_pm"] + jnp.minimum(sc["nvm_read"], sc["nvm_write"]),
-        2.0 * sc["ow_cpu_sw1"] + sc["pbc_proc_ns"] + sc["tag_ns"]
-        + sc["data_ns"])
+        2.0 * ns["ow_cpu_pm"] + jnp.minimum(ns["nvm_read"], ns["nvm_write"]),
+        2.0 * ns["ow_cpu_sw1"] + ns["pbc_proc_ns"] + ns["tag_ns"]
+        + ns["data_ns"])
     floor = 0.5 * lat_lo
     j = jnp.arange(win.w_gap.shape[0])
-    lb = t_issue + jnp.sum(jnp.where((j >= 1) & (j < win.k_live),
-                                     win.w_gap + floor, 0.0))
-    may_fit = (floor <= 0.0) | (win.others_min > lb)
+    lb = tb.to_f64(t_issue) + jnp.sum(
+        jnp.where((j >= 1) & (j < win.k_live),
+                  tb.to_f64(win.w_gap) + floor, 0.0))
+    may_fit = (floor <= 0.0) | (tb.to_f64(win.others_min) > lb)
     want = ((win.elig & win.fab_ok & win.deep_ok & may_fit)
             | (valid & ~live & (win.k_cap >= 2)))
     # skipped: no epoch gate, and every window the fabric and deep
@@ -225,9 +232,10 @@ def macro_step(ctx, st, ops, addrs, win: Window, valid, live, t_issue, i,
     # cursor; the sequential masked adds reproduce the step-at-a-time
     # rounding order exactly.  Monotone issue times (gaps >= 0) make
     # first-dead imply all-dead.
-    gaps_ok = jnp.all(w_gap >= 0.0)
+    gaps_ok = jnp.all(tb.ge(w_gap, tb.ZERO))
     clk_dead, _ = jax.lax.scan(
-        lambda ck, jg: (jnp.where(jg[0] < k_cap, ck + jg[1], ck), None),
+        lambda ck, jg: (jnp.where(jg[0] < k_cap, tb.add(ck, jg[1]), ck),
+                        None),
         st.clock[c], (jnp.arange(kmax), w_gap))
     dead_ok = valid & ~live & gaps_ok & (k_cap >= 2)
     st_dead = st._replace(clock=st.clock.at[c].set(clk_dead))
@@ -249,6 +257,7 @@ def macro_step(ctx, st, ops, addrs, win: Window, valid, live, t_issue, i,
         pbc0 = st.pbc_busy
 
     ow = sc["ow_cpu_pm"]
+    proc_tag = tb.add(sc["pbc_proc_ns"], sc["tag_ns"])
 
     # The window replay is a lax.scan over the kmax slots (not a Python
     # unroll): every iteration runs the identical expressions in
@@ -263,31 +272,32 @@ def macro_step(ctx, st, ops, addrs, win: Window, valid, live, t_issue, i,
         j, o_j, a_j, g_j = x
         m = j < k_live
         is_p = o_j == int(Op.PERSIST)
-        t_j = clk + g_j
+        t_j = tb.add(clk, g_j)
         t_last = jnp.where(m, t_j, t_last)
         bank = channels.bank_of(a_j, ctx.n_banks)
         tracked = (a_j >= 0) & (a_j < ctx.n_track)
         a_idx = jnp.clip(a_j, 0, A - 1)
 
-        # ---- PM read (handler miss path; identical in both schemes)
-        pm_start_r = channels.service_start(pmb_cur, bank, t_j + ow)
-        resp = pm_start_r + sc["nvm_read"] + ow
+        # the two legs' arrivals, and below their ends, as one vector
+        # operation each (elementwise: the same roundings)
+        t_pm, arr = tb.add(t_j, jnp.stack([ow, sc["ow_cpu_sw1"]]))
+
+        # ---- PM read (handler miss path; identical in both schemes) and
+        # persist, NoPB leg (always exact: no guard): one bank wait
+        pm_start = channels.service_start(pmb_cur, bank, t_pm)
+        read_end, write_end, read_free, write_free = tb.add(
+            pm_start, jnp.stack([sc["nvm_read"], sc["nvm_write"],
+                                 sc["nvm_r_occ"], sc["nvm_w_occ"]]))
+        resp, ack_n = tb.add(jnp.stack([read_end, write_end]), ow)
         state_rd = policy.lazy_free(state_cur, dd_cur, t_j)
         has_rd = jnp.any(ctx.slot_active & (tag_cur == a_j)
                          & (state_rd != EMPTY))
-        pmb_rd = pmb_cur.at[bank].set(pm_start_r + sc["nvm_r_occ"])
-
-        # ---- persist, NoPB leg (always exact: no guard)
-        pm_start_w = channels.service_start(pmb_cur, bank, t_j + ow)
-        ack_n = pm_start_w + sc["nvm_write"] + ow
-        ok_n = ack_n <= crash
-        pmb_wn = channels.reserve(pmb_cur, bank, pm_start_w,
-                                  sc["nvm_w_occ"])
+        pmb_rd = pmb_cur.at[bank].set(read_free)
+        ok_n = tb.le(ack_n, crash)
+        pmb_wn = pmb_cur.at[bank].set(write_free)
 
         # ---- persist, buffered leg (fresh-Empty allocation only)
-        arr = t_j + sc["ow_cpu_sw1"]
-        pbc_start = channels.pbc_start(pbc_cur, arr,
-                                       sc["pbc_proc_ns"] + sc["tag_ns"])
+        pbc_start = channels.pbc_start(pbc_cur, arr, proc_tag)
         state_p1 = policy.lazy_free(state_cur, dd_cur, pbc_start)
         has_dirty = jnp.any(ctx.slot_active & (tag_cur == a_j)
                             & (state_p1 == DIRTY))
@@ -298,9 +308,9 @@ def macro_step(ctx, st, ops, addrs, win: Window, valid, live, t_issue, i,
         over_quota = occ_t >= sc["quota"][ctx.tenant]
         empty_mask = ctx.slot_active & (state_p1 == EMPTY) & ~over_quota
         any_empty = jnp.any(empty_mask)
-        wslot = jnp.argmin(jnp.where(empty_mask, lru_cur, INF))
-        t_written = pbc_start + sc["data_ns"]
-        ack_p = t_written + sc["ow_cpu_sw1"]
+        wslot = tb.argmin(jnp.where(empty_mask, lru_cur, tb.INF))
+        t_written = tb.add(pbc_start, sc["data_ns"])
+        ack_p = tb.add(t_written, sc["ow_cpu_sw1"])
         v_new = aver_cur[a_idx] + 1
         state_w = jnp.where(ctx.slot_ids == wslot, DIRTY, state_p1)
         tag_w = tag_cur.at[wslot].set(a_j)
@@ -327,8 +337,14 @@ def macro_step(ctx, st, ops, addrs, win: Window, valid, live, t_issue, i,
         # serving-SLO tightening mirror (handler computes tight from the
         # pre-op stats row *including this persist*; with no target the
         # lowered scalar is INF, over stays 0 and tight is never true)
-        lat_p = ack_p - t_j
-        over_p = (lat_p > sc["lat_target"]).astype(jnp.float64)  # lint: mirror(slo-over)
+        # the op's five time differences, as one vector operation
+        diffs = tb.sub(
+            jnp.stack([resp, pbc_cur, jnp.where(is_nopb, ack_n, ack_p),
+                       t_written, ack_p]),
+            jnp.stack([t_j, arr, t_j, arr, t_j]))
+        diffs64 = tb.to_f64(diffs)
+        lat_j, lat_p = diffs[2], diffs[4]
+        over_p = tb.gt(lat_p, sc["lat_target"]).astype(jnp.float64)  # lint: mirror(slo-over)
         cnt1 = stats_cur[ctx.tenant, S_PERSIST_CNT] + 1.0  # lint: mirror(slo-cnt)
         over1 = stats_cur[ctx.tenant, S_SLO_OVER] + over_p  # lint: mirror(slo-run)
         tight = over1 > sc["lat_tol"] * cnt1  # lint: mirror(slo-tight)
@@ -344,14 +360,13 @@ def macro_step(ctx, st, ops, addrs, win: Window, valid, live, t_issue, i,
         state_wp = jnp.where(is_rf, state_w, st4_pb)
         dd_wp = jnp.where(is_rf, dd_cur, dd4_pb)
         pmb_wp = jnp.where(is_rf, pmb_cur, pmb2_pb)
-        pbcq_inc = jnp.maximum(pbc_cur - arr, 0.0)
-        pbc_wp = jnp.maximum(
-            channels.pbc_hold(pbc_cur, arr, sc["pbc_occ_ns"]), 0.0)
+        pbcq_inc = jnp.where(tb.gt(pbc_cur, arr), diffs64[1], 0.0)
+        pbc_wp = channels.pbc_hold(pbc_cur, arr, sc["pbc_occ_ns"])
 
         # ---- per-op guard
-        g_wr = (any_empty & (t_written <= crash)
+        g_wr = (any_empty & tb.le(t_written, crash)
                 & (~is_rf | (~has_dirty & rf_zero)))
-        g_op = ((t_j <= crash)
+        g_op = (tb.le(t_j, crash)
                 & jnp.where(pb_like, jnp.where(is_p, g_wr, ~has_rd), True))
         guard = guard & jnp.where(m, g_op, True)
 
@@ -376,7 +391,7 @@ def macro_step(ctx, st, ops, addrs, win: Window, valid, live, t_issue, i,
         pbc_cur = jnp.where(sel_wp, pbc_wp, pbc_cur)
         aver_cur = aver_cur.at[a_idx].add(
             jnp.where(m & is_p & tracked, 1, 0))
-        pv_ok = jnp.where(is_nopb, ok_n, ~is_rf & (dd_new_pb <= crash))
+        pv_ok = jnp.where(is_nopb, ok_n, ~is_rf & tb.le(dd_new_pb, crash))
         pm_ver_cur = pm_ver_cur.at[a_idx].max(
             jnp.where(m & is_p & tracked & pv_ok, v_new, 0))
         # stats / telemetry: adds of exact 0.0 are bitwise identities
@@ -389,25 +404,24 @@ def macro_step(ctx, st, ops, addrs, win: Window, valid, live, t_issue, i,
         # element-wise identical to the chained adds.
         # lint: exempt(stats-columns, S_COALESCES S_READ_HITS S_PI_DETOURS): guard aborts PB-hit/coalesce windows
         # lint: exempt(stats-columns, S_STALL_TIME S_VICTIM_CNT): guard aborts stall/eviction windows
-        lat_j = jnp.where(is_nopb, ack_n, ack_p) - t_j
-        over_j = (lat_j > sc["lat_target"]).astype(jnp.float64)  # lint: mirror(slo-over)
-        hist_col = (S_LAT_HIST0 + lat_bin(lat_j))[None]  # lint: mirror(lat-bin)
+        over_j = tb.gt(lat_j, sc["lat_target"]).astype(jnp.float64)  # lint: mirror(slo-over)
+        lat64 = diffs64[2]
+        hist_col = (S_LAT_HIST0 + lat_bin(lat64))[None]  # lint: mirror(lat-bin)
         scols = jnp.concatenate([
             jnp.asarray([S_READ_SUM, S_READ_CNT, S_PBCQ_SUM,
                          S_PERSIST_SUM, S_PERSIST_CNT, S_SLO_OVER,
                          S_PM_WRITES, S_ACKED, S_DURABLE], jnp.int32),
             hist_col])
         svals = jnp.stack([
-            jnp.where(sel_r, resp - t_j, 0.0),
+            jnp.where(sel_r, diffs64[0], 0.0),
             jnp.where(sel_r, 1.0, 0.0),
             jnp.where(sel_wp, pbcq_inc, 0.0),
-            jnp.where(m & is_p,
-                      jnp.where(is_nopb, ack_n, ack_p) - t_j, 0.0),
+            jnp.where(m & is_p, lat64, 0.0),
             jnp.where(m & is_p, 1.0, 0.0),
             jnp.where(m & is_p, over_j, 0.0),
             jnp.where(m & is_p & (is_nopb | ~is_rf), 1.0, 0.0),
             jnp.where(m & is_p,
-                      jnp.where(is_nopb, ok_n, ack_p <= crash)
+                      jnp.where(is_nopb, ok_n, tb.le(ack_p, crash))
                       .astype(jnp.float64), 0.0),
             jnp.where(m & is_p,
                       jnp.where(is_nopb, ok_n.astype(jnp.float64), 1.0),
@@ -417,7 +431,7 @@ def macro_step(ctx, st, ops, addrs, win: Window, valid, live, t_issue, i,
         hop_cur = hop_cur.at[
             0, jnp.asarray([H_FWD_CNT, H_FWD_SUM], jnp.int32)
         ].add(jnp.stack([jnp.where(sel_wp, 1.0, 0.0),
-                         jnp.where(sel_wp, t_written - arr, 0.0)]))
+                         jnp.where(sel_wp, diffs64[3], 0.0)]))
         return (clk, state_cur, tag_cur, lru_cur, dd_cur, ver_cur,
                 owner_cur, pmb_cur, pbc_cur, pm_ver_cur, aver_cur,
                 stats_cur, hop_cur, guard, t_last), None
@@ -430,14 +444,14 @@ def macro_step(ctx, st, ops, addrs, win: Window, valid, live, t_issue, i,
      guard, t_last), _ = jax.lax.scan(
         win_op, carry0, (jnp.arange(kmax), w_ops, w_addr, w_gap))
 
-    no_ilv = win.others_min > t_last
+    no_ilv = tb.gt(win.others_min, t_last)
     # epoch-scheduled grids: the whole window must live in the head
     # op's epoch (boundary instants belong to the *next* epoch, so the
     # last issue time must be strictly below the next boundary)
     if next_epoch_bound is None:
         ep_ok = jnp.asarray(True)
     else:
-        ep_ok = t_last < next_epoch_bound
+        ep_ok = tb.lt(t_last, next_epoch_bound)
     live_ok = (win.elig & win.fab_ok & win.deep_ok & ep_ok & guard
                & no_ilv)
     # prioritized abort attribution (MACRO_ABORT_REASONS order): each

@@ -35,7 +35,8 @@ from repro.core.params import (DEFAULT_DRAIN_PRESET,          # noqa: F401
                                RF_LOW_WATER_DRAINS, SCHEME_NAMES, Scheme,
                                preset_count, rf_drain_count,
                                threshold_count)
-from repro.core.engine.state import DIRTY, DRAIN, EMPTY, INF
+from repro.core.engine import timebase as tb
+from repro.core.engine.state import DIRTY, DRAIN, EMPTY
 
 
 # ---------------------------------------------------------------------------
@@ -44,7 +45,7 @@ from repro.core.engine.state import DIRTY, DRAIN, EMPTY, INF
 
 def lazy_free(state, dd, now):
     """Observe Drain->Empty transitions whose PM ack time has passed."""
-    freed = (state == DRAIN) & (dd <= now)
+    freed = (state == DRAIN) & tb.le(dd, now)
     return jnp.where(freed, EMPTY, state)
 
 
@@ -95,7 +96,7 @@ def select_slot(sc, state, slot_active, lru, dd, owner, tenant, occ):
     own = owner == tenant
     empty_mask = slot_active & (state == EMPTY) & ~over_quota
     any_empty = jnp.any(empty_mask)
-    empty_idx = jnp.argmin(jnp.where(empty_mask, lru, INF))
+    empty_idx = tb.argmin(jnp.where(empty_mask, lru, tb.INF))
     dirty_all = slot_active & (state == DIRTY)
     over_share = occ >= sc["share"]                       # (T,) bool
     hot = dirty_all & over_share[jnp.clip(owner, 0, T - 1)]
@@ -103,10 +104,10 @@ def select_slot(sc, state, slot_active, lru, dd, owner, tenant, occ):
     dirty_mask = jnp.where(over_quota, dirty_all & own,
                            jnp.where(use_hot, hot, dirty_all))
     any_dirty = jnp.any(dirty_mask)
-    victim_idx = jnp.argmin(jnp.where(dirty_mask, lru, INF))
+    victim_idx = tb.argmin(jnp.where(dirty_mask, lru, tb.INF))
     drain_all = slot_active & (state == DRAIN)
     drain_mask = jnp.where(over_quota, drain_all & own, drain_all)
-    earliest_idx = jnp.argmin(jnp.where(drain_mask, dd, INF))
+    earliest_idx = tb.argmin(jnp.where(drain_mask, dd, tb.INF))
     return any_empty, empty_idx, any_dirty, victim_idx, earliest_idx
 
 
@@ -117,11 +118,14 @@ def drain_immediate(sc, bank, slot_ids, wslot, t_written,
     The channel FIFO preserves the version order of same-line drains.
     Returns (state4, dd4, pm_busy2, policy_writes).
     """
-    pm_start2 = jnp.maximum(pm_busy1[bank], t_written + sc["ow_sw1_pm"])
-    dd_new = pm_start2 + sc["nvm_write"] + sc["ow_sw1_pm"]
+    pm_start2 = tb.maximum(pm_busy1[bank],
+                           tb.add(t_written, sc["ow_sw1_pm"]))
+    write_end, bank_free = tb.add(
+        pm_start2, jnp.stack([sc["nvm_write"], sc["nvm_w_occ"]]))
+    dd_new = tb.add(write_end, sc["ow_sw1_pm"])
     state4 = jnp.where(slot_ids == wslot, DRAIN, state3)
     dd4 = dd3.at[wslot].set(dd_new)
-    pm_busy2 = pm_busy1.at[bank].set(pm_start2 + sc["nvm_w_occ"])
+    pm_busy2 = pm_busy1.at[bank].set(bank_free)
     return state4, dd4, pm_busy2, jnp.asarray(1.0, jnp.float64)
 
 
@@ -135,7 +139,7 @@ def surviving_entries(state, dd, slot_active, crash_at):
     (lazily) Empty at the crash instant.
     """
     return slot_active & ((state == DIRTY) |
-                          ((state == DRAIN) & (dd > crash_at)))
+                          ((state == DRAIN) & tb.gt(dd, crash_at)))
 
 
 def recovery_burst_cost(sc, per_bank, n):
@@ -153,9 +157,10 @@ def recovery_burst_cost(sc, per_bank, n):
     worst = jnp.max(per_bank)
     return jnp.where(
         n > 0,
-        (worst - 1.0) * sc["nvm_w_occ"] + sc["nvm_write"]
-        + 2.0 * sc["ow_sw1_pm"],
-        0.0)
+        tb.add(tb.add(tb.mul(sc["nvm_w_occ"], jnp.maximum(worst - 1.0, 0.0)),
+                      sc["nvm_write"]),
+               tb.mul(sc["ow_sw1_pm"], 2)),
+        tb.ZERO)
 
 
 def drain_threshold_preset(sc, n_banks, slot_active, t_written,
@@ -213,8 +218,8 @@ def drain_threshold_preset(sc, n_banks, slot_active, t_written,
     k = jnp.maximum(k_thresh, k_low)
     if defer is not None:
         k = jnp.where(defer, 0.0, k)
-    key = jnp.where(dirty_mask, lru3, INF)
-    rank = jnp.argsort(jnp.argsort(key)).astype(jnp.float64)
+    key = jnp.where(dirty_mask, lru3, tb.INF)
+    rank = jnp.argsort(tb.argsort(key)).astype(jnp.float64)
     to_drain = (rank < k) & dirty_mask
     banks = tag3 % B
     # rank among drained entries sharing a bank (serializes the burst per
@@ -224,15 +229,18 @@ def drain_threshold_preset(sc, n_banks, slot_active, t_written,
     rank_b = jnp.sum(
         (same_bank & earlier & to_drain[None, :]).astype(jnp.float64),
         axis=1)
-    start_i = (jnp.maximum(pm_busy1[banks], t_written + sc["ow_sw1_pm"])
-               + rank_b * sc["nvm_w_occ"])
-    dd_j = start_i + sc["nvm_write"] + sc["ow_sw1_pm"]
+    start_i = tb.add(tb.maximum(pm_busy1[banks],
+                                tb.add(t_written, sc["ow_sw1_pm"])),
+                     tb.mul(sc["nvm_w_occ"], rank_b))
+    dd_j = tb.add(tb.add(start_i, sc["nvm_write"]), sc["ow_sw1_pm"])
     state4 = jnp.where(to_drain, DRAIN, state3)
     dd4 = jnp.where(to_drain, dd_j, dd3)
-    busy_after = jnp.where(to_drain, start_i + sc["nvm_w_occ"], 0.0)
-    per_bank = jnp.max(
-        jnp.where(same_bank & to_drain[None, :], busy_after[None, :], 0.0),
+    busy_after = jnp.where(to_drain, tb.add(start_i, sc["nvm_w_occ"]),
+                           tb.ZERO)
+    per_bank = tb.max(
+        jnp.where(same_bank & to_drain[None, :], busy_after[None, :],
+                  tb.ZERO),
         axis=1)
-    pm_busy2 = jnp.maximum(
-        pm_busy1, jnp.zeros((B,), jnp.float64).at[banks].max(per_bank))
+    pm_busy2 = tb.maximum(
+        pm_busy1, jnp.zeros((B,), tb.DTYPE).at[banks].max(per_bank))
     return state4, dd4, pm_busy2, k

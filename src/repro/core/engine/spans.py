@@ -18,6 +18,7 @@ What reads each (the benchmark's per-layer metrics, ``bench/metrics/``):
   (:func:`last_macro_hit_rate`) — ``macro_hit``;
 * :attr:`Call.macro_gate_steps` over :attr:`Call.steps` —
   ``macro_gate_open``;
+* :attr:`Call.time_ops` — ``step_time_ops``;
 * :func:`jax_seconds` as :attr:`Call.jax_s` of the first timed call —
   ``setup_trace_s``, ``setup_lower_s``, ``setup_compile_s``;
 * :func:`compile_count` — the benchmark's ``window_compiles`` check and
@@ -69,6 +70,9 @@ class Call:
     macro_gate_steps: int = 0       # grid steps the macro replay ran on
     abort_reasons: Dict[str, int] = dataclasses.field(
         default_factory=lambda: dict.fromkeys(MACRO_ABORT_REASONS, 0))
+    time_ops: int = 0               # time-word operations in one cell's
+                                    # grid step (engine.timebase), counted
+                                    # when the program was traced
     compiles: int = 0               # engine programs built by the call
 
 

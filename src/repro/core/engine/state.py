@@ -29,11 +29,13 @@ from typing import Dict, NamedTuple
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.engine import timebase as tb
 from repro.core.params import (PBEState, PCSConfig, epoch_value,
                                hop_drain_counts, preset_count, resolve_epoch,
                                tenant_drain_counts, threshold_count)
 
-INF = 1e30
+INF = 1e30     # the engine's finite infinity for counts and quotas;
+               # time words use ``timebase.INF``, its bit pattern
 
 # Epoched-schedule lowering (DESIGN §7): the sc keys that gain a leading
 # (E,) epoch axis when any config in the grid carries a Schedule.  The
@@ -44,6 +46,23 @@ INF = 1e30
 EPOCH_KEYS = ("threshold_count", "preset_count", "quota", "share",
               "t_threshold", "t_preset", "deep_thr", "deep_pre",
               "lat_target", "leaf_of_t")
+
+# The sc keys that hold times (ns): they lower to time words
+# (``engine.timebase``); every other key is a float64 count or flag.
+TIME_KEYS = frozenset((
+    "tag_ns", "data_ns", "pbc_proc_ns", "pbc_occ_ns", "pbc_read_ns",
+    "pbc_read_occ", "nvm_read", "nvm_write", "nvm_r_occ", "nvm_w_occ",
+    "dram_ns", "fwd_margin", "switch_pipe", "ow_cpu_pm", "ow_cpu_sw1",
+    "ow_sw1_pm", "hop_ns", "link_ns", "deep_tag", "deep_data",
+    "lat_target", "crash_at", "epoch_bounds"))
+
+
+def lower_scalars(sc: Dict[str, "float | np.ndarray"]
+                  ) -> Dict[str, np.ndarray]:
+    """The program's operands from :func:`scalars_from_config` rows (or
+    their stack): time keys as time words, the rest as float64."""
+    return {k: (tb.from_host(v) if k in TIME_KEYS
+                else np.asarray(v, np.float64)) for k, v in sc.items()}
 
 # statistics vector layout
 S_PERSIST_SUM = 0
@@ -165,20 +184,22 @@ class MachineState(NamedTuple):
     The scan carry is packed: categorical columns (``state``/``owner``
     and their deep-hop twins) live in int8, barrier arrival counts in
     int16 — weak-typed literal comparisons and ``where`` selects keep
-    the narrow dtype through every handler.  Every *time* column stays
-    float64: the issue-time merge, the crash compares and the lazily
-    freed drain-ack stamps all subtract nanosecond-scale quantities
-    from ~1e9-scale clocks, where float32 would quantize at ~100 ns and
-    break the bit-exact engine<->oracle differentials.  ``tag`` (cache
-    lines up to 2^20+) and the version counters stay int32.
+    the narrow dtype through every handler.  Every *time* column holds
+    time words (``engine.timebase``: IEEE binary64 bit patterns in
+    int64, rounded like IEEE on every backend): the issue-time merge,
+    the crash compares and the lazily freed drain-ack stamps decide on
+    nanosecond-scale differences of ~1e9-scale clocks, where any other
+    rounding breaks the bit-exact engine<->oracle differentials.
+    ``tag`` (cache lines up to 2^20+) and the version counters stay
+    int32.
     """
 
-    clock: jnp.ndarray     # (C,)  f64  per-core clocks
+    clock: jnp.ndarray     # (C,)  time  per-core clocks
     ptr: jnp.ndarray       # (C,)  i32  per-core trace cursors
     tag: jnp.ndarray       # (P,)  i32  TAT tags (P = max_pbe)
     state: jnp.ndarray     # (P,)  i8   ST states (Empty/Dirty/Drain)
-    lru: jnp.ndarray       # (P,)  f64  LRU stamps
-    dd: jnp.ndarray        # (P,)  f64  in-flight drain-ack times
+    lru: jnp.ndarray       # (P,)  time  LRU stamps
+    dd: jnp.ndarray        # (P,)  time  in-flight drain-ack times
     ver: jnp.ndarray       # (P,)  i32  per-entry persist version
     owner: jnp.ndarray     # (P,)  i8   tenant that last wrote each entry
                            #            (quota occupancy, weighted victim
@@ -186,8 +207,8 @@ class MachineState(NamedTuple):
                            #            per-tenant recovery attribution)
     aver: jnp.ndarray      # (A,)  i32  per-address issued-version counter
     pm_ver: jnp.ndarray    # (A,)  i32  newest version durable at PM
-    pm_busy: jnp.ndarray   # (B,)  f64  PM bank next-free times
-    pbc_busy: jnp.ndarray  # ()    f64  PBC next-free time
+    pm_busy: jnp.ndarray   # (B,)  time  PM bank next-free times
+    pbc_busy: jnp.ndarray  # ()    time  PBC next-free time
     blocked: jnp.ndarray   # (C,)  bool blocked at barrier
     bcount: jnp.ndarray    # (T,)  i16  per-tenant barrier arrival counts
     stats: jnp.ndarray     # (T, N_STATS) f64 per-tenant accumulators
@@ -197,13 +218,13 @@ class MachineState(NamedTuple):
     # byte-identical code (D == 0 skips the chain entirely at trace time).
     dtag: jnp.ndarray      # (D, P) i32  deep-hop TAT tags
     dstate: jnp.ndarray    # (D, P) i8   deep-hop ST states
-    dlru: jnp.ndarray      # (D, P) f64  deep-hop LRU stamps
-    ddd: jnp.ndarray       # (D, P) f64  deep-hop in-flight forward-ack times
+    dlru: jnp.ndarray      # (D, P) time  deep-hop LRU stamps
+    ddd: jnp.ndarray       # (D, P) time  deep-hop in-flight forward-ack times
     dver: jnp.ndarray      # (D, P) i32  deep-hop held persist versions
     downer: jnp.ndarray    # (D, P) i8   owning tenant (recovery attribution)
-    dwt: jnp.ndarray       # (D, P) f64  commit time into this hop's cells
+    dwt: jnp.ndarray       # (D, P) time  commit time into this hop's cells
                            #             (crash gate + read visibility)
-    hpbc: jnp.ndarray      # (D,)   f64  deep-hop PBC / inter-switch channel
+    hpbc: jnp.ndarray      # (D,)   time  deep-hop PBC / inter-switch channel
                            #             next-free times
     hop_stats: jnp.ndarray  # (Hmax, N_HOP_STATS) f64 per-switch telemetry
     # ---- fabric (fan-out) columns, NL = n_leaves_max when > 1 else 0 ----
@@ -211,7 +232,7 @@ class MachineState(NamedTuple):
     # replace the shared scalar ``pbc_busy`` (dead-carried) when the grid
     # holds any multi-leaf fabric.  NL == 0 skips the fabric code at
     # trace time, keeping chain-only grids byte-identical to PR 5.
-    lpbc: jnp.ndarray      # (NL,)  f64  per-leaf PBC next-free times
+    lpbc: jnp.ndarray      # (NL,)  time  per-leaf PBC next-free times
 
 
 def init_state(n_cores: int, max_pbe: int, pm_banks: int,
@@ -224,31 +245,31 @@ def init_state(n_cores: int, max_pbe: int, pm_banks: int,
     if T > 127:
         raise ValueError("n_tenants_max exceeds the int8 owner column")
     return MachineState(
-        clock=jnp.zeros((n_cores,), jnp.float64),
+        clock=jnp.zeros((n_cores,), tb.DTYPE),
         ptr=jnp.zeros((n_cores,), jnp.int32),
         tag=jnp.full((max_pbe,), -1, jnp.int32),
         state=jnp.full((max_pbe,), EMPTY, jnp.int8),
-        lru=jnp.zeros((max_pbe,), jnp.float64),
-        dd=jnp.zeros((max_pbe,), jnp.float64),
+        lru=jnp.zeros((max_pbe,), tb.DTYPE),
+        dd=jnp.zeros((max_pbe,), tb.DTYPE),
         ver=jnp.zeros((max_pbe,), jnp.int32),
         owner=jnp.zeros((max_pbe,), jnp.int8),
         aver=jnp.zeros((A,), jnp.int32),
         pm_ver=jnp.zeros((A,), jnp.int32),
-        pm_busy=jnp.zeros((pm_banks,), jnp.float64),
-        pbc_busy=jnp.zeros((), jnp.float64),
+        pm_busy=jnp.zeros((pm_banks,), tb.DTYPE),
+        pbc_busy=jnp.zeros((), tb.DTYPE),
         blocked=jnp.zeros((n_cores,), bool),
         bcount=jnp.zeros((T,), jnp.int16),
         stats=jnp.zeros((T, N_STATS), jnp.float64),
         dtag=jnp.full((D, max_pbe), -1, jnp.int32),
         dstate=jnp.full((D, max_pbe), EMPTY, jnp.int8),
-        dlru=jnp.zeros((D, max_pbe), jnp.float64),
-        ddd=jnp.zeros((D, max_pbe), jnp.float64),
+        dlru=jnp.zeros((D, max_pbe), tb.DTYPE),
+        ddd=jnp.zeros((D, max_pbe), tb.DTYPE),
         dver=jnp.zeros((D, max_pbe), jnp.int32),
         downer=jnp.zeros((D, max_pbe), jnp.int8),
-        dwt=jnp.zeros((D, max_pbe), jnp.float64),
-        hpbc=jnp.zeros((D,), jnp.float64),
+        dwt=jnp.zeros((D, max_pbe), tb.DTYPE),
+        hpbc=jnp.zeros((D,), tb.DTYPE),
         hop_stats=jnp.zeros((D + 1, N_HOP_STATS), jnp.float64),
-        lpbc=jnp.zeros((NL,), jnp.float64),
+        lpbc=jnp.zeros((NL,), tb.DTYPE),
     )
 
 

@@ -57,11 +57,12 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.engine import spans
-from repro.core.engine.handlers import HANDLERS, StepCtx, recovery_snapshot
+from repro.core.engine import timebase as tb
+from repro.core.engine.handlers import (ALL_SCHEMES, HANDLERS, StepCtx,
+                                        recovery_snapshot)
 from repro.core.engine.macro import (MACRO_ABORT_REASONS, macro_gate,
                                     macro_step, macro_window)
-from repro.core.engine.state import (EPOCH_KEYS, INF, MachineState,
-                                     init_state)
+from repro.core.engine.state import EPOCH_KEYS, MachineState, init_state
 from repro.core.params import MACRO_KMAX, Op
 
 # Steps per inner scan segment of the chunked driver.  Segment
@@ -69,6 +70,9 @@ from repro.core.params import MACRO_KMAX, Op
 # stream end), so results are invariant to this constant; it trades
 # while_loop trip overhead against wasted post-exhaustion steps.
 CHUNK = 128
+
+# an issue time at or above this is the "no op left" sentinel
+NO_OP = tb.bits(0.5e30)
 
 
 def resolve_epoch_sc(sc, t_issue):
@@ -94,10 +98,10 @@ def resolve_epoch_sc(sc, t_issue):
     if "epoch_bounds" not in sc:
         return sc, None
     eb = sc["epoch_bounds"]
-    ep = jnp.sum((eb <= t_issue).astype(jnp.int32))
+    ep = jnp.sum(tb.le(eb, t_issue).astype(jnp.int32))
     sc_op = {k: (v[ep] if k in EPOCH_KEYS else v)
              for k, v in sc.items() if k != "epoch_bounds"}
-    next_bound = jnp.min(jnp.where(eb > t_issue, eb, INF))
+    next_bound = tb.min(jnp.where(tb.gt(eb, t_issue), eb, tb.INF))
     return sc_op, next_bound
 
 
@@ -106,14 +110,14 @@ def scan_cell(ops, addrs, gaps, lengths, scheme, sc, *,
               n_tenants_max: int = 1, n_deep_max: int = 0,
               n_leaves_max: int = 1,
               mlen=None, macro: bool = False,
-              axis_names: tuple = (),
+              axis_names: tuple = (), schemes: tuple = ALL_SCHEMES,
               return_state: bool = False):
     """Simulate one (trace, config) cell.
 
     Returns ``(runtime, stats, durable_ver, n_recovered, recovery_ns,
     recovered_per_tenant, hop_stats, recovered_per_hop,
     recovered_per_leaf, macro_ops, macro_aborts, segments,
-    gate_steps)``, plus the final
+    gate_steps, time_ops)``, plus the final
     :class:`MachineState` when ``return_state`` is set (used by the
     padding-invariant tests).  ``scheme`` and every entry of ``sc`` are
     traced scalars; only array shapes (core count C, ``max_pbe``,
@@ -134,11 +138,16 @@ def scan_cell(ops, addrs, gaps, lengths, scheme, sc, *,
     while the loop runs on for the grid's slowest cell.  ``gate_steps``
     counts the steps on which the macro gate opened (the replay ran):
     every cell of a grid sees the same gate, so the slowest cell's
-    count is the grid's (0 when ``macro`` is off).
+    count is the grid's (0 when ``macro`` is off).  ``runtime`` and
+    ``recovery_ns`` are time words (``engine.timebase``); ``time_ops``
+    is the number of time operations in one traced step, a constant of
+    the program.
 
     ``axis_names`` (static) names the ``vmap`` axes the caller maps this
     cell over, so the macro gate can reduce over the whole grid; ``()``
-    (one cell, no batch axes) makes it a per-cell branch.
+    (one cell, no batch axes) makes it a per-cell branch.  ``schemes``
+    (static) are the scheme ids of the grid's configs: a handler leg no
+    cell takes is left out of the program (``handlers.by_scheme``).
 
     ``macro=True`` (static) enables the macro-stepping fast path;
     ``mlen`` is the (C, L) int8 run plan from
@@ -172,25 +181,28 @@ def scan_cell(ops, addrs, gaps, lengths, scheme, sc, *,
                     jnp.minimum(t_int, n_tenants_max) - 1)
     live_per_tenant = jnp.zeros((n_tenants_max,), jnp.int32).at[tids].add(
         (lengths > 0).astype(jnp.int32))
-    # per-step invariant: the issue-time merge runs in f64, so widen the
-    # stored f32 gaps once instead of on every step
-    gaps64 = gaps.astype(jnp.float64)
+    # per-step invariant: the gaps enter as float32, so convert them to
+    # time words once instead of on every step
+    gaps_t = tb.from_f32(gaps)
+    step_ops = []
 
     def step(carry, _):
+        ops0 = tb.op_count()
         st, mops, maborts, gsteps = carry
         active = st.ptr < lengths
         idx = jnp.minimum(st.ptr, jnp.maximum(lengths - 1, 0))
-        next_gap = gaps64[core_ids, idx]
+        next_gap = gaps_t[core_ids, idx]
         # blocked cores wait at a barrier and cannot be selected; all
         # others compete on the *issue* time of their next op
-        tsel = jnp.where(active & ~st.blocked, st.clock + next_gap, INF)
-        c = jnp.argmin(tsel)
+        tsel = jnp.where(active & ~st.blocked, tb.add(st.clock, next_gap),
+                         tb.INF)
+        c = tb.argmin(tsel)
         # padded steps after exhaustion (or a barrier mismatch) are no-ops
-        valid = jnp.any(active) & (tsel[c] < INF * 0.5)
+        valid = jnp.any(active) & tb.lt(tsel[c], NO_OP)
         i = idx[c]
         t_issue = jnp.where(valid, tsel[c], st.clock[c])
         # ops issuing after the power loss never happen (machine is off)
-        live = valid & (t_issue <= sc["crash_at"])
+        live = valid & tb.le(t_issue, sc["crash_at"])
         op = jnp.where(live, ops[c, i], int(Op.COMPUTE))
         t = jnp.where(live, t_issue, st.clock[c])
         # epoched schedules: every layer below sees the operand rows of
@@ -202,12 +214,12 @@ def scan_cell(ops, addrs, gaps, lengths, scheme, sc, *,
         ctx = StepCtx(c=c, t=t, addr=addrs[c, i], scheme=scheme, sc=sc_op,
                       slot_ids=slot_ids, slot_active=slot_active,
                       tenant=tid_c, tids=tids, n_live_t=n_live_t,
-                      n_banks=pm_banks, n_track=n_track)
+                      n_banks=pm_banks, n_track=n_track, schemes=schemes)
         branches = [lambda s, h=h: h(ctx, s) for h in HANDLERS]
         st2 = jax.lax.switch(jnp.clip(op, 0, 5), branches, st)
 
         if use_macro:
-            win = macro_window(ctx, gaps64, lengths, mlen, tsel, valid,
+            win = macro_window(ctx, gaps_t, lengths, mlen, tsel, valid,
                                live, i, kmax=MACRO_KMAX)
 
             def replay(s):
@@ -257,6 +269,7 @@ def scan_cell(ops, addrs, gaps, lengths, scheme, sc, *,
         ptr = st2.ptr.at[c].add(jnp.where(valid, adv, 0))
         clock = st2.clock.at[c].set(
             jnp.where(valid & ~live & ~took, t_issue, st2.clock[c]))
+        step_ops.append(tb.op_count() - ops0)
         return (st2._replace(clock=clock, ptr=ptr, blocked=blocked,
                              bcount=bcount), mops, maborts, gsteps), None
 
@@ -286,13 +299,15 @@ def scan_cell(ops, addrs, gaps, lengths, scheme, sc, *,
     final, mops, maborts, gsteps = carry
     # a crashed run ends at the power loss: dead cores advanced their
     # clocks through never-executed ops, so cap at the crash instant
-    runtime = jnp.max(jnp.where(final.clock < INF * 0.5,
-                                jnp.minimum(final.clock, sc["crash_at"]),
-                                0.0))
+    runtime = tb.max(jnp.where(tb.lt(final.clock, NO_OP),
+                               tb.minimum(final.clock, sc["crash_at"]),
+                               tb.ZERO))
     (durable_ver, n_recov, recov_ns, recov_t, recov_h,
      recov_l) = recovery_snapshot(
-        final, scheme, sc, slot_active, pm_banks, n_track)
+        final, scheme, sc, slot_active, pm_banks, n_track, schemes)
+    # the time operations of one grid step, counted when it was traced
+    time_ops = jnp.asarray(step_ops[0] if step_ops else 0, jnp.int32)
     out = (runtime, final.stats, durable_ver, n_recov, recov_ns, recov_t,
            final.hop_stats, recov_h, recov_l, mops, maborts, segments,
-           gsteps)
+           gsteps, time_ops)
     return out + (final,) if return_state else out

@@ -21,7 +21,7 @@ import pytest
 from conftest import TINY_BUCKET
 from repro.core import Op, PCSConfig, Scheme, Trace, make_trace
 from repro.core.engine import simulate, simulate_grid, simulate_sweep
-from repro.core.engine.state import scalars_from_config
+from repro.core.engine.state import lower_scalars, scalars_from_config
 from repro.core.engine.step import scan_cell
 
 FIELDS = ("runtime_ns", "persist_lat_ns", "read_lat_ns", "persists",
@@ -154,8 +154,8 @@ def _scan_state(tr, cfg, n_steps, extra_cores=0):
     ops[:C], addrs[:C], gaps[:C], lengths[:C] = (tr.ops, tr.addrs, tr.gaps,
                                                  tr.lengths)
     with jax.enable_x64(True):
-        sc = {k: jnp.asarray(v, jnp.float64)
-              for k, v in scalars_from_config(cfg).items()}
+        sc = {k: jnp.asarray(v) for k, v in
+              lower_scalars(scalars_from_config(cfg)).items()}
         out = _jitted_cell(cfg.n_pbe, n_steps, cfg.pm_banks)(
             jnp.asarray(ops), jnp.asarray(addrs), jnp.asarray(gaps),
             jnp.asarray(lengths), jnp.asarray(int(cfg.scheme), jnp.int32),

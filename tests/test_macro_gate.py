@@ -24,7 +24,9 @@ from repro.core.engine import (last_macro_abort_reasons, simulate,
 from repro.core.engine.handlers import StepCtx
 from repro.core.engine.macro import (MACRO_ABORT_REASONS, macro_gate,
                                      macro_step, macro_window)
-from repro.core.engine.state import INF, init_state, scalars_from_config
+from repro.core.engine import timebase as tb
+from repro.core.engine.state import (init_state, lower_scalars,
+                                     scalars_from_config)
 from repro.core.params import MACRO_KMAX, Op
 from repro.core.traces import fuzz_crash_ns, fuzz_trace, leaf_placement
 
@@ -170,6 +172,11 @@ def test_cells_and_single_cell_follow_the_grid():
 N_FUZZ = 4096
 
 
+def _words(x):
+    """float64 times as time words (the CPU can bitcast them)."""
+    return jax.lax.bitcast_convert_type(x, tb.DTYPE)
+
+
 def _fuzz_window(key, scheme, sc):
     """One random step's inputs: four cores' clocks and next issue
     times, a window of persists and reads at core 0's cursor, busy PM
@@ -188,11 +195,11 @@ def _fuzz_window(key, scheme, sc):
     lengths = jnp.full((C,), L, jnp.int32).at[0].set(
         jax.random.randint(ks[5], (), 1, L + 1, jnp.int32))
     st = st._replace(
-        clock=clock,
-        pm_busy=clock[0] + jax.random.uniform(ks[6], (B,), jnp.float64,
-                                              -500.0, 1500.0),
-        pbc_busy=clock[0] + jax.random.uniform(ks[7], (), jnp.float64,
-                                               -500.0, 1500.0))
+        clock=_words(clock),
+        pm_busy=_words(clock[0] + jax.random.uniform(
+            ks[6], (B,), jnp.float64, -500.0, 1500.0)),
+        pbc_busy=_words(clock[0] + jax.random.uniform(
+            ks[7], (), jnp.float64, -500.0, 1500.0)))
     # the other cores issue around the span a window can cover
     others = clock[0] + jax.random.uniform(ks[8], (C,), jnp.float64,
                                            0.0, 6000.0)
@@ -200,11 +207,12 @@ def _fuzz_window(key, scheme, sc):
     tsel = others.at[0].set(t0)
     crash = jnp.where(jax.random.bernoulli(ks[9], 0.2),
                       t0 + jax.random.uniform(ks[10], (), jnp.float64,
-                                              -300.0, 3000.0), INF)
-    sc = dict(sc, crash_at=crash)
+                                              -300.0, 3000.0), 1e30)
+    sc = dict(sc, crash_at=_words(crash))
     c, i = jnp.asarray(0, jnp.int32), jnp.asarray(0, jnp.int32)
     valid = jnp.asarray(True)
     live = t0 <= crash
+    t0, tsel, gaps = _words(t0), _words(tsel), _words(gaps)
     ctx = StepCtx(c=c, t=t0, addr=addrs[0, 0], scheme=scheme, sc=sc,
                   slot_ids=jnp.arange(P), slot_active=jnp.arange(P) < P,
                   tenant=jnp.asarray(0, jnp.int32),
@@ -230,8 +238,8 @@ def test_prefilter_no_means_the_replay_cannot_commit(cfg, reason):
     """Wherever the pre-filter says no, ``macro_step`` itself commits
     nothing and gives the skip's abort vector, on fuzzed windows."""
     with jax.enable_x64(True):
-        sc = {k: jnp.asarray(v, jnp.float64)
-              for k, v in scalars_from_config(cfg).items()}
+        sc = {k: jnp.asarray(v) for k, v in
+              lower_scalars(scalars_from_config(cfg)).items()}
         scheme = jnp.asarray(int(cfg.scheme), jnp.int32)
         run = jax.jit(jax.vmap(lambda k: _fuzz_window(k, scheme, sc)))
         want, ab_skip, use, ab = (np.asarray(x) for x in run(

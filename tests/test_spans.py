@@ -178,3 +178,20 @@ def test_jax_seconds_count_outermost_events_once():
     before = spans.jax_seconds()
     simulate_grid(TRACES, CONFIGS, bucket=BUCKET, macro=False)
     assert spans.calls()[-1].jax_s == before
+
+
+# time-word operations in one grid step of the depth-1 program, macro
+# off and on (engine.timebase; counted when the step is traced)
+TIME_OPS = {False: 78, True: 110}
+
+
+@pytest.mark.parametrize("macro", [False, True])
+def test_time_ops_pinned_and_carried_on_every_call(macro):
+    simulate_grid(TRACES, CONFIGS, bucket=BUCKET, macro=macro)
+    first = spans.calls()[-1]
+    simulate_grid(TRACES, CONFIGS, bucket=BUCKET, macro=macro)
+    again = spans.calls()[-1]
+    # the second call runs the cached program: nothing is traced, and
+    # the program still carries its count
+    assert again.compiles == 0
+    assert first.time_ops == again.time_ops == TIME_OPS[macro]

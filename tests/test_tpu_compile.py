@@ -95,8 +95,7 @@ def test_engine_compiles_for_v5e_at_paper_budget(one_chip, paper_inputs,
     with jax.enable_x64(True):
         args = [_spec(b, one_chip) for b in buffers]
         args.append(_spec(schemes, one_chip))
-        args.append({k: _spec(v, one_chip, jnp.float64)
-                     for k, v in sc.items()})
+        args.append({k: _spec(v, one_chip) for k, v in sc.items()})
         compiled = fn.lower(*args, **statics).compile()
     _assert_fits(compiled)
 
