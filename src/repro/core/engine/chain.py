@@ -40,6 +40,7 @@ from __future__ import annotations
 
 from typing import NamedTuple
 
+import jax
 import jax.numpy as jnp
 
 from repro.core.engine import channels
@@ -130,6 +131,17 @@ def _pm_land(sc, pos, batch: Batch, pm_busy, pm_ver, n_banks, n_track):
     pm_ver2 = pm_ver.at[jnp.clip(batch.addr, 0, A - 1)].max(
         jnp.where(ok, batch.ver, 0))
     return pm_busy2, pm_ver2, dd_vals, jnp.sum(act.astype(F))
+
+
+def _drain_count(sc, j, scheme, dirty):
+    """Row ``j``'s Dirty count and how many of them its drain-down
+    forwards: every one under PB, down to the preset once the count
+    reaches the threshold under PB_RF.  A count ``k <= 0`` (or no Dirty
+    entry) drains nothing."""
+    dirty_cnt = jnp.sum(dirty.astype(F))
+    k_rf = jnp.where(dirty_cnt >= sc["deep_thr"][j],
+                     dirty_cnt - sc["deep_pre"][j], 0.0)
+    return dirty_cnt, jnp.where(scheme == 1, dirty_cnt, k_rf)
 
 
 def _place(sc, j, scheme, rows, hpbc_j, batch: Batch, hop_stats):
@@ -227,10 +239,7 @@ def _place(sc, j, scheme, rows, hpbc_j, batch: Batch, hop_stats):
 
     # this hop's own drain-down (evaluated once, after the batch settles)
     dirty = slot_act & (state1 == DIRTY)
-    dirty_cnt = jnp.sum(dirty.astype(F))
-    k_rf = jnp.where(dirty_cnt >= sc["deep_thr"][j],
-                     dirty_cnt - sc["deep_pre"][j], 0.0)
-    k = jnp.where(scheme == 1, dirty_cnt, k_rf)     # PB forwards everything
+    dirty_cnt, k = _drain_count(sc, j, scheme, dirty)
     key = jnp.where(dirty, lru1, tb.INF)
     rank = jnp.argsort(tb.argsort(key)).astype(F)
     to_drain = (rank < k) & dirty
@@ -306,6 +315,82 @@ def forward_chain(sc, scheme, rows, hpbc, hop_stats, batch: Batch, dd1,
                                    batch.active)
     return (dd1, rows, hpbc, hop_stats, pmb_l, pmv_l,
             pm_writes + n_l)
+
+
+def grid_any(flag, axis_names: tuple):
+    """``flag`` ORed over every cell of the grid: a ``lax.psum`` over the
+    named ``vmap`` axes the cell runs under, which makes the result
+    unbatched (a ``lax.cond`` on it stays a real branch); with no axes,
+    the cell's own flag."""
+    if not axis_names:
+        return flag
+    return jax.lax.psum(flag.astype(jnp.int32), axis_names) > 0
+
+
+def would_drain(sc, scheme, rows):
+    """Would any live deep row run its own drain-down on a call that
+    brings it no packet?  Only when a threshold or preset changed since
+    the row last settled (an epoch schedule): every call ends with each
+    row drained down to what its counts allow."""
+    D, P = rows["dtag"].shape
+    slot_ids = jnp.arange(P, dtype=jnp.int32)
+    fire = jnp.asarray(False)
+    for j in range(D):
+        row_live = (float(j) + 2.0) <= sc["n_switches"]
+        dirty = (slot_ids < sc["deep_pbe"][j].astype(jnp.int32)) \
+            & (rows["dstate"][j] == DIRTY)
+        dirty_cnt, k = _drain_count(sc, j, scheme, dirty)
+        fire = fire | (row_live & (dirty_cnt > 0.0) & (k > 0.0))
+    return fire
+
+
+def gated_chain(sc, scheme, rows, hpbc, hop_stats, batch: Batch, dd1,
+                pm_busy, pm_ver, *, sends, axis_names: tuple,
+                n_banks: int, n_track: int):
+    """:func:`forward_chain` behind a grid-wide gate.
+
+    ``sends`` is the cell's traced "this leg is mine to run" (a live
+    buffered persist in a chain cell).  The cell *wants* the leg when it
+    sends and the leg has work: an active packet in ``batch`` or a live
+    row that would drain down unprompted (:func:`would_drain`).  The
+    leg runs when some cell of the grid wants it (:func:`grid_any` over
+    ``axis_names``); otherwise a ``lax.cond`` returns the inputs as they
+    are, with 0.0 PM writes.  Returns ``forward_chain``'s outputs and
+    the cell's want.
+
+    Exact, because a call with no active packet and no firing row is
+    the identity, bit for bit:
+
+      * ``_place``: ``fifo_service`` leaves ``hpbc`` as it was; ``t0 =
+        NEG`` frees nothing (a Drain entry's ack is a time >= 0);
+        ``co``, ``amat``, ``mat`` and ``upd`` are all false, so each row
+        column is selected back; ``to_drain`` is empty as no row fires;
+        the ``hop_stats`` adds are ``+0.0`` to non-negative sums; the
+        next row's batch is all inactive;
+      * ``_pm_land``: ``maximum(pm_busy, 0)`` on times >= 0 and a masked
+        ``max`` on versions >= 0, 0.0 writes;
+      * ``_scatter_dd``: every mask is false.
+
+    A cell that does not send has its leg output discarded by its
+    caller (the op switch, ``handlers.by_scheme`` or ``where(is_chain,
+    ...)``), so running or skipping the leg for it changes nothing.
+    """
+    want = sends & (jnp.any(batch.active) | would_drain(sc, scheme, rows))
+
+    def run(a):
+        rows, hpbc, hop_stats, dd1, pm_busy, pm_ver = a
+        return forward_chain(sc, scheme, rows, hpbc, hop_stats, batch, dd1,
+                             pm_busy, pm_ver, n_banks=n_banks,
+                             n_track=n_track)
+
+    def skip(a):
+        rows, hpbc, hop_stats, dd1, pm_busy, pm_ver = a
+        return (dd1, rows, hpbc, hop_stats, pm_busy, pm_ver,
+                jnp.asarray(0.0, F))
+
+    out = jax.lax.cond(grid_any(want, axis_names), run, skip,
+                       (rows, hpbc, hop_stats, dd1, pm_busy, pm_ver))
+    return out, want
 
 
 def deep_read(sc, st: MachineState, addr, t):

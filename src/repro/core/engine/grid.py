@@ -222,7 +222,7 @@ def _execute(program, buffers, configs: Sequence[PCSConfig], max_pbe,
 
 
 def _count(rec: spans.Call, mops, maborts, segments, gate_steps, time_ops,
-           traces, configs, n_steps: int, pairs: bool) -> None:
+           chain_legs, traces, configs, n_steps: int, pairs: bool) -> None:
     """The call's counters from the program's telemetry outputs."""
     rec.cells = int(np.size(segments))
     rec.trace_ops = int(sum(t.total_ops for t in traces)
@@ -233,6 +233,11 @@ def _count(rec: spans.Call, mops, maborts, segments, gate_steps, time_ops,
     rec.steps = int(rec.segments.max()) * CHUNK + n_steps % CHUNK
     # the gate is grid-wide; the slowest cell saw every step
     rec.macro_gate_steps = int(np.max(gate_steps))
+    # one count a leg, as grid-wide as the gate; none without a chain
+    n_legs = np.shape(chain_legs)[-1]
+    if n_legs:
+        rec.chain_leg_steps = tuple(int(x) for x in np.max(
+            np.reshape(chain_legs, (-1, n_legs)), axis=0))
     rec.macro_ops = int(np.sum(mops))
     rec.time_ops = int(np.max(time_ops))
     rec.abort_reasons = dict(zip(MACRO_ABORT_REASONS, (
@@ -245,9 +250,9 @@ def _results_from(out, traces, configs, track_addrs, rec, n_steps,
                   pairs: bool):
     (runtimes, stats, durable_ver, n_recov, recov_ns, recov_t,
      hop_stats, recov_h, recov_l, mops, maborts, segments, gate_steps,
-     time_ops) = out
-    _count(rec, mops, maborts, segments, gate_steps, time_ops, traces,
-           configs, n_steps, pairs)
+     time_ops, chain_legs) = out
+    _count(rec, mops, maborts, segments, gate_steps, time_ops, chain_legs,
+           traces, configs, n_steps, pairs)
     runtimes, recov_ns = tb.to_host(runtimes), tb.to_host(recov_ns)
 
     def cell(i, j, k):

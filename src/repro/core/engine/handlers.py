@@ -1,10 +1,13 @@
 """Op handlers of the timed engine: one function per trace-op kind.
 
 Each handler maps ``(ctx, MachineState) -> MachineState`` for the op the
-selected core issues at time ``ctx.t``.  The step driver dispatches over
-the op kind with ``jax.lax.switch``; *within* the PM-read and persist
-handlers a second ``lax.switch`` dispatches over the **traced** scheme
-scalar (NoPB / PB / PB_RF), so mixed-scheme grids share one XLA program.
+selected core issues at time ``ctx.t``; the persist handler also returns
+the (2,) bool of switch-chain legs (victim, policy drain) the cell
+wanted (``chain.gated_chain``).  The step driver dispatches over the op
+kind with ``jax.lax.switch`` on :data:`HANDLERS`, whose entries all
+return ``(state, legs)``; *within* the PM-read and persist handlers a
+second ``lax.switch`` dispatches over the **traced** scheme scalar
+(NoPB / PB / PB_RF), so mixed-scheme grids share one XLA program.
 
 PM write acks are modeled lazily: when a drain is scheduled its ack
 arrival time at the switch is computed immediately (PM queueing
@@ -22,7 +25,7 @@ import jax.numpy as jnp
 
 from repro.core.engine import chain, channels, fabric, policy
 from repro.core.engine import timebase as tb
-from repro.core.params import Scheme, spine_defer
+from repro.core.params import Op, Scheme, spine_defer
 from repro.core.engine.state import (DIRTY, DRAIN, EMPTY, H_COALESCES,
                                      H_FWD_CNT, H_FWD_SUM, H_READ_HITS,
                                      MachineState, S_ACKED, S_COALESCES,
@@ -46,6 +49,7 @@ class StepCtx(NamedTuple):
     """Per-step context handed to every handler."""
 
     c: jnp.ndarray          # ()  selected core
+    op: jnp.ndarray         # ()  i32 the core's op (COMPUTE when not live)
     t: jnp.ndarray          # ()  op issue time (core clock + compute gap)
     addr: jnp.ndarray       # ()  target cache line
     scheme: jnp.ndarray     # ()  i32 traced scheme id (Scheme value)
@@ -58,6 +62,7 @@ class StepCtx(NamedTuple):
     n_banks: int            # static PM bank count
     n_track: int = 0        # static durability-tracked address count
     schemes: tuple = ALL_SCHEMES  # static scheme ids the grid holds
+    axis_names: tuple = ()  # static vmap axes over the grid's cells
 
 
 def by_scheme(schemes, scheme, nopb, buffered, operand):
@@ -70,6 +75,11 @@ def by_scheme(schemes, scheme, nopb, buffered, operand):
     if set(schemes) == {NOPB}:
         return nopb(operand)
     return jax.lax.switch(jnp.minimum(scheme, 1), [nopb, buffered], operand)
+
+
+def no_legs():
+    """The chain legs of a handler that sends no packet down one."""
+    return jnp.zeros((2,), bool)
 
 
 def _tracked(ctx: StepCtx, addr):
@@ -207,9 +217,10 @@ def handle_pm_read(ctx: StepCtx, st: MachineState) -> MachineState:
 
 
 # ----------------------------------------------------------------- persist
-def _persist_with_buffer(ctx: StepCtx, st: MachineState) -> MachineState:
+def _persist_with_buffer(ctx: StepCtx, st: MachineState):
     """Shared PB persist core: PBC service, lookup, allocation / victim
-    selection, entry write — then the scheme's drain policy.
+    selection, entry write — then the scheme's drain policy.  Returns
+    the state and the chain legs the cell wanted (:func:`no_legs`).
 
     One traced body serves both buffered schemes: ``is_rf`` selects
     coalescing and the threshold/preset drain policy (PB_RF) vs the
@@ -290,10 +301,13 @@ def _persist_with_buffer(ctx: StepCtx, st: MachineState) -> MachineState:
     # FIRST (it leaves the PBC at pbc_start, ahead of the entry write),
     # so the slot frees at its true downstream ack.  D == 0 (no deep row
     # allocated anywhere in the grid) skips the chain at trace time.
+    # Each leg runs only on grid steps where some cell sends it a packet
+    # (``chain.gated_chain``); elsewhere it returns its inputs.
     D = st.dtag.shape[0]
     vic_emit = needs_victim & tb.le(pbc_start, crash)
     if D > 0:
         is_chain = sc["n_switches"] >= 2.0
+        sends = (ctx.op == int(Op.PERSIST)) & (ctx.scheme != NOPB) & is_chain
         one_i = lambda v: jnp.asarray([v], jnp.int32)        # noqa: E731
         vic_batch = chain.Batch(
             active=vic_emit[None],
@@ -301,10 +315,11 @@ def _persist_with_buffer(ctx: StepCtx, st: MachineState) -> MachineState:
             owner=st.owner[victim_idx][None], emit=pbc_start[None],
             ohop=one_i(0), oslot=victim_idx[None].astype(jnp.int32))
         (dd_v, rows_v, hpbc_v, hstats_v, pmb_v, pmv_v,
-         pmw_v) = chain.forward_chain(
+         pmw_v), want_v = chain.gated_chain(
             sc, ctx.scheme, chain.rows_of(st), st.hpbc, st.hop_stats,
-            vic_batch, st.dd, st.pm_busy, st.pm_ver,
-            n_banks=ctx.n_banks, n_track=ctx.n_track)
+            vic_batch, st.dd, st.pm_busy, st.pm_ver, sends=sends,
+            axis_names=ctx.axis_names, n_banks=ctx.n_banks,
+            n_track=ctx.n_track)
         vic_ack = jnp.where(vic_emit, dd_v[victim_idx], victim_dd)
         vic_wait = jnp.where(is_chain, vic_ack, victim_dd)
     else:
@@ -431,10 +446,12 @@ def _persist_with_buffer(ctx: StepCtx, st: MachineState) -> MachineState:
             ohop=jnp.zeros((P,), jnp.int32),
             oslot=pol_order)
         (dd_c, rows_c, hpbc_c, hstats_c, pmb_c, pmv_c,
-         pmw_c) = chain.forward_chain(
+         pmw_c), want_p = chain.gated_chain(
             sc, ctx.scheme, rows_v, hpbc_v, hstats_v, pol_batch,
-            jnp.where(commit, dd4, dd_v), pmb_v, pmv_v,
-            n_banks=ctx.n_banks, n_track=ctx.n_track)
+            jnp.where(commit, dd4, dd_v), pmb_v, pmv_v, sends=sends,
+            axis_names=ctx.axis_names, n_banks=ctx.n_banks,
+            n_track=ctx.n_track)
+        legs = jnp.stack([want_v, want_p])
         dd5 = jnp.where(is_chain, dd_c, dd5)
         pm_ver3 = jnp.where(is_chain, pmv_c, pm_ver3)
         pm_busy3 = jnp.where(is_chain, pmb_c, pm_busy3)
@@ -446,6 +463,7 @@ def _persist_with_buffer(ctx: StepCtx, st: MachineState) -> MachineState:
     else:
         chain_cols = {}
         hop_stats = st.hop_stats
+        legs = no_legs()
     # hop-1 telemetry row (chain row 0; maintained at every depth >= 1)
     hop_stats = hop_stats.at[0, H_FWD_CNT].add(commit.astype(jnp.float64))
     diffs64 = tb.to_f64(diffs)
@@ -495,13 +513,15 @@ def _persist_with_buffer(ctx: StepCtx, st: MachineState) -> MachineState:
                        state=state5, lru=lru5, dd=dd5, ver=ver5,
                        owner=owner5, aver=aver3, pm_ver=pm_ver3,
                        pm_busy=pm_busy3, stats=stats,
-                       hop_stats=hop_stats, **pbc_kw, **chain_cols)
+                       hop_stats=hop_stats, **pbc_kw, **chain_cols), legs
 
 
-def handle_persist(ctx: StepCtx, st: MachineState) -> MachineState:
+def handle_persist(ctx: StepCtx, st: MachineState):
+    """The persist handler: the state and the chain legs the cell
+    wanted (a volatile switch has no chain to send down)."""
     sc, t, addr = ctx.sc, ctx.t, ctx.addr
 
-    def nopb(st: MachineState) -> MachineState:
+    def nopb(st: MachineState):
         # Volatile switch: the persist round-trips to PM.  Nothing is
         # durable until PM acks — a write whose ack lands after the
         # crash is lost (and the core never saw the ack either).
@@ -537,9 +557,9 @@ def handle_persist(ctx: StepCtx, st: MachineState) -> MachineState:
                 jnp.where(tracked & ok, v_new, 0)),
             pm_busy=channels.reserve(st.pm_busy, bank, pm_start,
                                      sc["nvm_w_occ"]),
-            stats=stats)
+            stats=stats), no_legs()
 
-    def buffered(st: MachineState) -> MachineState:
+    def buffered(st: MachineState):
         # PB and PB_RF share one traced body (is_rf inside selects the
         # coalesce rule and drain policy) so vmap executes the
         # expensive chain legs once per step instead of twice.
@@ -562,8 +582,15 @@ def handle_barrier(ctx: StepCtx, st: MachineState) -> MachineState:
     return st._replace(clock=jnp.where(last, released, waiting))
 
 
-HANDLERS = [handle_compute, handle_dram_read, handle_dram_write,
-            handle_pm_read, handle_persist, handle_barrier]
+def _no_chain(handler):
+    """``handler`` with the chain legs of an op that sends nothing."""
+    return lambda ctx, st: (handler(ctx, st), no_legs())
+
+
+# indexed by Op; each entry returns (state, chain legs wanted)
+HANDLERS = [_no_chain(handle_compute), _no_chain(handle_dram_read),
+            _no_chain(handle_dram_write), _no_chain(handle_pm_read),
+            handle_persist, _no_chain(handle_barrier)]
 
 
 # ---------------------------------------------------------------- recovery

@@ -19,6 +19,8 @@ What reads each (the benchmark's per-layer metrics, ``bench/metrics/``):
 * :attr:`Call.macro_gate_steps` over :attr:`Call.steps` —
   ``macro_gate_open``;
 * :attr:`Call.time_ops` — ``step_time_ops``;
+* :attr:`Call.chain_leg_steps` over :attr:`Call.steps` —
+  ``chain_leg_open``;
 * :func:`jax_seconds` as :attr:`Call.jax_s` of the first timed call —
   ``setup_trace_s``, ``setup_lower_s``, ``setup_compile_s``;
 * :func:`compile_count` — the benchmark's ``window_compiles`` check and
@@ -38,7 +40,7 @@ import dataclasses
 import itertools
 import threading
 import time
-from typing import Dict, Iterator, List, NamedTuple, Optional
+from typing import Dict, Iterator, List, NamedTuple, Optional, Tuple
 
 import jax
 import numpy as np
@@ -68,6 +70,9 @@ class Call:
     steps: int = 0                  # grid steps executed
     macro_ops: int = 0              # trace slots committed by macro-steps
     macro_gate_steps: int = 0       # grid steps the macro replay ran on
+    # grid steps the chain's (victim, policy-drain) legs ran on; None in
+    # a program without a chain
+    chain_leg_steps: Optional[Tuple[int, int]] = None
     abort_reasons: Dict[str, int] = dataclasses.field(
         default_factory=lambda: dict.fromkeys(MACRO_ABORT_REASONS, 0))
     time_ops: int = 0               # time-word operations in one cell's

@@ -58,6 +58,7 @@ import jax.numpy as jnp
 
 from repro.core.engine import spans
 from repro.core.engine import timebase as tb
+from repro.core.engine.chain import grid_any
 from repro.core.engine.handlers import (ALL_SCHEMES, HANDLERS, StepCtx,
                                         recovery_snapshot)
 from repro.core.engine.macro import (MACRO_ABORT_REASONS, macro_gate,
@@ -117,7 +118,7 @@ def scan_cell(ops, addrs, gaps, lengths, scheme, sc, *,
     Returns ``(runtime, stats, durable_ver, n_recovered, recovery_ns,
     recovered_per_tenant, hop_stats, recovered_per_hop,
     recovered_per_leaf, macro_ops, macro_aborts, segments,
-    gate_steps, time_ops)``, plus the final
+    gate_steps, time_ops, chain_legs)``, plus the final
     :class:`MachineState` when ``return_state`` is set (used by the
     padding-invariant tests).  ``scheme`` and every entry of ``sc`` are
     traced scalars; only array shapes (core count C, ``max_pbe``,
@@ -138,16 +139,20 @@ def scan_cell(ops, addrs, gaps, lengths, scheme, sc, *,
     while the loop runs on for the grid's slowest cell.  ``gate_steps``
     counts the steps on which the macro gate opened (the replay ran):
     every cell of a grid sees the same gate, so the slowest cell's
-    count is the grid's (0 when ``macro`` is off).  ``runtime`` and
-    ``recovery_ns`` are time words (``engine.timebase``); ``time_ops``
+    count is the grid's (0 when ``macro`` is off).  ``chain_legs``
+    counts, the same way, the steps on which each switch-chain leg ran
+    (victim, policy drain; ``chain.gated_chain``): a (2,) vector, or
+    (0,) in a program with no deep row, which has no leg.  ``runtime``
+    and ``recovery_ns`` are time words (``engine.timebase``); ``time_ops``
     is the number of time operations in one traced step, a constant of
     the program.
 
     ``axis_names`` (static) names the ``vmap`` axes the caller maps this
-    cell over, so the macro gate can reduce over the whole grid; ``()``
-    (one cell, no batch axes) makes it a per-cell branch.  ``schemes``
-    (static) are the scheme ids of the grid's configs: a handler leg no
-    cell takes is left out of the program (``handlers.by_scheme``).
+    cell over, so the macro gate and the chain-leg gates can reduce over
+    the whole grid; ``()`` (one cell, no batch axes) makes each a
+    per-cell branch.  ``schemes`` (static) are the scheme ids of the
+    grid's configs: a handler leg no cell takes is left out of the
+    program (``handlers.by_scheme``).
 
     ``macro=True`` (static) enables the macro-stepping fast path;
     ``mlen`` is the (C, L) int8 run plan from
@@ -185,10 +190,11 @@ def scan_cell(ops, addrs, gaps, lengths, scheme, sc, *,
     # time words once instead of on every step
     gaps_t = tb.from_f32(gaps)
     step_ops = []
+    n_legs = 2 if n_deep_max > 0 else 0
 
     def step(carry, _):
         ops0 = tb.op_count()
-        st, mops, maborts, gsteps = carry
+        st, mops, maborts, gsteps, legs = carry
         active = st.ptr < lengths
         idx = jnp.minimum(st.ptr, jnp.maximum(lengths - 1, 0))
         next_gap = gaps_t[core_ids, idx]
@@ -211,12 +217,16 @@ def scan_cell(ops, addrs, gaps, lengths, scheme, sc, *,
 
         tid_c = tids[c]
         n_live_t = live_per_tenant[tid_c]
-        ctx = StepCtx(c=c, t=t, addr=addrs[c, i], scheme=scheme, sc=sc_op,
-                      slot_ids=slot_ids, slot_active=slot_active,
+        ctx = StepCtx(c=c, op=op, t=t, addr=addrs[c, i], scheme=scheme,
+                      sc=sc_op, slot_ids=slot_ids, slot_active=slot_active,
                       tenant=tid_c, tids=tids, n_live_t=n_live_t,
-                      n_banks=pm_banks, n_track=n_track, schemes=schemes)
+                      n_banks=pm_banks, n_track=n_track, schemes=schemes,
+                      axis_names=axis_names)
         branches = [lambda s, h=h: h(ctx, s) for h in HANDLERS]
-        st2 = jax.lax.switch(jnp.clip(op, 0, 5), branches, st)
+        st2, wants = jax.lax.switch(jnp.clip(op, 0, 5), branches, st)
+        if n_legs:
+            # the legs ran where some cell of the grid wanted them
+            legs = legs + grid_any(wants, axis_names).astype(jnp.int32)
 
         if use_macro:
             win = macro_window(ctx, gaps_t, lengths, mlen, tsel, valid,
@@ -232,8 +242,7 @@ def scan_cell(ops, addrs, gaps, lengths, scheme, sc, *,
 
             if next_bound is None:
                 want, ab_skip = macro_gate(win, sc_op, t_issue, valid, live)
-                gate = (jax.lax.psum(want.astype(jnp.int32), axis_names) > 0
-                        if axis_names else want)
+                gate = grid_any(want, axis_names)
                 st2, took, adv, ab_vec = jax.lax.cond(
                     gate, replay,
                     lambda s: (s, jnp.asarray(False),
@@ -271,7 +280,8 @@ def scan_cell(ops, addrs, gaps, lengths, scheme, sc, *,
             jnp.where(valid & ~live & ~took, t_issue, st2.clock[c]))
         step_ops.append(tb.op_count() - ops0)
         return (st2._replace(clock=clock, ptr=ptr, blocked=blocked,
-                             bcount=bcount), mops, maborts, gsteps), None
+                             bcount=bcount), mops, maborts, gsteps,
+                legs), None
 
     def segment(carry, length):
         return jax.lax.scan(step, carry, None, length=length)[0]
@@ -280,7 +290,8 @@ def scan_cell(ops, addrs, gaps, lengths, scheme, sc, *,
                         n_deep_max, n_leaves_max),
              jnp.zeros((), jnp.int32),
              jnp.zeros((len(MACRO_ABORT_REASONS),), jnp.int32),
-             jnp.zeros((), jnp.int32))
+             jnp.zeros((), jnp.int32),
+             jnp.zeros((n_legs,), jnp.int32))
     n_full, n_tail = divmod(n_steps, CHUNK)
     segments = jnp.zeros((), jnp.int32)
     if n_full > 0:
@@ -296,7 +307,7 @@ def scan_cell(ops, addrs, gaps, lengths, scheme, sc, *,
             more_work, run_segment, (jnp.asarray(0, jnp.int32), carry))
     if n_tail > 0:
         carry = segment(carry, n_tail)
-    final, mops, maborts, gsteps = carry
+    final, mops, maborts, gsteps, legs = carry
     # a crashed run ends at the power loss: dead cores advanced their
     # clocks through never-executed ops, so cap at the crash instant
     runtime = tb.max(jnp.where(tb.lt(final.clock, NO_OP),
@@ -309,5 +320,5 @@ def scan_cell(ops, addrs, gaps, lengths, scheme, sc, *,
     time_ops = jnp.asarray(step_ops[0] if step_ops else 0, jnp.int32)
     out = (runtime, final.stats, durable_ver, n_recov, recov_ns, recov_t,
            final.hop_stats, recov_h, recov_l, mops, maborts, segments,
-           gsteps, time_ops)
+           gsteps, time_ops, legs)
     return out + (final,) if return_state else out

@@ -14,16 +14,29 @@ Covers the acceptance properties of the chain promotion:
   (d) per-hop stats rows follow the PR 3 NaN convention: a hop that saw
       zero traffic has NaN mean forward latency (never 0.0) and the
       figure scripts skip it;
-  (e) ``pbe_per_hop`` construction-time validation.
+  (e) ``pbe_per_hop`` construction-time validation;
+  (f) the chain-leg gate's identity: a ``forward_chain`` call with no
+      active packet and no row above its drain counts returns its
+      inputs bit for bit, and a threshold lowered mid-run (the one case
+      where a row drains down unprompted) keeps the gated engine equal
+      to the ungated one.
 """
+import functools
 import math
 
+import jax
+import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from conftest import TINY_BUCKET
-from repro.core import (AllocPolicy, PBPolicy, PCSConfig, Scheme,
-                        make_trace, simulate, simulate_grid)
-from repro.core.engine import compile_count
+from repro.core import (AllocPolicy, DrainPolicy, PBPolicy, PCSConfig,
+                        Schedule, Scheme, make_trace, simulate,
+                        simulate_grid)
+from repro.core.engine import chain, compile_count
+from repro.core.engine import timebase as tb
+from repro.core.engine.state import DIRTY, lower_scalars, scalars_from_config
+from repro.core.engine.step import scan_cell
 
 COUNT_FIELDS = ("persists", "pm_reads", "read_hits", "coalesces",
                 "pm_writes", "pi_detours", "victim_drains",
@@ -199,3 +212,174 @@ def test_pbe_per_hop_syncs_hop1_capacity():
     # defaulting: every hop inherits n_pbe
     assert PCSConfig(scheme=Scheme.PB, n_switches=2, n_pbe=4).hop_pbes \
         == (4, 4)
+
+
+# ---------------------------------------------------------------------------
+# (f) the chain-leg gate: an empty call is the identity
+# ---------------------------------------------------------------------------
+
+D_ID = 3         # deep rows: chains of up to 4 switches
+PBE_ID = 16
+
+
+@functools.lru_cache(maxsize=None)
+def _state_cell(n_steps):
+    return jax.jit(functools.partial(
+        scan_cell, max_pbe=PBE_ID, n_steps=n_steps, pm_banks=4, n_track=0,
+        n_deep_max=D_ID, return_state=True))
+
+
+def _lowered(cfg, deep_counts=None):
+    sc = lower_scalars(scalars_from_config(cfg, n_deep_max=D_ID))
+    if deep_counts is not None:
+        sc["deep_thr"], sc["deep_pre"] = (np.full((D_ID,), v, np.float64)
+                                          for v in deep_counts)
+    return {k: jnp.asarray(v) for k, v in sc.items()}
+
+
+def _chain_state(tr, cfg, deep_counts=None):
+    """The machine state after running ``tr`` on ``cfg``, and its sc."""
+    n_steps = 1024 * ((tr.total_ops + 1023) // 1024)
+    with jax.enable_x64(True):
+        sc = _lowered(cfg, deep_counts)
+        out = _state_cell(n_steps)(
+            jnp.asarray(tr.ops), jnp.asarray(tr.addrs),
+            jnp.asarray(tr.gaps), jnp.asarray(tr.lengths),
+            jnp.asarray(int(cfg.scheme), jnp.int32), sc)
+    return out[-1], sc
+
+
+def _empty_call(sc, scheme, st, n_packets):
+    """``forward_chain`` of a batch whose packets are all inactive."""
+    rng = np.random.default_rng(n_packets)
+    batch = chain.Batch(
+        active=jnp.zeros((n_packets,), bool),
+        addr=jnp.asarray(rng.integers(0, 64, n_packets), jnp.int32),
+        ver=jnp.asarray(rng.integers(1, 9, n_packets), jnp.int32),
+        owner=jnp.zeros((n_packets,), st.owner.dtype),
+        emit=tb.from_host(rng.uniform(0.0, 1e5, n_packets)),
+        ohop=jnp.zeros((n_packets,), jnp.int32),
+        oslot=jnp.arange(n_packets, dtype=jnp.int32) % PBE_ID)
+    fn = jax.jit(functools.partial(chain.forward_chain, n_banks=4,
+                                   n_track=64))
+    return fn(sc, scheme, chain.rows_of(st), st.hpbc, st.hop_stats, batch,
+              st.dd, st.pm_busy, st.pm_ver)
+
+
+def _bits(x):
+    x = np.asarray(x)
+    return x.view(np.int64) if x.dtype == np.float64 else x
+
+
+def _deep_dirty(st):
+    return int(np.sum(np.asarray(st.dstate) == DIRTY))
+
+
+ID_CASES = {
+    "pb": (PCSConfig(scheme=Scheme.PB, n_switches=4), None, None),
+    "pb_rf": (PCSConfig(scheme=Scheme.PB_RF, n_switches=4), None, None),
+    # power lost at 40% of the run: the rest of the stream is dead
+    "crashed": (PCSConfig(scheme=Scheme.PB_RF, n_switches=3), 0.4, None),
+    # a preset at or above the threshold: a row at the threshold drains
+    # nothing, and one above the preset keeps its fraction
+    "pre_ge_thr": (PCSConfig(scheme=Scheme.PB_RF, n_switches=4), None,
+                   (3.0, 4.5)),
+}
+
+
+@pytest.mark.parametrize("name", list(ID_CASES))
+def test_empty_forward_chain_is_the_identity(chain_trace, name):
+    """On states left by chain runs, a call with no active packet gives
+    back every input bit for bit and writes nothing to PM, and the
+    gate's drain-down term says no row would drain."""
+    cfg, crash_frac, deep_counts = ID_CASES[name]
+    if crash_frac is not None:
+        t_end = simulate(chain_trace, cfg, bucket=TINY_BUCKET).runtime_ns
+        cfg = cfg.with_crash(crash_frac * t_end)
+    st, sc = _chain_state(chain_trace, cfg, deep_counts)
+    scheme = jnp.asarray(int(cfg.scheme), jnp.int32)
+    with jax.enable_x64(True):
+        assert not bool(chain.would_drain(sc, scheme, chain.rows_of(st)))
+        for q in (1, PBE_ID):
+            dd1, rows, hpbc, hop_stats, pm_busy, pm_ver, n_pm = _empty_call(
+                sc, scheme, st, q)
+            want = chain.rows_of(st)
+            for k in want:
+                assert np.array_equal(_bits(rows[k]), _bits(want[k])), (k, q)
+            for got, was in ((dd1, st.dd), (hpbc, st.hpbc),
+                             (hop_stats, st.hop_stats),
+                             (pm_busy, st.pm_busy), (pm_ver, st.pm_ver)):
+                assert np.array_equal(_bits(got), _bits(was)), q
+            assert _bits(n_pm) == _bits(np.float64(0.0))
+    if name != "pb":
+        # PB forwards what it commits; the others hold Dirty entries
+        assert _deep_dirty(st) > 0, name
+
+
+def test_lowered_threshold_makes_a_row_drain_unprompted(chain_trace):
+    """The identity needs its premise: with the threshold dropped below
+    a row's Dirty count (an epoch switch), the drain-down term fires and
+    an empty call does move the row."""
+    cfg = PCSConfig(scheme=Scheme.PB_RF, n_switches=4)
+    st, _ = _chain_state(chain_trace, cfg)
+    assert _deep_dirty(st) > 0
+    scheme = jnp.asarray(int(cfg.scheme), jnp.int32)
+    with jax.enable_x64(True):
+        sc = _lowered(cfg, (1.0, 0.0))
+        assert bool(chain.would_drain(sc, scheme, chain.rows_of(st)))
+        rows = _empty_call(sc, scheme, st, 1)[1]
+    assert not np.array_equal(rows["dstate"], st.dstate)
+
+
+def _same(a, b):
+    """Every ``SimResult`` field equal, NaN equal to NaN."""
+    for f in a.__dataclass_fields__:
+        x, y = getattr(a, f), getattr(b, f)
+        if isinstance(x, np.ndarray) or isinstance(y, np.ndarray):
+            if x is None or y is None or not np.array_equal(
+                    x, y, equal_nan=x.dtype.kind == "f"):
+                return False
+        elif not (x == y or (isinstance(x, float) and math.isnan(x)
+                             and math.isnan(y))):
+            return False
+    return True
+
+
+@pytest.fixture
+def legs(monkeypatch):
+    """Switch the chain-leg gate: ``"every"`` runs every leg on every
+    step (the engine before the gate), ``"blind"`` gates on the batch
+    alone, without the drain-down term.  The jitted programs are dropped
+    at each switch, so no call reuses one traced under another mode."""
+    def use(mode):
+        monkeypatch.undo()
+        jax.clear_caches()
+        if mode == "every":
+            monkeypatch.setattr(chain, "grid_any",
+                                lambda flag, axis_names: jnp.asarray(True))
+        elif mode == "blind":
+            monkeypatch.setattr(chain, "would_drain",
+                                lambda sc, scheme, rows: jnp.asarray(False))
+    yield use
+    monkeypatch.undo()
+    jax.clear_caches()
+
+
+def test_lowered_deep_threshold_mid_run_gated_equals_ungated(legs):
+    """A schedule that lowers every hop's threshold mid-run: rows that
+    settled under the old counts drain down on the next leg call,
+    arrivals or not.  The gate sees that (``would_drain``) and the
+    gated engine equals the ungated one; a gate blind to it does not."""
+    tr = make_trace("radiosity", persist_budget=120)
+    t_end = simulate(tr, PCSConfig(scheme=Scheme.PB_RF),
+                     bucket=TINY_BUCKET).runtime_ns
+    cfg = PCSConfig(scheme=Scheme.PB_RF, n_switches=3, policy=PBPolicy(
+        drain=DrainPolicy(threshold=Schedule((0.5 * t_end,), (1.0, 0.25)),
+                          preset=0.125)))
+    gated = simulate(tr, cfg, bucket=TINY_BUCKET)
+    legs("every")
+    every = simulate(tr, cfg, bucket=TINY_BUCKET)
+    legs("blind")
+    blind = simulate(tr, cfg, bucket=TINY_BUCKET)
+    assert _same(gated, every)
+    assert not _same(blind, every)

@@ -213,8 +213,8 @@ def _fuzz_window(key, scheme, sc):
     valid = jnp.asarray(True)
     live = t0 <= crash
     t0, tsel, gaps = _words(t0), _words(tsel), _words(gaps)
-    ctx = StepCtx(c=c, t=t0, addr=addrs[0, 0], scheme=scheme, sc=sc,
-                  slot_ids=jnp.arange(P), slot_active=jnp.arange(P) < P,
+    ctx = StepCtx(c=c, op=ops[0, 0], t=t0, addr=addrs[0, 0], scheme=scheme,
+                  sc=sc, slot_ids=jnp.arange(P), slot_active=jnp.arange(P) < P,
                   tenant=jnp.asarray(0, jnp.int32),
                   tids=jnp.zeros((C,), jnp.int32),
                   n_live_t=jnp.asarray(C, jnp.int32), n_banks=B, n_track=0)
