@@ -3,7 +3,7 @@ export PYTHONPATH := src$(if $(PYTHONPATH),:$(PYTHONPATH),)
 
 .PHONY: lint test test-all test-fuzz bench-smoke bench bench-compare calibrate ci
 
-# static invariant analysis (repro.analysis): retrace-hazard, mirror-site,
+# static invariant analysis (repro.analysis): retrace-hazard,
 # oracle-twin, dtype-packing and sweep-registry passes; writes the
 # findings summary to ANALYSIS.json and fails on any finding
 lint:

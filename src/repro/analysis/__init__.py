@@ -1,13 +1,10 @@
 """Static invariant analysis for the persistent-CXL-switch simulator.
 
-Five passes, each pinning a contract the test suite can only probe
+Four passes, each pinning a contract the test suite can only probe
 dynamically (and expensively):
 
   * ``retrace``  — every sweepable config knob survives DCE of the
     abstractly traced engine cell (no baked statics);
-  * ``mirror``   — replicated engine expressions (slot/NoPB/macro
-    twins, policy guards) stay structurally identical, and handler
-    families cover the same stats columns;
   * ``twin``     — engine and untimed oracle consume the same policy
     fields and map their statistics onto each other;
   * ``dtypes``   — the packed scan carry keeps its dtypes, no f64->f32
@@ -31,11 +28,6 @@ def _retrace() -> List[Finding]:
     return retrace.check_engine()
 
 
-def _mirror() -> List[Finding]:
-    from repro.analysis import mirror
-    return mirror.check()
-
-
 def _twin() -> List[Finding]:
     from repro.analysis import twin
     return twin.check()
@@ -53,7 +45,6 @@ def _sweeps() -> List[Finding]:
 
 PASSES = {
     "retrace": _retrace,
-    "mirror": _mirror,
     "twin": _twin,
     "dtypes": _dtypes,
     "sweeps": _sweeps,

@@ -26,15 +26,13 @@ import jax.numpy as jnp
 from repro.core.engine import chain, channels, fabric, policy
 from repro.core.engine import timebase as tb
 from repro.core.params import Op, Scheme, spine_defer
-from repro.core.engine.state import (DIRTY, DRAIN, EMPTY, H_COALESCES,
+from repro.core.engine.state import (DIRTY, DRAIN, H_COALESCES,
                                      H_FWD_CNT, H_FWD_SUM, H_READ_HITS,
-                                     MachineState, S_ACKED, S_COALESCES,
-                                     S_DRAM_READS, S_DURABLE, S_LAT_HIST0,
-                                     S_PBCQ_SUM, S_PERSIST_CNT,
-                                     S_PERSIST_SUM, S_PI_DETOURS,
-                                     S_PM_WRITES, S_READ_CNT, S_READ_HITS,
-                                     S_READ_SUM, S_SLO_OVER, S_STALL_TIME,
-                                     S_VICTIM_CNT, lat_bin)
+                                     MachineState, S_COALESCES,
+                                     S_DRAM_READS, S_PBCQ_SUM, S_PI_DETOURS,
+                                     S_READ_CNT, S_READ_HITS, S_READ_SUM,
+                                     S_STALL_TIME, S_VICTIM_CNT,
+                                     persist_stats)
 
 
 NOPB = int(Scheme.NOPB)
@@ -342,19 +340,11 @@ def _persist_with_buffer(ctx: StepCtx, st: MachineState):
     wslot = jnp.where(is_coalesce, idx, slot)
     t_written = tb.add(jnp.where(is_coalesce, pbc_start, ta), sc["data_ns"])
     ack = tb.add(t_written, sc["ow_cpu_sw1"])
-    # Serving-SLO drain tightening (DrainPolicy.latency_target_ns): the
-    # running over-target fraction *including this persist* decides
-    # whether this op's drain-down runs tight.  With no target the
-    # lowered scalar is INF, over_now is always 0 and tight is always
-    # false — bit-exact with the pre-SLO engine.
     # the persist's four time differences, as one vector operation
     diffs = tb.sub(jnp.stack([pbc_prev, ack, ta, t_written]),
                    jnp.stack([arr, t, pbc_start, arr]))
-    lat = diffs[1]
-    over_now = tb.gt(lat, sc["lat_target"]).astype(jnp.float64)  # lint: mirror(slo-over)
-    cnt1 = st.stats[ctx.tenant, S_PERSIST_CNT] + 1.0  # lint: mirror(slo-cnt)
-    over1 = st.stats[ctx.tenant, S_SLO_OVER] + over_now  # lint: mirror(slo-run)
-    tight = over1 > sc["lat_tol"] * cnt1  # lint: mirror(slo-tight)
+    over_now = policy.slo_over(sc, diffs[1])
+    tight = policy.slo_tight(sc, st.stats[ctx.tenant], over_now)
     state3 = jnp.where(ctx.slot_ids == wslot, DIRTY, state2)
     tag3 = st.tag.at[wslot].set(addr)
     lru3 = st.lru.at[wslot].set(t_written)
@@ -482,33 +472,18 @@ def _persist_with_buffer(ctx: StepCtx, st: MachineState):
         pbc_kw = dict(lpbc=st.lpbc.at[my_leaf].set(pbc_free))
     else:
         pbc_kw = dict(pbc_busy=pbc_free)
-    # One fused scatter for every per-persist accumulator (all distinct
-    # columns, so the sums are element-wise identical to chained adds —
-    # the macro fast path stays bit-exact).  A persist committed into
-    # the persistent switch is durable regardless of the drain's fate
-    # (the paper's core claim); the core only *observes* the ack if it
-    # lands before the crash, and ack beats the crash only if the write
-    # committed first, so acked => durable.
-    lat64 = diffs64[1]
-    hist_col = (S_LAT_HIST0 + lat_bin(lat64))[None]  # lint: mirror(lat-bin)
-    cols = jnp.concatenate([
-        jnp.asarray([S_VICTIM_CNT, S_PBCQ_SUM, S_PERSIST_SUM,
-                     S_PERSIST_CNT, S_SLO_OVER, S_COALESCES, S_PM_WRITES,
-                     S_STALL_TIME, S_ACKED, S_DURABLE], jnp.int32),
-        hist_col])
-    vals = jnp.stack([
-        ((~is_coalesce) & (~any_empty)).astype(jnp.float64),
-        jnp.where(tb.gt(pbc_prev, arr), diffs64[0], 0.0),
-        lat64,
-        jnp.ones((), jnp.float64),
-        over_now,
-        is_coalesce.astype(jnp.float64),
-        pm_writes_inc,
-        stall,
-        tb.le(ack, crash).astype(jnp.float64),
-        commit.astype(jnp.float64),
-        jnp.ones((), jnp.float64)])
-    stats = st.stats.at[ctx.tenant, cols].add(vals)  # lint: mirror(stats-scatter)
+    # A persist committed into the persistent switch is durable
+    # regardless of the drain's fate (the paper's core claim); the core
+    # only *observes* the ack if it lands before the crash, and ack beats
+    # the crash only if the write committed first, so acked => durable.
+    cols, vals = persist_stats(
+        diffs64[1], over_now, pm_writes_inc, tb.le(ack, crash), commit,
+        extra=((S_VICTIM_CNT, (~is_coalesce) & (~any_empty)),
+               (S_PBCQ_SUM, jnp.where(tb.gt(pbc_prev, arr), diffs64[0],
+                                      0.0)),
+               (S_COALESCES, is_coalesce),
+               (S_STALL_TIME, stall)))
+    stats = st.stats.at[ctx.tenant, cols].add(vals)
     return st._replace(clock=st.clock.at[ctx.c].set(ack), tag=tag5,
                        state=state5, lru=lru5, dd=dd5, ver=ver5,
                        owner=owner5, aver=aver3, pm_ver=pm_ver3,
@@ -535,21 +510,10 @@ def handle_persist(ctx: StepCtx, st: MachineState):
         tracked = _tracked(ctx, addr)
         a_idx = jnp.clip(addr, 0, A - 1)
         v_new = st.aver[a_idx] + 1
-        # lint: exempt(stats-columns, S_COALESCES S_READ_HITS S_PI_DETOURS): no PB table on the volatile switch
-        # lint: exempt(stats-columns, S_PBCQ_SUM S_STALL_TIME S_VICTIM_CNT): no PBC queue or eviction on the direct PM path
         lat = tb.sub(ack, t)
-        over_now = tb.gt(lat, sc["lat_target"]).astype(jnp.float64)  # lint: mirror(slo-over)
-        one = jnp.ones((), jnp.float64)
-        lat64 = tb.to_f64(lat)
-        hist_col = (S_LAT_HIST0 + lat_bin(lat64))[None]  # lint: mirror(lat-bin)
-        cols = jnp.concatenate([
-            jnp.asarray([S_PERSIST_SUM, S_PERSIST_CNT, S_SLO_OVER,
-                         S_PM_WRITES, S_ACKED, S_DURABLE], jnp.int32),
-            hist_col])
-        vals = jnp.stack([lat64, one, over_now, one,
-                          ok.astype(jnp.float64), ok.astype(jnp.float64),
-                          one])
-        stats = st.stats.at[ctx.tenant, cols].add(vals)  # lint: mirror(stats-scatter)
+        cols, vals = persist_stats(tb.to_f64(lat), policy.slo_over(sc, lat),
+                                   1.0, ok, ok)
+        stats = st.stats.at[ctx.tenant, cols].add(vals)
         return st._replace(
             clock=st.clock.at[ctx.c].set(ack),
             aver=st.aver.at[a_idx].add(jnp.where(tracked, 1, 0)),

@@ -6,10 +6,16 @@ there: consecutive PM_READ / PERSIST ops of one core with non-negative
 gaps and pairwise-distinct addresses (when a persist is involved).  The
 step driver (``engine.step``) consults that plan and hands eligible
 windows to :func:`macro_step`, which replays up to ``MACRO_KMAX`` ops of
-the selected core as an *unrolled exact mini-interpreter* — every
-arithmetic expression is kept in the same form and order as the
-slot-at-a-time handlers, so a committed macro-step is bit-identical to
-the handler path by construction, not by approximation.
+the selected core as an exact mini-interpreter.  Its decisions are the
+handlers' own functions, called on the same operands: bank and PBC
+service (``channels``), lazy frees, Empty-slot allocation under the
+quota gate, the PB drain, the PB_RF drain-down count and the SLO
+tightening (``policy``), and the per-persist stats row
+(``state.persist_stats``).  The leg arithmetic it writes out itself (the
+read miss and the two persist acks) keeps the handlers' form and order,
+so a committed macro-step is bit-identical to the handler path, not an
+approximation of it; ``tests/test_macro.py``, ``tests/test_macro_gate.py``
+and the crash differential pin that.
 
 Commit-or-abort contract (the SyphonArch trace-speculation shape —
 record a hot linear path, guard it, fall back on guard failure):
@@ -72,10 +78,8 @@ import jax.numpy as jnp
 from repro.core.engine import channels, fabric, policy
 from repro.core.engine import timebase as tb
 from repro.core.engine.state import (DIRTY, EMPTY, H_FWD_CNT, H_FWD_SUM,
-                                     S_ACKED, S_DURABLE, S_LAT_HIST0,
-                                     S_PBCQ_SUM, S_PERSIST_CNT,
-                                     S_PERSIST_SUM, S_PM_WRITES, S_READ_CNT,
-                                     S_READ_SUM, S_SLO_OVER, lat_bin)
+                                     S_PBCQ_SUM, S_READ_CNT, S_READ_SUM,
+                                     persist_stats)
 from repro.core.params import Op
 
 # Prioritized abort attribution for live candidate windows: each live op
@@ -301,14 +305,10 @@ def macro_step(ctx, st, ops, addrs, win: Window, valid, live, t_issue, i,
         state_p1 = policy.lazy_free(state_cur, dd_cur, pbc_start)
         has_dirty = jnp.any(ctx.slot_active & (tag_cur == a_j)
                             & (state_p1 == DIRTY))
-        # select_slot's Empty leg under the quota gate, verbatim
-        occ_t = jnp.sum(jnp.where(
-            ctx.slot_active & (state_p1 != EMPTY)
-            & (jnp.clip(owner_cur, 0, T - 1) == ctx.tenant), 1.0, 0.0))
-        over_quota = occ_t >= sc["quota"][ctx.tenant]
-        empty_mask = ctx.slot_active & (state_p1 == EMPTY) & ~over_quota
-        any_empty = jnp.any(empty_mask)
-        wslot = tb.argmin(jnp.where(empty_mask, lru_cur, tb.INF))
+        occ = policy.tenant_occupancy(state_p1, ctx.slot_active, owner_cur,
+                                      T)
+        _, any_empty, wslot = policy.empty_slot(
+            sc, state_p1, ctx.slot_active, lru_cur, ctx.tenant, occ)
         t_written = tb.add(pbc_start, sc["data_ns"])
         ack_p = tb.add(t_written, sc["ow_cpu_sw1"])
         v_new = aver_cur[a_idx] + 1
@@ -318,43 +318,23 @@ def macro_step(ctx, st, ops, addrs, win: Window, valid, live, t_issue, i,
         ver_w = ver_cur.at[wslot].set(v_new)
         owner_w = owner_cur.at[wslot].set(
             ctx.tenant.astype(owner_cur.dtype))
-        # PB: immediate drain of the written entry (exact policy call)
+        # PB: immediate drain of the written entry
         st4_pb, dd4_pb, pmb2_pb, _pw = policy.drain_immediate(
             sc, bank, ctx.slot_ids, wslot, t_written, state_w, dd_cur,
             pmb_cur)
         dd_new_pb = dd4_pb[wslot]
-        # PB_RF: guard that the threshold/preset drain-down fires zero
-        # drains (same sub-expressions as drain_threshold_preset's k)
-        scoped = sc["drain_scope"] > 0.0
-        in_scope = jnp.where(scoped, owner_w == ctx.tenant, True)
-        dirty_cnt = jnp.sum((state_w == DIRTY) & ctx.slot_active
-                            & in_scope)
-        empty_cnt = jnp.sum((state_w == EMPTY) & ctx.slot_active)
-        thr = jnp.where(scoped, sc["t_threshold"][ctx.tenant],
-                        sc["threshold_count"])
-        pre = jnp.where(scoped, sc["t_preset"][ctx.tenant],
-                        sc["preset_count"])
-        # serving-SLO tightening mirror (handler computes tight from the
-        # pre-op stats row *including this persist*; with no target the
-        # lowered scalar is INF, over stays 0 and tight is never true)
         # the op's five time differences, as one vector operation
         diffs = tb.sub(
             jnp.stack([resp, pbc_cur, jnp.where(is_nopb, ack_n, ack_p),
                        t_written, ack_p]),
             jnp.stack([t_j, arr, t_j, arr, t_j]))
         diffs64 = tb.to_f64(diffs)
-        lat_j, lat_p = diffs[2], diffs[4]
-        over_p = tb.gt(lat_p, sc["lat_target"]).astype(jnp.float64)  # lint: mirror(slo-over)
-        cnt1 = stats_cur[ctx.tenant, S_PERSIST_CNT] + 1.0  # lint: mirror(slo-cnt)
-        over1 = stats_cur[ctx.tenant, S_SLO_OVER] + over_p  # lint: mirror(slo-run)
-        tight = over1 > sc["lat_tol"] * cnt1  # lint: mirror(slo-tight)
-        thr = jnp.where(tight, 1.0, thr)  # lint: mirror(rf-tight-thr)
-        pre = jnp.where(tight, 0.0, pre)  # lint: mirror(rf-tight-pre)
-        do_drain = dirty_cnt >= thr  # lint: mirror(rf-do-drain)
-        k_thresh = jnp.where(do_drain, dirty_cnt - pre, 0.0)  # lint: mirror(rf-k-thresh)
-        k_low = jnp.where(empty_cnt <= sc["empty_slack"],  # lint: mirror(rf-k-low)
-                          jnp.minimum(sc["low_water"], dirty_cnt), 0.0)
-        rf_zero = jnp.maximum(k_thresh, k_low) == 0.0
+        # PB_RF: guard that the drain-down fires zero drains
+        tight = policy.slo_tight(sc, stats_cur[ctx.tenant],
+                                 policy.slo_over(sc, diffs[4]))
+        _, k = policy.rf_drain_k(sc, state_w, ctx.slot_active, owner_w,
+                                 ctx.tenant, tight)
+        rf_zero = k == 0.0
         # scheme-selected buffered outcome (RF with k == 0 is a no-op
         # drain policy: state/dd/pm_busy provably unchanged)
         state_wp = jnp.where(is_rf, state_w, st4_pb)
@@ -394,40 +374,18 @@ def macro_step(ctx, st, ops, addrs, win: Window, valid, live, t_issue, i,
         pv_ok = jnp.where(is_nopb, ok_n, ~is_rf & tb.le(dd_new_pb, crash))
         pm_ver_cur = pm_ver_cur.at[a_idx].max(
             jnp.where(m & is_p & tracked & pv_ok, v_new, 0))
-        # stats / telemetry: adds of exact 0.0 are bitwise identities
-        # (every counter is >= +0.0), so skipped terms stay exact.  The
-        # per-persist latency histogram + SLO-over counter use identical
-        # expressions to the handler sites (lat = scheme-selected ack -
-        # issue time); masked lanes add exact 0.0 at a garbage bin,
-        # which is a bitwise identity.  One fused scatter per window
-        # step (all columns distinct) keeps every per-column sum
-        # element-wise identical to the chained adds.
-        # lint: exempt(stats-columns, S_COALESCES S_READ_HITS S_PI_DETOURS): guard aborts PB-hit/coalesce windows
-        # lint: exempt(stats-columns, S_STALL_TIME S_VICTIM_CNT): guard aborts stall/eviction windows
-        over_j = tb.gt(lat_j, sc["lat_target"]).astype(jnp.float64)  # lint: mirror(slo-over)
-        lat64 = diffs64[2]
-        hist_col = (S_LAT_HIST0 + lat_bin(lat64))[None]  # lint: mirror(lat-bin)
-        scols = jnp.concatenate([
-            jnp.asarray([S_READ_SUM, S_READ_CNT, S_PBCQ_SUM,
-                         S_PERSIST_SUM, S_PERSIST_CNT, S_SLO_OVER,
-                         S_PM_WRITES, S_ACKED, S_DURABLE], jnp.int32),
-            hist_col])
-        svals = jnp.stack([
-            jnp.where(sel_r, diffs64[0], 0.0),
-            jnp.where(sel_r, 1.0, 0.0),
-            jnp.where(sel_wp, pbcq_inc, 0.0),
-            jnp.where(m & is_p, lat64, 0.0),
-            jnp.where(m & is_p, 1.0, 0.0),
-            jnp.where(m & is_p, over_j, 0.0),
-            jnp.where(m & is_p & (is_nopb | ~is_rf), 1.0, 0.0),
-            jnp.where(m & is_p,
-                      jnp.where(is_nopb, ok_n, tb.le(ack_p, crash))
-                      .astype(jnp.float64), 0.0),
-            jnp.where(m & is_p,
-                      jnp.where(is_nopb, ok_n.astype(jnp.float64), 1.0),
-                      0.0),
-            jnp.where(m & is_p, 1.0, 0.0)])
-        stats_cur = stats_cur.at[ctx.tenant, scols].add(svals)  # lint: mirror(stats-scatter)
+        # stats: adds of exact 0.0 are bitwise identities (every counter
+        # is >= +0.0), so masked lanes and skipped terms stay exact.  A
+        # committed window holds no PB hit, coalesce, stall or eviction,
+        # so their columns are never added.
+        scols, svals = persist_stats(
+            diffs64[2], policy.slo_over(sc, diffs[2]), is_nopb | ~is_rf,
+            jnp.where(is_nopb, ok_n, tb.le(ack_p, crash)),
+            jnp.where(is_nopb, ok_n, True), on=m & is_p,
+            extra=((S_READ_SUM, jnp.where(sel_r, diffs64[0], 0.0)),
+                   (S_READ_CNT, jnp.where(sel_r, 1.0, 0.0)),
+                   (S_PBCQ_SUM, jnp.where(sel_wp, pbcq_inc, 0.0))))
+        stats_cur = stats_cur.at[ctx.tenant, scols].add(svals)
         hop_cur = hop_cur.at[
             0, jnp.asarray([H_FWD_CNT, H_FWD_SUM], jnp.int32)
         ].add(jnp.stack([jnp.where(sel_wp, 1.0, 0.0),

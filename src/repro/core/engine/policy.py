@@ -9,19 +9,19 @@ untimed oracle (``core.semantics``) and the checkpoint tier
     from ``core.params`` so every layer reads one definition);
   * :func:`rf_drain_count` — the PB_RF threshold/preset + keep-one-free
     drain decision as a pure scalar function, used verbatim by the
-    untimed oracle and mirrored sub-expression-for-sub-expression by the
-    traced :func:`drain_threshold_preset`;
+    untimed oracle; its traced twin is :func:`rf_drain_k`;
   * the traced policy pieces of the timed engine: PB lookup
     (:func:`pb_lookup`), Empty/victim/earliest-Drain slot selection
-    (:func:`select_slot`) and the per-scheme drain policies
-    (:func:`drain_immediate`, :func:`drain_threshold_preset`), which the
-    persist handler dispatches with ``jax.lax.switch`` on the *traced*
-    scheme scalar.
+    (:func:`empty_slot`, :func:`select_slot`), the serving-SLO
+    tightening (:func:`slo_over`, :func:`slo_tight`) and the per-scheme
+    drain policies (:func:`drain_immediate`,
+    :func:`drain_threshold_preset`).
 
-All traced functions are written against the carry arrays of
-``engine.state.MachineState`` and must stay bit-compatible with the
-original monolithic scan: each arithmetic expression is kept in the same
-form and order.
+Each decision is made by one function here, called by the slot-at-a-time
+handlers and by the macro replay (``engine.macro``) alike, so the two
+paths compute it with the same operations in the same order.  All traced
+functions are written against the carry arrays of
+``engine.state.MachineState``.
 """
 from __future__ import annotations
 
@@ -36,7 +36,8 @@ from repro.core.params import (DEFAULT_DRAIN_PRESET,          # noqa: F401
                                preset_count, rf_drain_count,
                                threshold_count)
 from repro.core.engine import timebase as tb
-from repro.core.engine.state import DIRTY, DRAIN, EMPTY
+from repro.core.engine.state import (DIRTY, DRAIN, EMPTY, S_PERSIST_CNT,
+                                     S_SLO_OVER)
 
 
 # ---------------------------------------------------------------------------
@@ -66,9 +67,21 @@ def pb_lookup(tag, state, slot_active, addr):
 def tenant_occupancy(state, slot_active, owner, n_tenants_max: int):
     """Per-tenant live-PBE counts: ``occ[t]`` = non-Empty entries owned
     by tenant ``t`` (the quota / weighted-victim accounting base)."""
-    live = (slot_active & (state != EMPTY)).astype(jnp.float64)
-    return jnp.zeros((n_tenants_max,), jnp.float64).at[
-        jnp.clip(owner, 0, n_tenants_max - 1)].add(live)
+    mine = (jnp.clip(owner, 0, n_tenants_max - 1)[None, :]
+            == jnp.arange(n_tenants_max)[:, None])
+    return jnp.sum(jnp.where(slot_active & (state != EMPTY) & mine,
+                             1.0, 0.0), axis=1)
+
+
+def empty_slot(sc, state, slot_active, lru, tenant, occ):
+    """The Empty leg of :func:`select_slot`: the LRU-oldest Empty slot,
+    closed to a tenant at or over its quota (``occ[tenant] >=
+    sc["quota"][tenant]``).  Returns ``(over_quota, any_empty,
+    empty_idx)``."""
+    over_quota = occ[tenant] >= sc["quota"][tenant]
+    empty_mask = slot_active & (state == EMPTY) & ~over_quota
+    return (over_quota, jnp.any(empty_mask),
+            tb.argmin(jnp.where(empty_mask, lru, tb.INF)))
 
 
 def select_slot(sc, state, slot_active, lru, dd, owner, tenant, occ):
@@ -92,11 +105,9 @@ def select_slot(sc, state, slot_active, lru, dd, owner, tenant, occ):
     to the pre-policy form, keeping results bit-identical.
     """
     T = occ.shape[0]
-    over_quota = occ[tenant] >= sc["quota"][tenant]
+    over_quota, any_empty, empty_idx = empty_slot(sc, state, slot_active,
+                                                  lru, tenant, occ)
     own = owner == tenant
-    empty_mask = slot_active & (state == EMPTY) & ~over_quota
-    any_empty = jnp.any(empty_mask)
-    empty_idx = tb.argmin(jnp.where(empty_mask, lru, tb.INF))
     dirty_all = slot_active & (state == DIRTY)
     over_share = occ >= sc["share"]                       # (T,) bool
     hot = dirty_all & over_share[jnp.clip(owner, 0, T - 1)]
@@ -163,14 +174,28 @@ def recovery_burst_cost(sc, per_bank, n):
         tb.ZERO)
 
 
-def drain_threshold_preset(sc, n_banks, slot_active, t_written,
-                           state3, tag3, lru3, dd3, pm_busy1, *,
-                           owner, tenant, tight=None, defer=None):
-    """PB_RF: threshold/preset drain-down over LRU Dirty entries.
+def slo_over(sc, lat):
+    """1.0 where a persist's latency ``lat`` (a time word) is over its
+    tenant's target (``DrainPolicy.latency_target_ns``), else 0.0.  With
+    no target the lowered scalar is INF and this is always 0.0."""
+    return tb.gt(lat, sc["lat_target"]).astype(jnp.float64)
 
-    Traced twin of :func:`rf_drain_count` plus the per-bank burst
-    serialization: drains sharing a PM bank are issued back-to-back at
-    the bank's write occupancy, overlapping across banks.
+
+def slo_tight(sc, stats_row, over):
+    """The serving-SLO override of the drain-down: whether the tenant's
+    running over-target fraction, *including this persist* (its
+    :func:`slo_over` value ``over``), exceeds its tolerance.
+    ``stats_row`` is the tenant's stats row before the persist."""
+    cnt1 = stats_row[S_PERSIST_CNT] + 1.0
+    over1 = stats_row[S_SLO_OVER] + over
+    return over1 > sc["lat_tol"] * cnt1
+
+
+def rf_drain_k(sc, state, slot_active, owner, tenant, tight):
+    """PB_RF's drain-down decision: the Dirty entries in scope and how
+    many of them (``k``, LRU first) drain now.  Returns ``(dirty_mask,
+    k)``.  Traced twin of :func:`params.rf_drain_count
+    <repro.core.params.rf_drain_count>`.
 
     Under a tenant-scoped :class:`~repro.core.params.DrainPolicy`
     (``sc["drain_scope"]`` set) the drain-down sees only the issuing
@@ -181,13 +206,36 @@ def drain_threshold_preset(sc, n_banks, slot_active, t_written,
     watching the *global* Empty pool (it protects the shared PI front)
     but likewise drains only in-scope entries.
 
-    ``tight`` (a traced bool, or None to skip) is the serving-SLO
-    override (``DrainPolicy.latency_target_ns``): while the issuing
-    tenant's observed over-target persist fraction exceeds its
-    tolerance, the drain-down runs with threshold 1 / preset 0 — drain
-    every in-scope Dirty entry ASAP so the next tail persist does not
-    queue behind a full PB.  A never-true ``tight`` (no target set)
-    selects the untightened counts and is bit-exact with ``tight=None``.
+    ``tight`` (a traced bool, :func:`slo_tight`) runs the drain-down
+    with threshold 1 / preset 0 — drain every in-scope Dirty entry ASAP
+    so the next tail persist does not queue behind a full PB.
+    """
+    scoped = sc["drain_scope"] > 0.0
+    in_scope = jnp.where(scoped, owner == tenant, True)
+    dirty_mask = (state == DIRTY) & slot_active & in_scope
+    dirty_cnt = jnp.sum(dirty_mask)
+    empty_cnt = jnp.sum((state == EMPTY) & slot_active)
+    thr = jnp.where(scoped, sc["t_threshold"][tenant],
+                    sc["threshold_count"])
+    pre = jnp.where(scoped, sc["t_preset"][tenant], sc["preset_count"])
+    thr = jnp.where(tight, 1.0, thr)
+    pre = jnp.where(tight, 0.0, pre)
+    do_drain = dirty_cnt >= thr
+    k_thresh = jnp.where(do_drain, dirty_cnt - pre, 0.0)
+    k_low = jnp.where(empty_cnt <= sc["empty_slack"],
+                      jnp.minimum(sc["low_water"], dirty_cnt),
+                      0.0)
+    return dirty_mask, jnp.maximum(k_thresh, k_low)
+
+
+def drain_threshold_preset(sc, n_banks, slot_active, t_written,
+                           state3, tag3, lru3, dd3, pm_busy1, *,
+                           owner, tenant, tight, defer=None):
+    """PB_RF: threshold/preset drain-down over LRU Dirty entries.
+
+    The :func:`rf_drain_k` decision plus the per-bank burst
+    serialization: drains sharing a PM bank are issued back-to-back at
+    the bank's write occupancy, overlapping across banks.
 
     ``defer`` (a traced bool, or None to skip) is the fabric's
     backpressure override (``FabricTopology.bp_high``): while the
@@ -199,23 +247,8 @@ def drain_threshold_preset(sc, n_banks, slot_active, t_written,
     policy_writes).
     """
     B = n_banks
-    scoped = sc["drain_scope"] > 0.0
-    in_scope = jnp.where(scoped, owner == tenant, True)
-    dirty_mask = (state3 == DIRTY) & slot_active & in_scope
-    dirty_cnt = jnp.sum(dirty_mask)
-    empty_cnt = jnp.sum((state3 == EMPTY) & slot_active)
-    thr = jnp.where(scoped, sc["t_threshold"][tenant],
-                    sc["threshold_count"])
-    pre = jnp.where(scoped, sc["t_preset"][tenant], sc["preset_count"])
-    if tight is not None:
-        thr = jnp.where(tight, 1.0, thr)  # lint: mirror(rf-tight-thr)
-        pre = jnp.where(tight, 0.0, pre)  # lint: mirror(rf-tight-pre)
-    do_drain = dirty_cnt >= thr  # lint: mirror(rf-do-drain)
-    k_thresh = jnp.where(do_drain, dirty_cnt - pre, 0.0)  # lint: mirror(rf-k-thresh)
-    k_low = jnp.where(empty_cnt <= sc["empty_slack"],  # lint: mirror(rf-k-low)
-                      jnp.minimum(sc["low_water"], dirty_cnt),
-                      0.0)
-    k = jnp.maximum(k_thresh, k_low)
+    dirty_mask, k = rf_drain_k(sc, state3, slot_active, owner, tenant,
+                               tight)
     if defer is not None:
         k = jnp.where(defer, 0.0, k)
     key = jnp.where(dirty_mask, lru3, tb.INF)

@@ -41,8 +41,8 @@ INF = 1e30     # the engine's finite infinity for counts and quotas;
 # (E,) epoch axis when any config in the grid carries a Schedule.  The
 # engine resolves them per op at its issue clock (``step.resolve_epoch_sc``)
 # before any handler/policy/macro code consumes them, so every downstream
-# expression — including the mirror-marked sites — sees the same shapes
-# as a static grid.  Everything else in the sc dict is epoch-invariant.
+# expression sees the same shapes as a static grid.  Everything else in
+# the sc dict is epoch-invariant.
 EPOCH_KEYS = ("threshold_count", "preset_count", "quota", "share",
               "t_threshold", "t_preset", "deep_thr", "deep_pre",
               "lat_target", "leaf_of_t")
@@ -112,14 +112,41 @@ def lat_bin(lat_ns):
     """Traced histogram bin index of one persist latency.
 
     ``log_r(x) == 2 * log2(x)`` for ``r = sqrt(2)``, so the bin index is
-    an exact cheap expression; both persist accumulation sites (the
-    slot-at-a-time handler and the macro-step mini-interpreter) MUST use
-    this same function so macro on/off stays bit-exact.  The ``max(lat,
-    1)`` guard keeps masked macro lanes (whose latency operand can be
-    arbitrary garbage, added with weight 0.0) out of ``log2(<=0)``.
+    an exact cheap expression.  The ``max(lat, 1)`` guard keeps masked
+    macro lanes (whose latency operand can be arbitrary garbage, added
+    with weight 0.0) out of ``log2(<=0)``.
     """
     x = jnp.floor(jnp.log2(jnp.maximum(lat_ns, 1.0) / LAT_HIST_MIN_NS) * 2.0)
     return jnp.clip(x.astype(jnp.int32) + 1, 0, N_LAT_BINS - 1)
+
+
+def persist_stats(lat64, over, pm_writes, acked, durable, *, on=True,
+                  extra=()):
+    """The ``(cols, vals)`` of a persist's one fused scatter-add into its
+    tenant's ``stats`` row.
+
+    Every persist adds its latency ``lat64`` (float64 ns), one count, its
+    SLO-over value ``over`` (``policy.slo_over``), one count in its
+    latency-histogram bin, the PM writes it caused, and whether its ack
+    beat the crash (``acked``) and its payload is durable (``durable``).
+    ``extra`` holds ``(column, value)`` pairs that only one leg adds,
+    already masked by the caller.  Where ``on`` is False every persist
+    value is exact 0.0 (a masked macro lane): every counter is ``>=
+    +0.0``, so the add leaves the row bit-equal.  The columns are
+    distinct, so the one scatter sums element-wise as chained adds
+    would, and the macro replay and the handlers, which both build
+    their row here, stay bit-exact.
+    """
+    own = jnp.asarray([lat64, 1.0, over, pm_writes, acked, durable, 1.0],
+                      jnp.float64)
+    cols = jnp.concatenate([
+        jnp.asarray([S_PERSIST_SUM, S_PERSIST_CNT, S_SLO_OVER, S_PM_WRITES,
+                     S_ACKED, S_DURABLE], jnp.int32),
+        (S_LAT_HIST0 + lat_bin(lat64))[None],
+        jnp.asarray([c for c, _ in extra], jnp.int32)])
+    vals = jnp.concatenate([jnp.where(on, own, 0.0),
+                            jnp.asarray([v for _, v in extra], jnp.float64)])
+    return cols, vals
 
 
 def lat_hist_edges() -> np.ndarray:

@@ -78,11 +78,11 @@ def rf_drain_count(dirty: int, empty: int, threshold: int, preset: int,
                    empty_slack: int = RF_EMPTY_SLACK) -> int:
     """How many LRU Dirty entries the PB_RF policy drains right now.
 
-    Pure-scalar twin of ``engine.policy.drain_threshold_preset``'s ``k``
-    (same sub-expressions, Python ints instead of traced f64).  The
-    untimed oracle calls this directly; the engine-vs-oracle
-    cross-validation test (tests/test_engine_oracle.py) is the drift
-    guard between the two forms.  Under a tenant-scoped
+    Pure-scalar twin of ``engine.policy.rf_drain_k``'s ``k`` (same
+    sub-expressions, Python ints instead of traced f64).  The untimed
+    oracle calls this directly; tests/test_policy_shared.py and the
+    engine-vs-oracle cross-validation (tests/test_engine_oracle.py) are
+    the drift guards between the two forms.  Under a tenant-scoped
     :class:`DrainPolicy` the caller passes the *tenant's* Dirty count
     and the *global* Empty count (the keep-one-free heuristic protects
     the shared PI front, but may only drain the tenant's own entries).

@@ -17,9 +17,8 @@ from pathlib import Path
 import numpy as np
 
 from repro.analysis import run_all
-from repro.analysis import dtypes, mirror, retrace, sweeps, twin
-from repro.analysis.common import (normalize_stmt, parse_exemptions,
-                                   parse_markers, rel)
+from repro.analysis import dtypes, retrace, sweeps, twin
+from repro.analysis.common import rel
 
 FIX = Path(__file__).resolve().parent / "fixtures" / "analysis"
 
@@ -80,56 +79,6 @@ def test_retrace_registered_fields_fixture():
     assert "BadPolicy.quota_schedule" in findings[0].message
     assert findings[0].file.endswith("params_bad.py")
     assert findings[0].line == 12  # the quota_schedule field line
-
-
-# -------------------------------------------------------------- mirror
-def test_mirror_fixture_rules_and_locations():
-    path = FIX / "mirror_bad.py"
-    findings = mirror.check_mirrors(
-        paths=[path], expected={"pair": 2, "same": 2, "ghost": 1})
-
-    skew = by_rule(findings, "mirror-skew")
-    assert [(f.file, f.line) for f in skew] == [(rel(path), 11)]
-    assert "mirror_bad.py:6" in skew[0].message
-
-    dangling = by_rule(findings, "mirror-dangling-marker")
-    # a bare-line marker attaches to the following line (here: EOF+1)
-    assert [(f.file, f.line) for f in dangling] == [(rel(path), 45)]
-
-    unknown = by_rule(findings, "mirror-unknown-group")
-    assert [(f.file, f.line) for f in unknown] == [(rel(path), 26)]
-    assert "'mystery'" in unknown[0].message
-
-    missing = by_rule(findings, "mirror-missing-site")
-    assert len(missing) == 1 and "'ghost'" in missing[0].message
-
-    assert len(findings) == 4  # the 'same' group normalizes equal
-
-
-def test_mirror_alpha_renaming_matches_carry_style_rebinding():
-    # site_c (fresh binding, st.acc root) and site_d (carry-style
-    # rebinding, bare name root) must normalize identically — that is
-    # exactly the handler-vs-macro shape the real groups rely on.
-    findings = mirror.check_mirrors(paths=[FIX / "mirror_bad.py"],
-                                    expected={"same": 2})
-    assert by_rule(findings, "mirror-skew") == []
-
-
-def test_mirror_column_coverage_fixture():
-    findings = mirror.check_column_coverage(
-        families={"a": [("mirror_bad.py", "fam_a")],
-                  "b": [("mirror_bad.py", "fam_b")],
-                  "c": [("mirror_bad.py", "fam_c")]},
-        base=FIX)
-    assert all(f.rule == "mirror-missing-column" for f in findings)
-    # fam_b exempts S_TWO with a reason -> clean; fam_c's exemption has
-    # no reason -> flagged, and S_TWO therefore still counts as missing
-    no_reason = [f for f in findings if "without a reason" in f.message]
-    assert [(f.file, f.line) for f in no_reason] \
-        == [(rel(FIX / "mirror_bad.py"), 40)]
-    missing = [f for f in findings if "S_TWO" in f.message]
-    assert len(missing) == 1 and "'c'" in missing[0].message
-    assert len(findings) == 2
 
 
 # ---------------------------------------------------------------- twin
@@ -209,35 +158,6 @@ def test_sweeps_fixture_unregistered_fabric():
     assert "'fabric_sweep'" in findings[0].message
     assert findings[0].file == rel(
         FIX / "bench_bad_fabric" / "fig_fabric.py")
-
-
-# ----------------------------------------------------- comment grammar
-def test_marker_and_exemption_parsing():
-    lines = ["x = 1  # lint: mirror(g-1)",
-             "# lint: mirror(g-2)",
-             "y = 2",
-             "# lint: exempt(stats-columns, S_A S_B): because",
-             "# lint: exempt(stats-columns, S_C)"]
-    markers = parse_markers(lines)
-    assert [(m.group, m.line) for m in markers] == [("g-1", 1),
-                                                    ("g-2", 3)]
-    exs = parse_exemptions(lines)
-    assert [(e.check, e.tokens, e.reason) for e in exs] \
-        == [("stats-columns", ("S_A", "S_B"), "because"),
-            ("stats-columns", ("S_C",), "")]
-
-
-def test_normalizer_separates_target_namespace():
-    import ast
-
-    def norm(src):
-        stmt = ast.parse(src).body[0]
-        return normalize_stmt(stmt, preserved={"jnp"})
-
-    # carry-style rebinding vs fresh binding: identical
-    assert norm("x = x.at[i].set(v)") == norm("y = x.at[i].set(v)")
-    # a real operand change is not erased by the renaming
-    assert norm("x = a + b") != norm("x = a - b")
 
 
 # --------------------------------------------------- real tree is clean
